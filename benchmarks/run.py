@@ -26,6 +26,8 @@ def main() -> None:
     ap.add_argument("--tag", default=None,
                     help="also write results/BENCH_<tag>.json")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     scale = 11 if args.quick else 12
 
     from . import bench_partitioning as bp

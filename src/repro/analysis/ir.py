@@ -17,7 +17,7 @@ only catch instance-by-instance:
   §Perf-partitioner);
 - ``unreduced_divergence`` — shard_map outputs claimed replicated while
   the body computes an axis-varying value that never crossed a
-  reduction (the bug ``check_rep=False`` stops catching).
+  reduction (the bug ``check_vma=False`` stops catching).
 
 Everything here imports jax lazily-enough to keep ``repro.analysis``
 (the lint layer) jax-free.
@@ -29,6 +29,7 @@ import re
 import numpy as np
 
 import jax
+from jax.extend.core import Literal
 
 # ---------------------------------------------------------------------------
 # Post-SPMD HLO text parsers (moved verbatim from launch/dryrun.py)
@@ -42,27 +43,36 @@ DTYPE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "s8": 1,
                "f8e5m2": 1, "s16": 2, "u16": 2}
 
 
+# the opcode, not the instruction name: jax names instructions after the
+# op that emitted them (``%ppermute.56 = f32[23] collective-permute(…)``)
+OPCODE_RE = re.compile(r"\s(" + "|".join(COLLECTIVE_KINDS)
+                       + r")(-start|-done)?\(")
+
+
+def hlo_collectives(hlo_text: str):
+    """(kind, async suffix, output type text) of every collective
+    instruction, async ``-done`` halves skipped (a start/done pair is one
+    collective, and the done's output repeats the start's)."""
+    for line in hlo_text.splitlines():
+        _, sep, rest = line.partition("=")
+        if not sep:
+            continue
+        m = OPCODE_RE.search(rest)
+        if m is None or m.group(2) == "-done":
+            continue
+        yield m.group(1), m.group(2), rest[:m.start()]
+
+
 def collective_bytes(hlo_text: str) -> dict:
     """Per-device output bytes of every collective instruction, by kind.
 
-    Anchored on the instruction name left of ``=`` and summing every
+    Anchored on the opcode right of ``=`` and summing every
     ``dtype[dims]`` in the output type — which may be a tuple:  XLA:CPU
-    lowers ``all_to_all`` to ``(f32[1,H], …×k) all-to-all(…)``.  Async
-    ``-done`` halves are skipped (their output repeats the start's)."""
+    lowers ``all_to_all`` to ``(f32[1,H], …×k) all-to-all(…)``."""
     out = {}
-    for line in hlo_text.splitlines():
-        head, sep, rest = line.partition("=")
-        if not sep:
-            continue
-        name = head.strip().removeprefix("ROOT").strip().lstrip("%")
-        kind = next((kd for kd in COLLECTIVE_KINDS
-                     if name.startswith(kd)), None)
-        if kind is None or "-done" in name:
-            continue
-        idx = rest.find(kind)
-        out_type = rest[:idx] if idx >= 0 else rest
+    for kind, suffix, out_type in hlo_collectives(hlo_text):
         shapes = SHAPE_RE.findall(out_type)
-        if "-start" in name and len(shapes) > 1:
+        if suffix == "-start" and len(shapes) > 1:
             # async start tuples are (aliased operand, result, …): the
             # first element is the input, not wire traffic
             shapes = shapes[1:]
@@ -79,22 +89,13 @@ def collective_bytes(hlo_text: str) -> dict:
 
 
 def collective_permute_count(hlo_text: str) -> int:
-    """Number of collective-permute instructions in the post-SPMD HLO.
-
-    Same name-anchoring as ``collective_bytes`` (instruction name left of
-    ``=``, async ``-done`` halves skipped so a start/done pair counts
-    once).  The overlapped ragged body must keep this count identical to
-    the phase-ordered body: overlap re-orders compute around the k−1
-    ring hops, it must never add or drop a hop."""
-    n = 0
-    for line in hlo_text.splitlines():
-        head, sep, _ = line.partition("=")
-        if not sep:
-            continue
-        name = head.strip().removeprefix("ROOT").strip().lstrip("%")
-        if name.startswith("collective-permute") and "-done" not in name:
-            n += 1
-    return n
+    """Number of collective-permute instructions in the post-SPMD HLO,
+    a start/done pair counted once.  The overlapped ragged body must
+    keep this count identical to the phase-ordered body: overlap
+    re-orders compute around the k−1 ring hops, it must never add or
+    drop a hop."""
+    return sum(kind == "collective-permute"
+               for kind, _, _ in hlo_collectives(hlo_text))
 
 
 # ---------------------------------------------------------------------------
@@ -241,13 +242,13 @@ def scatter_copy_sites(jaxpr_or_fn, *args) -> list[dict]:
         dyn = set(dyn)
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
-            in_dyn = any(not isinstance(v, jax.core.Literal) and v in dyn
+            in_dyn = any(not isinstance(v, Literal) and v in dyn
                          for v in eqn.invars)
             in_loop = any(p in LOOP_PRIMITIVES for p in path)
             if in_loop and len(eqn.invars) > 1 and any(
                     name.startswith(p) for p in SCATTER_PRIMITIVES):
                 idx = eqn.invars[1]
-                if not isinstance(idx, jax.core.Literal) and idx in dyn:
+                if not isinstance(idx, Literal) and idx in dyn:
                     sites.append({
                         "primitive": name,
                         "operand_shape": tuple(eqn.invars[0].aval.shape),
@@ -266,7 +267,7 @@ def scatter_copy_sites(jaxpr_or_fn, *args) -> list[dict]:
                     ins = eqn.invars[-len(sub_j.invars):] \
                         if len(sub_j.invars) <= len(eqn.invars) else None
                     seed = ({bv for bv, ov in zip(sub_j.invars, ins)
-                             if not isinstance(ov, jax.core.Literal)
+                             if not isinstance(ov, Literal)
                              and ov in dyn}
                             if ins is not None else set(sub_j.invars))
                 visit(sub_j, path + (name,), seed)
@@ -293,14 +294,24 @@ def _eqn_axes(eqn):
     return set(axes)
 
 
-def _body_divergence(inner, in_names, out_names, mesh_axes):
+def _spec_axes(spec) -> tuple:
+    """The mesh axes a shard_map ``PartitionSpec`` shards over (empty =
+    replicated)."""
+    axes = []
+    for entry in spec:
+        if entry is not None:
+            axes.extend(entry if isinstance(entry, tuple) else (entry,))
+    return tuple(axes)
+
+
+def _body_divergence(inner, in_axes, out_axes, mesh_axes):
     varying: set = set()
 
     def is_varying(atom):
-        return not isinstance(atom, jax.core.Literal) and atom in varying
+        return not isinstance(atom, Literal) and atom in varying
 
-    for var, names in zip(inner.invars, in_names):
-        if names:               # sharded input: per-device slice differs
+    for var, axes in zip(inner.invars, in_axes):
+        if axes:               # sharded input: per-device slice differs
             varying.add(var)
     for eqn in inner.eqns:
         name = eqn.primitive.name
@@ -321,18 +332,18 @@ def _body_divergence(inner, in_names, out_names, mesh_axes):
         if out_varying:
             varying.update(eqn.outvars)
     out = []
-    for i, (var, names) in enumerate(zip(inner.outvars, out_names)):
-        if not names and is_varying(var):
+    for i, (var, axes) in enumerate(zip(inner.outvars, out_axes)):
+        if not axes and is_varying(var):
             out.append(i)
     return out
 
 
 def unreduced_divergence(jaxpr_or_fn, *args) -> list[dict]:
-    """shard_map outputs declared replicated (empty out_names) whose
+    """shard_map outputs declared replicated (empty out_specs) whose
     value is axis-varying and never crossed a reduction.
 
-    This is the divergence class ``check_rep=False`` (which the ragged
-    wires require) stops catching at runtime: every device returns a
+    This is the divergence class ``check_vma=False`` (which the sharded
+    partitioner runs with) stops catching at runtime: every device returns a
     *different* array through an out_spec that promises they're all the
     same, and downstream code silently reads device 0's copy.  Returns
     one record per diverging output with the shard_map's position path.
@@ -347,9 +358,9 @@ def unreduced_divergence(jaxpr_or_fn, *args) -> list[dict]:
         inner = _as_jaxpr(eqn.params["jaxpr"])
         mesh = eqn.params.get("mesh")
         mesh_axes = set(getattr(mesh, "axis_names", ()) or ())
-        in_names = [dict(n) for n in eqn.params.get("in_names", ())]
-        out_names = [dict(n) for n in eqn.params.get("out_names", ())]
-        for i in _body_divergence(inner, in_names, out_names, mesh_axes):
+        in_axes = [_spec_axes(s) for s in eqn.params["in_specs"]]
+        out_axes = [_spec_axes(s) for s in eqn.params["out_specs"]]
+        for i in _body_divergence(inner, in_axes, out_axes, mesh_axes):
             findings.append({
                 "output": i,
                 "aval": str(inner.outvars[i].aval),

@@ -26,9 +26,10 @@ ENGINE_INTERNALS = frozenset({
 
 class SessionBypass(Rule):
     id = "SESSION-BYPASS"
-    description = ("entry points (launch/, examples/, benchmarks/) drive "
-                   "GraphSession, not raw layout/engine internals")
-    roots = ("src/repro/launch", "examples", "benchmarks")
+    description = ("entry points (launch/, examples/, benchmarks/, "
+                   "chip_smoke.py) drive GraphSession, not raw "
+                   "layout/engine internals")
+    roots = ("src/repro/launch", "examples", "benchmarks", "chip_smoke.py")
 
     def run(self, tree, relpath, text):
         out = []

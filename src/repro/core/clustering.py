@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.cluster_scatter import cluster_scatter, edge_decisions
-from ..kernels.ops import DEFAULT_INTERPRET
 
 
 @dataclass
@@ -228,7 +227,7 @@ _BIG_ID = np.int32(2 ** 31 - 1)
 def _block_step(carry, x, *, vmax: float, allow_split: bool,
                 split_degree_factor: float, cap: int, num_vertices: int,
                 B: int, unroll: int = 1, kernel: str = "xla",
-                interpret: bool = DEFAULT_INTERPRET):
+                interpret: bool | None = None):
     """Process one block of B edges: localize → inner scan → write back."""
     clu, deg, vol, nid, seen_v, seen_deg = carry
     bu, bv = x
@@ -268,8 +267,8 @@ def _block_step(carry, x, *, vmax: float, allow_split: bool,
     if kernel == "pallas":
         # the whole block table stays resident in kernel memory for the
         # full edge loop — no per-step buffer copies (the XLA scan's
-        # ~1.3 µs/scatter floor); interpret=True on CPU runs the same
-        # kernel body for correctness (bit-identical, tested)
+        # ~1.3 µs/scatter floor); off the TPU the interpreter runs the
+        # same kernel body for correctness (bit-identical, tested)
         scal0 = jnp.stack([nid, nid0, seen_v, seen_deg])
         buf, scal, fires = cluster_scatter(
             ints, buf, scal0, vmax, allow_split=allow_split,
@@ -311,7 +310,7 @@ def streaming_clustering_jax(src, dst, num_vertices: int, vmax: float,
                              id_cap: int | None = None,
                              block_size: int = 128, unroll: int = 1,
                              kernel: str = "xla",
-                             interpret: bool = DEFAULT_INTERPRET):
+                             interpret: bool | None = None):
     """Blocked lax.scan form; returns raw (non-compacted) labels + state
     arrays (clu, deg, divided, replicas, next_id) — bit-identical to
     ``streaming_clustering_np``.
@@ -329,8 +328,9 @@ def streaming_clustering_jax(src, dst, num_vertices: int, vmax: float,
     ``kernel`` picks the inner-loop strategy: ``"xla"`` = the lax.scan
     over ``_edge_step_local`` (the fused-scatter scan), ``"pallas"`` = the
     ``kernels.cluster_scatter`` fused table-update kernel (interpret mode
-    on CPU).  Both share ``edge_decisions`` so results are bit-identical;
-    ``unroll`` only applies to the XLA scan.
+    unless lowered for a TPU; ``interpret`` forces one mode).  Both share
+    ``edge_decisions`` so results are bit-identical; ``unroll`` only
+    applies to the XLA scan.
     """
     E = src.shape[0]
     cap = int(id_cap) if id_cap is not None else num_vertices + 2 * E + 2

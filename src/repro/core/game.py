@@ -204,7 +204,7 @@ def greedy_assign(cg: ClusterGraph, k: int) -> np.ndarray:
 # same contraction with CSR tiles.
 # ---------------------------------------------------------------------------
 
-_LANE_BIG = jnp.float32(3e38)   # masks partition lanes >= the traced k_real
+_LANE_BIG = np.float32(3e38)    # masks partition lanes >= the traced k_real
 
 
 def _mask_lanes(cost, k_real, lanes=None):
@@ -290,6 +290,13 @@ def jax_game_rounds(xs, xd, sizes, row_tot, k: int, lam, *,
           else k_real.astype(jnp.float32))
     n_batches = max(1, -(-m_cap // batch_size))
     ar = jnp.arange(m_cap)
+    # compact cluster ids fill [0, m); the padding past m never moves (no
+    # size, no cut mass), so batches wholly inside it are skipped — with
+    # m ≪ m_cap that is most of them.  The pmax keeps every device's
+    # trip count, and so its collectives, in step.
+    live = (sizes > 0) | (row_tot > 0)
+    m_live = coll.pmax(jnp.max(jnp.where(live, ar + 1, 0)), axis)
+    live_batches = jnp.maximum(1, (m_live + batch_size - 1) // batch_size)
 
     key = jax.random.PRNGKey(seed)
     if axis is not None:
@@ -312,10 +319,9 @@ def jax_game_rounds(xs, xd, sizes, row_tot, k: int, lam, *,
                .add(1.0, mode="drop"))
         if use_pallas:
             from ..kernels.game_bestresponse import game_bestresponse
-            interpret = jax.default_backend() != "tpu"
             best, best_cost = game_bestresponse(
                 aff, sizes, row_tot, assign, loads, lam=lam, k=k,
-                block_m=block_m, interpret=interpret)
+                block_m=block_m)
         else:
             pids = jax.lax.broadcasted_iota(jnp.int32, (m_cap, kpad), 1)
             own = (pids == assign[:, None]).astype(jnp.float32)
@@ -338,6 +344,13 @@ def jax_game_rounds(xs, xd, sizes, row_tot, k: int, lam, *,
         # small clusters between near-equal partitions is what keeps
         # Jacobi sweeps from settling
         p = jnp.maximum(damping * 0.92 ** rnd.astype(jnp.float32), 0.08)
+        if axis is not None:
+            # every device plays its batch at once against the same
+            # global loads, so n devices herd n times as hard as one:
+            # each moves 1/n as often, keeping the round's expected
+            # moved mass that of a single device (measured on 4 devices
+            # at scale 16, k=4: RF 1.28x the jit partition -> 1.06x)
+            p = p / jax.lax.axis_size(axis)
         move = wants & jax.random.bernoulli(damp_key, p, (m_cap,))
         msz = jnp.where(move, sizes, 0.0)
         delta = (jnp.zeros((kpad,), jnp.float32)
@@ -365,7 +378,8 @@ def jax_game_rounds(xs, xd, sizes, row_tot, k: int, lam, *,
     def round_body(carry):
         assign, loads, rnd, _, best_assign, best_phi, stall = carry
         assign, loads, moved, _ = jax.lax.fori_loop(
-            0, n_batches, batch_body, (assign, loads, jnp.int32(0), rnd))
+            0, live_batches, batch_body,
+            (assign, loads, jnp.int32(0), rnd))
         phi = potential(assign, loads)
         better = phi < best_phi - 1e-6 * jnp.abs(best_phi)
         best_assign = jnp.where(better, assign, best_assign)

@@ -368,8 +368,6 @@ def _make_sharded_fn(mesh, e_per: int, num_vertices: int,
     shard_map pipeline: one stream slice per device along the ``stream``
     axis, each running the SAME stage body as the jit strategy — only the
     ctx differs."""
-    from ..dist._compat import shard_map
-
     n = mesh.shape["stream"]
     spec = _stream_spec(mesh, (n * e_per,))
 
@@ -396,11 +394,16 @@ def _make_sharded_fn(mesh, e_per: int, num_vertices: int,
                 out.cluster.next_id[None],
                 out.overflow.astype(jnp.int32)[None])
 
-    # check_vma=False: the game's while_loop has no replication rule on
-    # the container's jax (0.4.x shard_map check_rep)
-    mapped = shard_map(node_fn, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=(spec, spec, spec, spec, spec),
-                       check_vma=False)
+    # check_vma=False: the stage body is the one the single-device jit
+    # strategy runs, and its scan/while carries start from constants
+    # (empty tables, zero counters) that turn per-device on the first
+    # step.  Under the check every such carry would need a pcast to
+    # 'varying' inside code that also runs outside shard_map.  Every
+    # output is per-slice (out_specs all shard), so nothing relies on
+    # the check to prove an output replicated.
+    mapped = jax.shard_map(node_fn, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=(spec, spec, spec, spec, spec),
+                           check_vma=False)
     return jax.jit(mapped)
 
 
@@ -415,7 +418,8 @@ def _run_sharded(src: np.ndarray, dst: np.ndarray, num_vertices: int,
                 f"--xla_force_host_platform_device_count={nodes} before "
                 f"the first jax import (launch.partition does this for "
                 f"--backend sharded)")
-        mesh = jax.make_mesh((nodes,), ("stream",))
+        from ..launch.mesh import make_stream_mesh
+        mesh = make_stream_mesh(nodes)
     n = int(mesh.shape["stream"])
     e_per = -(-E // n)
     e_pad = e_per * n

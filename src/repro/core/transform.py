@@ -14,12 +14,15 @@ Space O(k) (the load array), time O(|E|) — matching §III-C.
 """
 from __future__ import annotations
 
+from functools import partial
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
 
 from ..dist import collectives as coll
+from ..kernels.greedy_transform import greedy_transform
 
 
 def transform_np(src: np.ndarray, dst: np.ndarray,
@@ -65,7 +68,7 @@ def transform_np(src: np.ndarray, dst: np.ndarray,
 
 
 def _transform_step(loads, edge, *, lmax, k: int, k_real=None):
-    u, v, pu, pv, du, dv, divu, divv, live = edge
+    pu, pv, du, dv, divu, divv, live = edge
     full_u = loads[pu] >= lmax
     full_v = loads[pv] >= lmax
     # lanes past the traced live count (the k_max-padded sweep) must not
@@ -100,7 +103,11 @@ def transform_jax(src, dst, vertex_part, deg, divided, k: int,
     τ·|E_local|/k with the *real* (masked) edge count, which is a traced
     scalar.  ``k_real`` (traced) restricts the balance cap and the
     least-loaded fallback to the live lanes of a k_max-padded sweep
-    step."""
+    step.
+
+    The per-edge recurrence runs as ``kernels.greedy_transform`` on the
+    TPU's scalar unit where the call is lowered for a TPU (bit-identical,
+    tested), as a ``lax.scan`` elsewhere and for traced ``k_real``."""
     E = src.shape[0]
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
@@ -110,18 +117,23 @@ def transform_jax(src, dst, vertex_part, deg, divided, k: int,
         lmax = (tau * E / float(k) if k_real is None
                 else tau * E / k_real.astype(jnp.float32))
     vp = jnp.asarray(vertex_part, jnp.int32)
-    edges = jnp.stack([
-        src, dst,
-        vp[src], vp[dst],
-        jnp.asarray(deg, jnp.int32)[src], jnp.asarray(deg, jnp.int32)[dst],
-        jnp.asarray(divided, jnp.int32)[src],
-        jnp.asarray(divided, jnp.int32)[dst],
-        live,
-    ], axis=1)
-    loads0 = jnp.zeros((k,), dtype=jnp.int32)
-    step = lambda s, e: _transform_step(s, e, lmax=lmax, k=k,
-                                        k_real=k_real)
-    _, assign = jax.lax.scan(step, loads0, edges)
+    deg = jnp.asarray(deg, jnp.int32)
+    divided = jnp.asarray(divided, jnp.int32)
+    edges = (vp[src], vp[dst], deg[src], deg[dst], divided[src],
+             divided[dst], live)
+    scan = partial(_transform_scan, k=k, k_real=k_real)
+    if k_real is not None:
+        return scan(*edges, lmax)
+    return jax.lax.platform_dependent(
+        *edges, jnp.asarray(lmax, jnp.float32),
+        tpu=partial(greedy_transform, k=k, interpret=False), default=scan)
+
+
+def _transform_scan(*cols, k: int, k_real=None):
+    *edges, lmax = cols
+    step = partial(_transform_step, lmax=lmax, k=k, k_real=k_real)
+    _, assign = jax.lax.scan(step, jnp.zeros((k,), jnp.int32),
+                             tuple(edges))
     return assign
 
 

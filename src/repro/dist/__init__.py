@@ -9,7 +9,6 @@ Everything runs on CPU under
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` — the same code
 path the production pod meshes lower through.
 """
-from . import _compat  # noqa: F401  (installs jax.shard_map on old jax)
 from . import collectives  # noqa: F401  (axis-wide reduction helpers)
 from .halo import (DenseExchange, HaloExchange,  # noqa: F401
                    QuantizedHaloExchange, get_exchange)

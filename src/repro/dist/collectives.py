@@ -39,3 +39,22 @@ def pmin(x, axis: str | None = None):
 def axis_index(axis: str):
     """This device's position along ``axis`` (for per-device seeding)."""
     return jax.lax.axis_index(axis)
+
+
+def varying(tree, axis: str | None = None):
+    """Type every leaf of ``tree`` as varying over the mesh ``axis``.
+
+    A value made inside ``shard_map`` from constants (zeros, a program's
+    init) is the same on every device, while one step of a per-device
+    loop makes it differ.  A loop carry must keep one type, so such
+    initial carries are cast up front.  Leaves that already vary pass
+    through; ``axis=None`` (the stacked form) is the identity."""
+    if axis is None:
+        return tree
+
+    def cast(x):
+        if axis in jax.typeof(x).vma:
+            return x
+        return jax.lax.pcast(x, (axis,), to="varying")
+
+    return jax.tree_util.tree_map(cast, tree)

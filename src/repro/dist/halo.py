@@ -91,6 +91,7 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
+from .collectives import varying
 from .compress import dequantize_rows, quantize_rows
 
 
@@ -476,7 +477,9 @@ class QuantizedHaloExchange:
             return ()
         zeros = jnp.zeros(dev["halo_send"].shape, jnp.float32)
         lane_state = {"sref": zeros, "sres": zeros, "rref": zeros}
-        return {"reduce": lane_state, "bcast": dict(lane_state)}
+        # zeros are the same on every device; one step makes them vary
+        return varying({"reduce": lane_state, "bcast": dict(lane_state)},
+                       self.axis)
 
     # -- per-device halves (inside shard_map over ``axis``) --
     def reduce_to_masters(self, partial, dev, combine: str = "sum",
@@ -568,7 +571,9 @@ class QuantizedHaloExchange:
         shape = dev["halo_send"].shape
         zeros = jnp.zeros((*shape[:-1], n, shape[-1]), jnp.float32)
         lane_state = {"sref": zeros, "sres": zeros, "rref": zeros}
-        return {"reduce": lane_state, "bcast": dict(lane_state)}
+        # zeros are the same on every device; one step makes them vary
+        return varying({"reduce": lane_state, "bcast": dict(lane_state)},
+                       self.axis)
 
     def reduce_to_masters_multi(self, partials, dev, combine: str = "sum",
                                 state=()):
@@ -935,7 +940,7 @@ class RaggedQuantizedHaloExchange:
                           "rref": jnp.zeros((*lead, h), jnp.float32)}
                          for _, h in self._hops())
 
-        return {"reduce": lanes(), "bcast": lanes()}
+        return varying({"reduce": lanes(), "bcast": lanes()}, self.axis)
 
     def _encode(self, lanes, st, h):
         """Top-Δ error-feedback step for one hop: returns the advanced

@@ -14,8 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ._compat import shard_map
-
 
 def reference_apply(stacked_params, xs, fn):
     """Sequentially run every microbatch through all stages.
@@ -76,6 +74,6 @@ def pipeline_apply(mesh, axis: str, stacked_params, xs, fn):
         return jax.lax.psum(outputs, axis)
 
     fn_sharded = partial(
-        shard_map, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(),
+        jax.shard_map, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(),
         check_vma=False)(local)
     return fn_sharded(stacked_params, xs)

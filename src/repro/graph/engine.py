@@ -55,7 +55,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .partition import PartitionLayout
 from ..dist import collectives as coll
-from ..dist._compat import shard_map
 from ..dist.halo import RAGGED_EXCHANGES, get_exchange
 
 DAMPING = 0.85
@@ -637,12 +636,8 @@ def shard_map_gas(program: GASProgram, layout: PartitionLayout, mesh: Mesh,
     args = (dev,) if warm is None else (dev, warm)
     specs = tuple(jax.tree_util.tree_map(lambda _: spec, a) for a in args)
 
-    # the while_loop in the tol path has no shard_map replication rule
-    # on pinned jax — the residual is pmax'd, so every device agrees on
-    # the trip count and the check is safe to skip
-    @partial(shard_map, mesh=mesh, in_specs=specs,
-             out_specs=spec if tol is None else (spec, spec),
-             check_vma=tol is None)
+    @partial(jax.shard_map, mesh=mesh, in_specs=specs,
+             out_specs=spec if tol is None else (spec, spec))
     def run(dev, *warm_arg):
         dev = jax.tree_util.tree_map(lambda x: x[0], dev)
         value = program.init(dev)
@@ -650,6 +645,9 @@ def shard_map_gas(program: GASProgram, layout: PartitionLayout, mesh: Mesh,
             wvals, wmask = jax.tree_util.tree_map(lambda x: x[0],
                                                   warm_arg[0])
             value = jnp.where(wmask, wvals, value)
+        # a program whose init ignores the layout (degree's zeros) starts
+        # the same on every device; the loop carry must vary from step 0
+        value = coll.varying(value, axis)
         if not iters:
             return (value[None] if tol is None
                     else (value[None], jnp.zeros((1,), jnp.int32)))
@@ -883,11 +881,8 @@ def shard_map_gas_many(programs, layout: PartitionLayout, mesh: Mesh,
     args = (dev,) if warm is None else (dev, warm)
     specs = tuple(jax.tree_util.tree_map(lambda _: spec, a) for a in args)
 
-    # see shard_map_gas: the tol while_loop needs the replication check
-    # off on pinned jax; the pmax'd residual keeps trip counts aligned
-    @partial(shard_map, mesh=mesh, in_specs=specs,
-             out_specs=spec if tol is None else (spec, spec),
-             check_vma=tol is None)
+    @partial(jax.shard_map, mesh=mesh, in_specs=specs,
+             out_specs=spec if tol is None else (spec, spec))
     def run(dev, *warm_arg):
         dev = jax.tree_util.tree_map(lambda x: x[0], dev)
         value = jnp.stack([p.init(dev) for p in fused.programs])
@@ -895,6 +890,9 @@ def shard_map_gas_many(programs, layout: PartitionLayout, mesh: Mesh,
             wvals, wmask = jax.tree_util.tree_map(lambda x: x[0],
                                                   warm_arg[0])
             value = jnp.where(wmask, wvals, value)
+        # a program whose init ignores the layout (degree's zeros) starts
+        # the same on every device; the loop carry must vary from step 0
+        value = coll.varying(value, axis)
         if not iters:
             return (value[None] if tol is None
                     else (value[None], jnp.zeros((1,), jnp.int32)))
@@ -940,7 +938,7 @@ def gas_step_for_dryrun(program, layout: PartitionLayout,
     fused = (None if isinstance(program, GASProgram)
              else fuse_programs(program))
 
-    @partial(shard_map, mesh=mesh,
+    @partial(jax.shard_map, mesh=mesh,
              in_specs=(jax.tree_util.tree_map(lambda _: spec, dev),),
              out_specs=spec)
     def step(dev):

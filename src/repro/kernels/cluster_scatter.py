@@ -27,6 +27,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .platform import by_platform
 
 
 def edge_decisions(cu0, cv0, d0, d1, vg0, vg1, live, nid, nid0,
@@ -141,18 +144,27 @@ def edge_decisions(cu0, cv0, d0, d1, vg0, vg1, live, nid, nid0,
 def _cluster_kernel(ints_ref, buf_ref, scal_ref, vmax_ref,
                     buf_out, scal_out, pk_out, *, B: int,
                     allow_split: bool, split_degree_factor: float):
-    # the whole block table stays resident in the output block for the
-    # full edge loop — the fused 8-lane scatter becomes eight in-memory
-    # read-modify-writes (duplicate lanes accumulate, matching .at[].add)
-    buf_out[...] = buf_ref[...]
+    # every operand lives in SMEM: the transition is scalar work on
+    # computed indices, which the TPU's scalar unit does with plain loads
+    # and stores, while a vector memory would need a masked vector op per
+    # table entry.  The whole block table stays resident in the output
+    # block for the full edge loop — the fused 8-lane scatter becomes
+    # eight in-memory read-modify-writes (duplicate lanes accumulate,
+    # matching .at[].add).  ``ints`` arrives flattened column-major:
+    # [0, B) local u slots, [B, 2B) local v slots, [2B, 3B) live flags.
+    def copy(j, c):
+        buf_out[j] = buf_ref[j]
+        return c
+
+    jax.lax.fori_loop(0, 10 * B, copy, 0)
     vmax = vmax_ref[0]
     scrap = 6 * B - 1
 
     def body(i, carry):
         nid, nid0, seen_v, seen_deg = carry
-        lu = ints_ref[i, 0]
-        lv_ = ints_ref[i, 1]
-        live = ints_ref[i, 2] != 0
+        lu = ints_ref[i]
+        lv_ = ints_ref[B + i]
+        live = ints_ref[2 * B + i] != 0
         cu0 = buf_out[lu]
         cv0 = buf_out[lv_]
         d0 = buf_out[2 * B + lu]
@@ -187,38 +199,35 @@ def _cluster_kernel(ints_ref, buf_ref, scal_ref, vmax_ref,
 
 def cluster_scatter(ints, buf, scal, vmax, *, allow_split: bool = True,
                     split_degree_factor: float = 0.0,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """One block of the clustering scan: ``ints`` (B, 3) int32 rows of
     (local u slot, local v slot, live); ``buf`` (10B,) int32 fused block
     table; ``scal`` (4,) int32 = (nid, nid0, seen_v, seen_deg); ``vmax``
     python float or traced scalar.  Returns (buf', scal', packed (B,))
     with ``packed`` the per-edge split events (fire_u + 2·fire_v) —
-    bit-identical to the XLA inner scan at any input."""
+    bit-identical to the XLA inner scan at any input.  ``interpret``
+    as in ``kernels.platform.by_platform``."""
     B = ints.shape[0]
     assert buf.shape == (10 * B,), (buf.shape, B)
     vmax_arr = jnp.asarray(vmax, jnp.float32).reshape((1,))
     kern = functools.partial(
         _cluster_kernel, B=int(B), allow_split=bool(allow_split),
         split_degree_factor=float(split_degree_factor))
-    return pl.pallas_call(
-        kern,
-        grid=(1,),
-        in_specs=[
-            pl.BlockSpec((B, 3), lambda i: (0, 0)),
-            pl.BlockSpec((10 * B,), lambda i: (0,)),
-            pl.BlockSpec((4,), lambda i: (0,)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((10 * B,), lambda i: (0,)),
-            pl.BlockSpec((4,), lambda i: (0,)),
-            pl.BlockSpec((B,), lambda i: (0,)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((10 * B,), jnp.int32),
-            jax.ShapeDtypeStruct((4,), jnp.int32),
-            jax.ShapeDtypeStruct((B,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(jnp.asarray(ints, jnp.int32), buf, jnp.asarray(scal, jnp.int32),
-      vmax_arr)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+    def call(*args, interpret: bool):
+        return pl.pallas_call(
+            kern,
+            in_specs=[smem, smem, smem, smem],
+            out_specs=[smem, smem, smem],
+            out_shape=[
+                jax.ShapeDtypeStruct((10 * B,), jnp.int32),
+                jax.ShapeDtypeStruct((4,), jnp.int32),
+                jax.ShapeDtypeStruct((B,), jnp.int32),
+            ],
+            interpret=interpret,
+        )(*args)
+
+    return by_platform(call, jnp.asarray(ints, jnp.int32).T.reshape(-1),
+                       buf, jnp.asarray(scal, jnp.int32), vmax_arr,
+                       interpret=interpret)

@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import ARCHS, get_config
 from repro.dist.halo import EXCHANGE_NAMES
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import SHAPES, cell_is_skipped, input_specs
 from repro.dist.sharding import (CP_SERVE_RULES, MULTI_POD_RULES,
@@ -728,6 +729,7 @@ def main():
     ap.add_argument("--loss-chunk", type=int, default=512)
     ap.add_argument("--out", default=str(RESULTS / "dryrun"))
     args = ap.parse_args()
+    enable_compile_cache()
 
     out_dir = Path(args.out)
     if args.graph:

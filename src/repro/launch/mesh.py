@@ -3,29 +3,40 @@
 A FUNCTION, not a module constant — importing this module never touches
 jax device state.  Callers that need 512 host devices must set
 XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax import
-(launch/dryrun.py does; tests spawn subprocesses)."""
+(launch/dryrun.py does; tests spawn subprocesses).
+
+Every mesh here has ``Auto`` axes.  The LM stack places arrays with
+``with_sharding_constraint`` under a rule table (``dist.sharding``), which
+an ``Explicit`` axis (``jax.make_mesh``'s default) refuses; the graph
+meshes are entered only by ``shard_map``, which takes every axis into
+manual control whatever its type."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(n_data: int = 2, n_model: int = 4):
     """Small mesh for multi-device subprocess tests (8 host devices)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_graph_mesh(k: int):
     """The graph engine's mesh: k partitions on one flat axis."""
-    return jax.make_mesh((k,), ("parts",))
+    return _auto_mesh((k,), ("parts",))
 
 
 def make_stream_mesh(n: int):
     """The sharded partitioner's mesh: n stream slices on one flat axis
     (repro.core.partitioner backend="sharded", paper §III-C)."""
-    return jax.make_mesh((n,), ("stream",))
+    return _auto_mesh((n,), ("stream",))

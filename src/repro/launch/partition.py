@@ -136,8 +136,10 @@ def main():
 
     from repro.core import web_graph
     from repro.core.graphgen import social_graph
+    from repro.launch.compile_cache import enable_compile_cache
 
     validate_nodes(args)
+    enable_compile_cache()
 
     g = (web_graph(scale=args.scale, seed=args.seed) if args.graph == "web"
          else social_graph(n=1 << args.scale, seed=args.seed))

@@ -22,6 +22,8 @@ in-process (no sockets — the driver IS the event loop), then:
    server, checkpoints through ``dist.ft.ServiceFT``, and SIGKILLs its
    own process mid-serving; the parent resumes from the snapshot and
    asserts the identical config blob, assignment, and query replies.
+   The child runs to its death before the parent touches a backend: an
+   accelerator belongs to one process at a time.
 
 Writes ``results/BENCH_serve.json`` (query latency, RF trace summary)
 for ``benchmarks/trend.py`` to diff across PRs.
@@ -41,6 +43,7 @@ import numpy as np
 
 from repro.core import CLUGPConfig, web_graph
 from repro.dist.ft import ServiceFT
+from repro.launch.compile_cache import enable_compile_cache
 from repro.serve import GraphServer
 from repro.session import GraphSession, SessionConfig
 
@@ -229,10 +232,10 @@ def child_snapshot(args) -> None:
     os.kill(os.getpid(), signal.SIGKILL)
 
 
-def kill_resume_check(args) -> None:
-    """Spawn the child, verify it died by SIGKILL, resume from its
-    snapshot, and assert the partition state is identical to the
-    deterministic reference."""
+def run_snapshot_child(args) -> None:
+    """Run the preemption victim to its death and verify it died by
+    SIGKILL.  Called before this process initialises a backend, so the
+    child can have the accelerator to itself."""
     cmd = [sys.executable, "-m", "repro.launch.serve_graph",
            "--child-snapshot", "--ckpt-dir", args.ckpt_dir,
            "--scale", str(args.scale), "--k", str(args.k),
@@ -245,6 +248,11 @@ def kill_resume_check(args) -> None:
     assert proc.returncode == -signal.SIGKILL, (
         f"child expected to die by SIGKILL, got {proc.returncode}:\n"
         f"{proc.stdout}{proc.stderr}")
+
+
+def resume_check(args) -> None:
+    """Resume from the dead child's snapshot and assert the partition
+    state is identical to the deterministic reference."""
     ref = build_server(args)
     srv = GraphServer.resume(ServiceFT(args.ckpt_dir), tol=args.tol)
     assert srv.sess.to_json() == ref.sess.to_json(), "config blob drifted"
@@ -287,11 +295,15 @@ def main() -> int:
     ap.add_argument("--out", default=None,
                     help="override results/BENCH_serve.json")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.child_snapshot:
         child_snapshot(args)
         return 0                    # unreachable — SIGKILL above
 
+    preempt = bool(args.ckpt_dir and args.smoke)
+    if preempt:
+        run_snapshot_child(args)
     srv = build_server(args)
     q = drive_queries(srv, args, check=args.smoke)
     ing = drive_ingest(srv, args)
@@ -308,8 +320,8 @@ def main() -> int:
         print(f"[serve] drift {ing['rf_drifted']:.3f} repaired to "
               f"{ing['rf_post_restream']:.3f} over {ing['restreams']} "
               f"restream(s)")
-    if args.ckpt_dir and args.smoke:
-        kill_resume_check(args)
+    if preempt:
+        resume_check(args)
 
     row = {"bench": "serve", "scale": args.scale, "k": args.k,
            "exchange": args.exchange, "window": args.window,

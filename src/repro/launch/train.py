@@ -17,6 +17,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, batch_at
 from repro.dist.ft import FTConfig, run as ft_run
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.train import (cosine_schedule, get_optimizer, make_train_step)
 
@@ -44,6 +45,7 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -249,30 +249,29 @@ def test_static_offset_scatter_not_flagged():
 
 def test_unreduced_divergence_planted_and_reduced():
     from jax.sharding import Mesh, PartitionSpec as P
-    from repro.dist._compat import shard_map
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("parts",))
 
     def bad(x):
         return x.sum()                       # per-shard partial sum
 
-    sm_bad = shard_map(bad, mesh=mesh, in_specs=P("parts"),
-                       out_specs=P(), check_rep=False)
+    sm_bad = jax.shard_map(bad, mesh=mesh, in_specs=P("parts"),
+                           out_specs=P(), check_vma=False)
     div = ir.unreduced_divergence(sm_bad, jnp.ones(8))
     assert [d["output"] for d in div] == [0], div
 
     def good(x):
         return jax.lax.psum(x.sum(), "parts")
 
-    sm_good = shard_map(good, mesh=mesh, in_specs=P("parts"),
-                        out_specs=P(), check_rep=False)
+    sm_good = jax.shard_map(good, mesh=mesh, in_specs=P("parts"),
+                            out_specs=P(), check_vma=False)
     assert ir.unreduced_divergence(sm_good, jnp.ones(8)) == []
 
     def sharded_out(x):
         return x * 2                         # varying but declared so
 
-    sm_ok = shard_map(sharded_out, mesh=mesh, in_specs=P("parts"),
-                      out_specs=P("parts"), check_rep=False)
+    sm_ok = jax.shard_map(sharded_out, mesh=mesh, in_specs=P("parts"),
+                          out_specs=P("parts"), check_vma=False)
     assert ir.unreduced_divergence(sm_ok, jnp.ones(8)) == []
 
 

@@ -36,6 +36,7 @@ def test_shard_map_pagerank_halo_matches_dense(multidevice):
     from repro.core import web_graph, partition, CLUGPConfig
     from repro.graph import (build_layout, shard_map_pagerank,
                              pagerank_step_for_dryrun, reference_pagerank)
+    from repro.analysis.ir import hlo_collectives
     from repro.launch.mesh import make_graph_mesh
 
     g = web_graph(scale=10, edge_factor=6, seed=3)
@@ -51,9 +52,9 @@ def test_shard_map_pagerank_halo_matches_dense(multidevice):
 
     jitted, args = pagerank_step_for_dryrun(lay, mesh, exchange='halo')
     hlo = jitted.lower(*args).compile().as_text()
-    lhs = [l.split(' = ')[0] for l in hlo.splitlines() if ' = ' in l]
-    assert any('all-to-all' in h for h in lhs), 'halo must use all_to_all'
-    assert not any('all-gather' in h for h in lhs), 'halo must not gather'
+    kinds = [kind for kind, _, _ in hlo_collectives(hlo)]
+    assert 'all-to-all' in kinds, 'halo must use all_to_all'
+    assert 'all-gather' not in kinds, 'halo must not gather'
     print('halo shard_map ok')
     """)
 
@@ -70,6 +71,7 @@ def test_shard_map_cc_and_quantized_match_reference(multidevice):
                              simulate_cc, simulate_pagerank,
                              pagerank_step_for_dryrun, reference_cc,
                              reference_pagerank)
+    from repro.analysis.ir import hlo_collectives
     from repro.launch.mesh import make_graph_mesh
 
     g = web_graph(scale=10, edge_factor=6, seed=3)
@@ -93,12 +95,11 @@ def test_shard_map_cc_and_quantized_match_reference(multidevice):
 
     jitted, args = pagerank_step_for_dryrun(lay, mesh, exchange='quantized')
     hlo = jitted.lower(*args).compile().as_text()
-    coll = [line for line in hlo.splitlines()
-            if line.strip().lstrip('%').startswith(
-                ('all-to-all', 'all-gather'))]
-    assert any('s8[' in line for line in coll), 'int8 lanes must ship'
-    assert not any(line.strip().lstrip('%').startswith('all-gather')
-                   for line in coll), 'quantized must not all-gather'
+    coll = [(kind, out) for kind, _, out in hlo_collectives(hlo)]
+    assert any(kind == 'all-to-all' and 's8[' in out
+               for kind, out in coll), 'int8 lanes must ship'
+    assert not any(kind == 'all-gather' for kind, _ in coll), \\
+        'quantized must not all-gather'
     print('cc + quantized shard_map ok')
     """)
 
@@ -118,6 +119,7 @@ def test_shard_map_ragged_ring_matches_and_ships_fewer_bytes(multidevice):
                              simulate_cc, simulate_pagerank,
                              pagerank_step_for_dryrun, reference_cc,
                              reference_pagerank)
+    from repro.analysis.ir import hlo_collectives
     from repro.launch.mesh import make_graph_mesh
 
     g = web_graph(scale=10, edge_factor=6, seed=3)
@@ -146,11 +148,10 @@ def test_shard_map_ragged_ring_matches_and_ships_fewer_bytes(multidevice):
 
     jitted, args = pagerank_step_for_dryrun(lay, mesh, exchange='ragged')
     hlo = jitted.lower(*args).compile().as_text()
-    lhs = [l.split(' = ')[0] for l in hlo.splitlines() if ' = ' in l]
-    assert any('collective-permute' in h for h in lhs), \\
-        'ragged must ppermute'
-    assert not any('all-to-all' in h for h in lhs)
-    assert not any('all-gather' in h for h in lhs)
+    kinds = [kind for kind, _, _ in hlo_collectives(hlo)]
+    assert 'collective-permute' in kinds, 'ragged must ppermute'
+    assert 'all-to-all' not in kinds
+    assert 'all-gather' not in kinds
 
     assert lay.comm_bytes('ragged') < lay.comm_bytes('halo')
     assert lay.comm_bytes('ragged_quantized', lossy=True) < \\
@@ -172,6 +173,7 @@ def test_shard_map_fused_many_matches_simulation(multidevice):
                              reference_centrality, reference_pagerank,
                              reference_ppr, shard_map_gas_many,
                              simulate_gas_many)
+    from repro.analysis.ir import hlo_collectives
     from repro.launch.mesh import make_graph_mesh
 
     g = web_graph(scale=10, edge_factor=6, seed=3)
@@ -207,11 +209,9 @@ def test_shard_map_fused_many_matches_simulation(multidevice):
     jitted, args = gas_step_for_dryrun(progs, lay, mesh,
                                        exchange='quantized')
     hlo = jitted.lower(*args).compile().as_text()
-    lhs = [line.split(' = ')[0] for line in hlo.splitlines()
-           if ' = ' in line]
-    n_a2a = sum('all-to-all' in h for h in lhs)
-    assert n_a2a == 4, n_a2a
-    assert not any('all-gather' in h for h in lhs)
+    kinds = [kind for kind, _, _ in hlo_collectives(hlo)]
+    assert kinds.count('all-to-all') == 4, kinds
+    assert 'all-gather' not in kinds
 
     z = shard_map_gas_many(progs, lay, mesh, iters=0, exchange='halo')
     V = g.num_vertices

@@ -12,6 +12,7 @@ from repro.dist.compress import (compress_with_error_feedback,
 from repro.dist.sharding import (CP_SERVE_RULES, MULTI_POD_RULES,
                                  SINGLE_POD_RULES, active_rules,
                                  resolve_spec, shard, use_rules)
+from repro.launch.mesh import make_test_mesh
 
 SINGLE_AXES = {"data": 2, "model": 4}
 MULTI_AXES = {"pod": 2, "data": 2, "model": 4}
@@ -78,7 +79,7 @@ def test_shard_identity_without_context_and_applies_with_context():
     x = jnp.ones((4, 8))
     assert active_rules() is None
     assert shard(x, "batch", None) is x          # no context → no-op
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh(1, 1)
     with use_rules(SINGLE_POD_RULES, mesh):
         assert active_rules() == (SINGLE_POD_RULES, mesh)
         y = shard(x, "batch", "vocab")
@@ -89,7 +90,7 @@ def test_shard_identity_without_context_and_applies_with_context():
 
 
 def test_use_rules_nesting_innermost_wins():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_test_mesh(1, 1)
     with use_rules(SINGLE_POD_RULES, mesh):
         with use_rules(CP_SERVE_RULES, mesh):
             assert active_rules()[0] is CP_SERVE_RULES

@@ -221,6 +221,32 @@ def test_cluster_kernel_full_stream_matches_xla():
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
+# ------------------------------------------------- greedy transform
+
+@pytest.mark.parametrize("cap,dead", [
+    (1.1, 0.0),     # the usual τ: few partitions fill
+    (0.3, 0.0),     # every partition fills: the least-loaded fallback
+    (1.1, 0.25),    # dead (padding) lanes, as on the sharded backend
+])
+def test_greedy_transform_matches_scan(cap, dead):
+    """The Alg. 1 kernel (what a TPU runs) equals the lax.scan (what
+    every other platform runs) bit for bit, across several edge blocks
+    whose load table carries from block to block."""
+    from repro.core.transform import _transform_scan
+    from repro.kernels.greedy_transform import BLOCK, greedy_transform
+    rng = np.random.default_rng(0)
+    E, k = 2 * BLOCK + 517, 8
+    cols = [rng.integers(0, k, E), rng.integers(0, k, E),
+            rng.integers(1, 40, E), rng.integers(1, 40, E),
+            rng.integers(0, 2, E), rng.integers(0, 2, E),
+            (rng.random(E) >= dead).astype(np.int64)]
+    cols = [jnp.asarray(c, jnp.int32) for c in cols]
+    lmax = jnp.float32(cap * E / k)
+    got = greedy_transform(*cols, lmax, k=k, interpret=True)
+    want = _transform_scan(*cols, lmax, k=k)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_resolve_cluster_kernel():
     from repro.core.stages import resolve_cluster_kernel
     assert resolve_cluster_kernel("pallas") == "pallas"
