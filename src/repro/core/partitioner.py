@@ -36,6 +36,7 @@ is ``repro.session.GraphSession``.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from functools import lru_cache, partial
 from typing import NamedTuple
 
@@ -50,6 +51,7 @@ from .pipeline import CLUGPConfig, CLUGPResult
 from .stages import (HOST_STAGES, JAX_STAGES, StageCtx, resolve_game_mode,
                      restream_loop, run_clugp_body)
 from . import metrics
+from .. import obs
 
 BACKENDS = ("np", "jit", "sharded")
 _BLOCK = 256          # game-kernel block: m_cap pads to a multiple of this
@@ -79,13 +81,14 @@ def partition(src: np.ndarray, dst: np.ndarray, num_vertices: int,
         raise ValueError(
             f"unknown backend {backend!r}; expected one of {BACKENDS}")
     _check_stream(src)
-    if backend == "np":
-        if nodes <= 1:
-            return _run_np(src, dst, num_vertices, cfg)
-        return _run_np_nodes(src, dst, num_vertices, cfg, nodes)
-    if backend == "jit":
-        return _run_jit(src, dst, num_vertices, cfg)
-    return _run_sharded(src, dst, num_vertices, cfg, nodes, mesh)
+    with obs.span("partition", backend=backend):
+        if backend == "np":
+            if nodes <= 1:
+                return _run_np(src, dst, num_vertices, cfg)
+            return _run_np_nodes(src, dst, num_vertices, cfg, nodes)
+        if backend == "jit":
+            return _run_jit(src, dst, num_vertices, cfg)
+        return _run_sharded(src, dst, num_vertices, cfg, nodes, mesh)
 
 
 # ------------------------------------------------------------- np strategy
@@ -241,25 +244,32 @@ def _run_jit(src: np.ndarray, dst: np.ndarray, num_vertices: int,
     E = src.shape[0]
     vmax = _resolve_vmax(cfg, E)
     caps = _init_caps(num_vertices, E)
-    while True:
-        out = _jit_body(
-            jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32),
-            num_vertices=num_vertices, cfg=cfg, vmax=float(vmax),
-            game_mode=resolve_game_mode(cfg.kernel, caps.m_cap),
-            id_cap=caps.id_cap, m_cap=caps.m_cap, nnz_cap=caps.nnz_cap)
-        caps, ok = _grow_caps(caps, next_id=int(out[-1]), m=int(out[5]),
-                              overflow=bool(out[-2]),
-                              num_vertices=num_vertices, e_per=E)
+    for attempt in itertools.count():
+        # a span per run of the body: it ends where the caps are read
+        # back, which waits for the device
+        with obs.span("partition.attempt", attempt=attempt,
+                      **caps._asdict()):
+            out = _jit_body(
+                jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32),
+                num_vertices=num_vertices, cfg=cfg, vmax=float(vmax),
+                game_mode=resolve_game_mode(cfg.kernel, caps.m_cap),
+                id_cap=caps.id_cap, m_cap=caps.m_cap, nnz_cap=caps.nnz_cap)
+            caps, ok = _grow_caps(caps, next_id=int(out[-1]), m=int(out[5]),
+                                  overflow=bool(out[-2]),
+                                  num_vertices=num_vertices, e_per=E)
         if ok:
             break
-    assign, compact, deg, divided, replicas, m, rounds, cluster_assign = (
-        np.asarray(x) for x in out[:-2])
+    with obs.span("partition.fetch"):
+        (assign, compact, deg, divided, replicas, m, rounds,
+         cluster_assign) = (np.asarray(x) for x in out[:-2])
     m = int(m)
     rounds = int(rounds)
     clus = ClusteringResult(compact, deg, divided, replicas, m)
-    cg = contract(src, dst, compact)
+    with obs.span("partition.contract"):
+        cg = contract(src, dst, compact)
     res = CLUGPResult(assign, clus, cg, cluster_assign[:m], rounds)
-    res.stats = metrics.summarize(src, dst, assign, num_vertices, cfg.k)
+    with obs.span("partition.summary"):
+        res.stats = metrics.summarize(src, dst, assign, num_vertices, cfg.k)
     res.stats["num_clusters"] = m
     res.stats["game_rounds"] = rounds
     res.stats["backend"] = "jit"
@@ -428,26 +438,31 @@ def _run_sharded(src: np.ndarray, dst: np.ndarray, num_vertices: int,
     mask = np.zeros(e_pad, dtype=bool)
     src_p[:E], dst_p[:E], mask[:E] = src, dst, True
     caps = _init_caps(num_vertices, e_per)
-    while True:
-        run = _make_sharded_fn(
-            mesh, e_per, num_vertices, cfg,
-            resolve_game_mode(cfg.kernel, caps.m_cap),
-            caps.id_cap, caps.m_cap, caps.nnz_cap)
-        with mesh:
-            assign_p, m_locals, rounds_arr, next_ids, overflows = run(
-                jnp.asarray(src_p), jnp.asarray(dst_p), jnp.asarray(mask))
-        caps, ok = _grow_caps(
-            caps, next_id=int(np.asarray(next_ids).max()),
-            m=int(np.asarray(m_locals).max()),
-            overflow=int(np.asarray(overflows).max()) > 0,
-            num_vertices=num_vertices, e_per=e_per)
+    for attempt in itertools.count():
+        with obs.span("partition.attempt", attempt=attempt,
+                      **caps._asdict()):
+            run = _make_sharded_fn(
+                mesh, e_per, num_vertices, cfg,
+                resolve_game_mode(cfg.kernel, caps.m_cap),
+                caps.id_cap, caps.m_cap, caps.nnz_cap)
+            with mesh:
+                assign_p, m_locals, rounds_arr, next_ids, overflows = run(
+                    jnp.asarray(src_p), jnp.asarray(dst_p),
+                    jnp.asarray(mask))
+            caps, ok = _grow_caps(
+                caps, next_id=int(np.asarray(next_ids).max()),
+                m=int(np.asarray(m_locals).max()),
+                overflow=int(np.asarray(overflows).max()) > 0,
+                num_vertices=num_vertices, e_per=e_per)
         if ok:
             break
-    assign = np.asarray(assign_p)[:E]
-    m_locals = np.asarray(m_locals)
-    rounds = int(np.asarray(rounds_arr).max())
+    with obs.span("partition.fetch"):
+        assign = np.asarray(assign_p)[:E]
+        m_locals = np.asarray(m_locals)
+        rounds = int(np.asarray(rounds_arr).max())
     res = CLUGPResult(assign, None, None, None, rounds)
-    res.stats = metrics.summarize(src, dst, assign, num_vertices, cfg.k)
+    with obs.span("partition.summary"):
+        res.stats = metrics.summarize(src, dst, assign, num_vertices, cfg.k)
     res.stats["num_clusters"] = int(m_locals.sum())
     res.stats["game_rounds"] = rounds
     res.stats["backend"] = "sharded"

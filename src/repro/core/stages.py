@@ -159,13 +159,22 @@ def run_clugp_body(src, dst, ctx: StageCtx, cfg, stages: StageSet
     transform (→ restream) sequence exists.  Every backend strategy runs
     this exact function; they differ only in the ``stages`` adapters and
     what they put in ``ctx``."""
-    cstate = stages.cluster(src, dst, ctx, cfg)
-    gstate = stages.contract(src, dst, cstate, ctx, cfg)
-    cluster_assign, rounds, overflow = stages.game(gstate, ctx, cfg)
-    vp = stages.vertex_part(cluster_assign, cstate, ctx)
-    assign = stages.transform(src, dst, vp, cstate, ctx, cfg)
-    assign, trace = restream_loop(src, dst, assign, [(None, cstate, ctx)],
-                                  ctx, cfg, stages)
+    # each stage under its own named scope, which the device ops of a
+    # profile carry (on the host stages the scopes cost nothing)
+    with jax.named_scope("clugp/cluster"):
+        cstate = stages.cluster(src, dst, ctx, cfg)
+    with jax.named_scope("clugp/contract"):
+        gstate = stages.contract(src, dst, cstate, ctx, cfg)
+    with jax.named_scope("clugp/game"):
+        cluster_assign, rounds, overflow = stages.game(gstate, ctx, cfg)
+    with jax.named_scope("clugp/vertex_part"):
+        vp = stages.vertex_part(cluster_assign, cstate, ctx)
+    with jax.named_scope("clugp/transform"):
+        assign = stages.transform(src, dst, vp, cstate, ctx, cfg)
+    with jax.named_scope("clugp/restream"):
+        assign, trace = restream_loop(src, dst, assign,
+                                      [(None, cstate, ctx)], ctx, cfg,
+                                      stages)
     return PipelineOut(assign, cstate, gstate, cluster_assign, rounds,
                        overflow, trace)
 

@@ -54,6 +54,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .partition import PartitionLayout
+from .. import obs
 from ..dist import collectives as coll
 from ..dist.halo import RAGGED_EXCHANGES, get_exchange
 
@@ -433,7 +434,7 @@ def _gas_body(program: GASProgram, ex, dev, axis: str | None = None,
     same values, shorter critical path."""
     stacked = axis is None
 
-    def body(_, carry):
+    def step(carry):
         value, state = carry
         if program.aux is not None:
             aux = (jnp.sum(jax.vmap(program.aux)(value, dev)) if stacked
@@ -471,6 +472,15 @@ def _gas_body(program: GASProgram, ex, dev, axis: str | None = None,
                                                      program.combine, state)
         return value, state
 
+    return _scoped_body(step, program.name)
+
+
+def _scoped_body(step, name: str):
+    """A ``fori_loop`` body running ``step`` under the named scope
+    ``gas/<name>``, which the device ops of a profile carry."""
+    def body(_, carry):
+        with jax.named_scope("gas/" + name):
+            return step(carry)
     return body
 
 
@@ -564,9 +574,9 @@ def _sim_gas(program: GASProgram, dev, iters: int, ex,
     return _converge_loop(body, value, state, iters, tol, mask)
 
 
-def _collect_master_values(layout: PartitionLayout, stacked) -> np.ndarray:
-    """(k, L_max) per-device values → dense (V,) using master slots."""
-    vals = np.asarray(stacked)
+def _master_values(layout: PartitionLayout, vals: np.ndarray) -> np.ndarray:
+    """(k, L_max) per-device values on the host → dense (V,) using master
+    slots."""
     out = np.zeros(layout.num_vertices, dtype=vals.dtype)
     gid = layout.vert_gid
     sel = layout.is_master & layout.vert_mask
@@ -589,14 +599,20 @@ def simulate_gas(program: GASProgram, layout: PartitionLayout,
     ``_gas_body``).  ``init_values`` warm-starts from a dense (V_old,)
     value vector, e.g. a previously converged fixed point."""
     _check_overlap(exchange, overlap)
-    dev = _stack_dev(layout, exchange)
-    ex = get_exchange(exchange, layout)
-    warm = (None if init_values is None
-            else _warm_tables(layout, program.dtype, init_values))
-    out = _sim_gas(program, dev, iters, ex, tol, overlap, warm)
-    values, iters_run = (out, iters) if tol is None else out
-    dense = _collect_master_values(layout, values)
-    return (dense, int(iters_run)) if return_iters else dense
+    with obs.span("gas." + program.name) as run_span:
+        with obs.span("gas.upload"):
+            dev = _stack_dev(layout, exchange)
+            ex = get_exchange(exchange, layout)
+            warm = (None if init_values is None
+                    else _warm_tables(layout, program.dtype, init_values))
+        with obs.span("gas.run"):
+            out = _sim_gas(program, dev, iters, ex, tol, overlap, warm)
+            values, iters_run = (out, iters) if tol is None else out
+            vals, iters_run = np.asarray(values), int(iters_run)
+        with obs.span("gas.collect"):
+            dense = _master_values(layout, vals)
+        run_span.attrs["iters"] = iters_run
+    return (dense, iters_run) if return_iters else dense
 
 
 def simulate_pagerank(layout: PartitionLayout, iters: int = 30,
@@ -628,11 +644,27 @@ def shard_map_gas(program: GASProgram, layout: PartitionLayout, mesh: Mesh,
     ``simulate_gas`` — the residual is pmax'd across the mesh so every
     device exits the while_loop on the same iteration."""
     _check_overlap(exchange, overlap)
-    dev = _stack_dev(layout, exchange)
-    ex = get_exchange(exchange, layout, axis=axis)
+    with obs.span("gas." + program.name) as run_span:
+        with obs.span("gas.upload"):
+            dev = _stack_dev(layout, exchange)
+            ex = get_exchange(exchange, layout, axis=axis)
+            warm = (None if init_values is None
+                    else _warm_tables(layout, program.dtype, init_values))
+        with obs.span("gas.run"):
+            vals, iters_run = _shard_map_run(program, dev, warm, ex, mesh,
+                                             iters, axis, tol, overlap)
+        with obs.span("gas.collect"):
+            dense = _master_values(layout, vals)
+        run_span.attrs["iters"] = iters_run
+    return (dense, iters_run) if return_iters else dense
+
+
+def _shard_map_run(program: GASProgram, dev, warm, ex, mesh: Mesh,
+                   iters: int, axis: str, tol: float | None,
+                   overlap: bool) -> tuple:
+    """Dispatch the shard_map'd loop; (host (k, L_max) values, iterations
+    run) once both are back on the host."""
     spec = P(axis)
-    warm = (None if init_values is None
-            else _warm_tables(layout, program.dtype, init_values))
     args = (dev,) if warm is None else (dev, warm)
     specs = tuple(jax.tree_util.tree_map(lambda _: spec, a) for a in args)
 
@@ -664,10 +696,7 @@ def shard_map_gas(program: GASProgram, layout: PartitionLayout, mesh: Mesh,
     with mesh:
         out = run(*args)
     values, iters_run = (out, iters) if tol is None else out
-    dense = _collect_master_values(layout, values)
-    if return_iters:
-        return dense, int(np.asarray(iters_run).reshape(-1)[0])
-    return dense
+    return np.asarray(values), int(np.asarray(iters_run).reshape(-1)[0])
 
 
 def shard_map_pagerank(layout: PartitionLayout, mesh: Mesh,
@@ -757,7 +786,7 @@ def _gas_body_multi(fused: FusedGAS, ex, dev, axis: str | None = None,
                 auxes[i] = per[j]
         return auxes
 
-    def body(_, carry):
+    def step(carry):
         value, state = carry
         auxes = global_aux(value)
         if stacked:
@@ -806,7 +835,7 @@ def _gas_body_multi(fused: FusedGAS, ex, dev, axis: str | None = None,
                 new_master, dev, fused.combine, state)
         return value, state
 
-    return body
+    return _scoped_body(step, fused.name)
 
 
 @partial(jax.jit,
@@ -851,15 +880,21 @@ def simulate_gas_many(programs, layout: PartitionLayout, iters: int = 30,
     per-program ``init_values`` as in ``simulate_gas``."""
     _check_overlap(exchange, overlap)
     fused = fuse_programs(programs)
-    dev = _stack_dev(layout, exchange)
-    ex = get_exchange(exchange, layout)
-    warm = (None if init_values is None
-            else _warm_tables_many(layout, fused, init_values))
-    out = _sim_gas_many(fused, dev, iters, ex, tol, overlap, warm)
-    values, iters_run = (out, iters) if tol is None else out
-    dense = [_collect_master_values(layout, values[:, i])
-             for i in range(len(fused.programs))]
-    return (dense, int(iters_run)) if return_iters else dense
+    with obs.span("gas." + fused.name) as run_span:
+        with obs.span("gas.upload"):
+            dev = _stack_dev(layout, exchange)
+            ex = get_exchange(exchange, layout)
+            warm = (None if init_values is None
+                    else _warm_tables_many(layout, fused, init_values))
+        with obs.span("gas.run"):
+            out = _sim_gas_many(fused, dev, iters, ex, tol, overlap, warm)
+            values, iters_run = (out, iters) if tol is None else out
+            vals, iters_run = np.asarray(values), int(iters_run)
+        with obs.span("gas.collect"):
+            dense = [_master_values(layout, vals[:, i])
+                     for i in range(len(fused.programs))]
+        run_span.attrs["iters"] = iters_run
+    return (dense, iters_run) if return_iters else dense
 
 
 def shard_map_gas_many(programs, layout: PartitionLayout, mesh: Mesh,
@@ -873,11 +908,28 @@ def shard_map_gas_many(programs, layout: PartitionLayout, mesh: Mesh,
     ``simulate_gas_many``."""
     _check_overlap(exchange, overlap)
     fused = fuse_programs(programs)
-    dev = _stack_dev(layout, exchange)
-    ex = get_exchange(exchange, layout, axis=axis)
+    with obs.span("gas." + fused.name) as run_span:
+        with obs.span("gas.upload"):
+            dev = _stack_dev(layout, exchange)
+            ex = get_exchange(exchange, layout, axis=axis)
+            warm = (None if init_values is None
+                    else _warm_tables_many(layout, fused, init_values))
+        with obs.span("gas.run"):
+            vals, iters_run = _shard_map_run_many(fused, dev, warm, ex, mesh,
+                                                  iters, axis, tol, overlap)
+        with obs.span("gas.collect"):
+            dense = [_master_values(layout, vals[:, i])
+                     for i in range(len(fused.programs))]
+        run_span.attrs["iters"] = iters_run
+    return (dense, iters_run) if return_iters else dense
+
+
+def _shard_map_run_many(fused: FusedGAS, dev, warm, ex, mesh: Mesh,
+                        iters: int, axis: str, tol: float | None,
+                        overlap: bool) -> tuple:
+    """``_shard_map_run`` for a fused bundle: (host (k, N, L_max) values,
+    iterations run)."""
     spec = P(axis)
-    warm = (None if init_values is None
-            else _warm_tables_many(layout, fused, init_values))
     args = (dev,) if warm is None else (dev, warm)
     specs = tuple(jax.tree_util.tree_map(lambda _: spec, a) for a in args)
 
@@ -910,11 +962,7 @@ def shard_map_gas_many(programs, layout: PartitionLayout, mesh: Mesh,
     with mesh:
         out = run(*args)
     values, iters_run = (out, iters) if tol is None else out
-    dense = [_collect_master_values(layout, values[:, i])
-             for i in range(len(fused.programs))]
-    if return_iters:
-        return dense, int(np.asarray(iters_run).reshape(-1)[0])
-    return dense
+    return np.asarray(values), int(np.asarray(iters_run).reshape(-1)[0])
 
 
 def gas_step_for_dryrun(program, layout: PartitionLayout,

@@ -54,6 +54,8 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from .. import obs
+
 
 @dataclass
 class PartitionLayout:
@@ -354,6 +356,13 @@ def build_layout(src: np.ndarray, dst: np.ndarray, assign: np.ndarray,
     partitioner backends hand their edge→partition assignment straight in
     and the single ``np.asarray`` below is the only host transfer — no
     per-edge host loop ever touches the assignment."""
+    with obs.span("layout.build", k=k):
+        return _build_layout(src, dst, assign, num_vertices, k,
+                             pad_multiple)
+
+
+def _build_layout(src, dst, assign, num_vertices: int, k: int,
+                  pad_multiple: int) -> PartitionLayout:
     E = src.shape[0]
     src = np.asarray(src, dtype=np.int64)
     dst = np.asarray(dst, dtype=np.int64)

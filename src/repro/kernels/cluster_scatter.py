@@ -220,6 +220,7 @@ def cluster_scatter(ints, buf, scal, vmax, *, allow_split: bool = True,
             kern,
             in_specs=[smem, smem, smem, smem],
             out_specs=[smem, smem, smem],
+            name="cluster_scatter",
             out_shape=[
                 jax.ShapeDtypeStruct((10 * B,), jnp.int32),
                 jax.ShapeDtypeStruct((4,), jnp.int32),

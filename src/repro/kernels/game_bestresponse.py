@@ -87,6 +87,7 @@ def game_bestresponse(aff, sizes, row_tot, cur, loads, *, lam,
                 pl.BlockSpec(memory_space=pltpu.SMEM),
             ],
             out_specs=[row, row],
+            name="game_bestresponse",
             out_shape=[
                 jax.ShapeDtypeStruct((1, M), jnp.int32),
                 jax.ShapeDtypeStruct((1, M), jnp.float32),

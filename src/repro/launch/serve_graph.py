@@ -332,7 +332,9 @@ def main() -> int:
            "rf_post_restream": round(ing["rf_post_restream"], 4)
            if ing["rf_post_restream"] is not None else None,
            "restreams": ing["restreams"],
-           "ingested_edges": ing["ingested_edges"]}
+           "ingested_edges": ing["ingested_edges"],
+           # compilations under the server's steps and flushes
+           "compiles": srv.stats["compiles"]}
     rows = [row]
     if args.tol is not None:
         # pre-ingest row + one post-ingest row per temperature; the

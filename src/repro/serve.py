@@ -35,6 +35,7 @@ the whole service is testable under pytest and CI.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import queue
 import time
@@ -42,6 +43,7 @@ from typing import Any
 
 import numpy as np
 
+from . import obs
 from .core import metrics
 from .core.stages import incremental_assign, restream_assign
 from .session import GraphSession, resolve_program
@@ -106,7 +108,8 @@ class GraphServer:
         self.rf_base = self._rf_now()
         self.rf_trace: list = [("start", self.rf_base)]
         self.stats = {"queries": 0, "microbatches": 0, "ingested_edges": 0,
-                      "windows": 0, "restreams": 0, "stragglers": 0}
+                      "windows": 0, "restreams": 0, "stragglers": 0,
+                      "compiles": 0}
 
     # ---------------------------------------------------------- queries
 
@@ -151,9 +154,16 @@ class GraphServer:
                 break
         if not batch:
             return 0
+        with self._span("serve.step", requests=len(batch)) as span:
+            span.attrs["cells"] = self._serve(batch)
+        return len(batch)
+
+    def _serve(self, batch: list) -> int:
+        """Answer one drained microbatch; returns the wire cells run."""
         t0 = time.perf_counter()
         self._ensure_host_tables()
         needed: dict = {}
+        cells: dict = {}
         resolved = []
         for ticket, kind, program, verts, exchange in batch:
             key = None
@@ -170,7 +180,6 @@ class GraphServer:
                     needed[key] = (prog, ex)
             resolved.append((ticket, kind, key, verts))
         if needed:
-            cells: dict = {}
             for key, (prog, ex) in needed.items():
                 cell = (prog.combine, np.dtype(prog.dtype).name, ex)
                 cells.setdefault(cell, []).append(prog)
@@ -205,7 +214,7 @@ class GraphServer:
             self.stats["stragglers"] += 1
         self.stats["microbatches"] += 1
         self.stats["queries"] += len(batch)
-        return len(batch)
+        return len(cells)
 
     def serve_pending(self) -> int:
         """Drain the whole queue (microbatch by microbatch)."""
@@ -269,6 +278,11 @@ class GraphServer:
         grown stream.  Past the RF watermark this triggers a restream."""
         if self._buffered == 0:
             return False
+        with self._span("serve.flush", edges=self._buffered):
+            self._flush()
+        return True
+
+    def _flush(self) -> None:
         ws = np.concatenate(self._buf_src)
         wd = np.concatenate(self._buf_dst)
         self._buf_src, self._buf_dst, self._buffered = [], [], 0
@@ -285,7 +299,6 @@ class GraphServer:
         self.rf_trace.append(("window", rf_now))
         if rf_now > self.rf_watermark * self.rf_base:
             self.restream()
-        return True
 
     def restream(self, passes: int | None = None) -> tuple:
         """Repair drift: prioritized restream of the WHOLE resident
@@ -307,7 +320,9 @@ class GraphServer:
         # single-threaded, so a microbatch only ever sees the layout
         # fully rebuilt (layout() raises before a half-built state could
         # be cached) and freshly invalidated value/host tables
-        self.sess.with_partition(src, dst, num_vertices, assign).layout()
+        with obs.span("serve.swap", edges=int(src.shape[0])):
+            self.sess.with_partition(src, dst, num_vertices,
+                                     assign).layout()
         # the outgoing fixed points become warm-start seeds for the
         # grown graph (values are dense (V,) keyed by gid, so they
         # survive the remap; new vertices fall back to program init)
@@ -315,6 +330,17 @@ class GraphServer:
         self._values.clear()
         self._csr = None
         self._owner_of = None
+
+    @contextlib.contextmanager
+    def _span(self, name: str, **attrs):
+        """``obs.span`` around a server entry point; the compilations JAX
+        made while it was open go to ``stats["compiles"]``."""
+        before = obs.compilations()
+        try:
+            with obs.span(name, **attrs) as span:
+                yield span
+        finally:
+            self.stats["compiles"] += obs.compilations() - before
 
     def _rf_now(self) -> float:
         src, dst = self.sess.edges

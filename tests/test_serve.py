@@ -7,6 +7,9 @@ watermark restream leaves RF ≤ the drifted RF (the restream repair is
 monotone by construction); a server rebuilt from its ``ServiceFT``
 snapshot carries the identical config blob, edges, and assignment.
 """
+import time
+
+import jax
 import numpy as np
 import pytest
 
@@ -14,6 +17,7 @@ from conftest import random_graph_and_assign
 
 from repro.core import (CLUGPConfig, incremental_assign, metrics,
                         restream_assign, stream_state, web_graph)
+from repro import obs
 from repro.dist.ft import ServiceFT
 from repro.serve import QUERY_KINDS, GraphServer
 from repro.session import GraphSession, SessionConfig
@@ -224,6 +228,43 @@ def test_tol_server_cold_and_warm_share_compute_semantics():
         ["pagerank"], iters=40, exchange="halo", tol=1e-6,
         init_values=[np.zeros(0)], return_iters=True)
     assert np.array_equal(srv.result(t).value, direct[0][verts])
+
+
+def test_flush_compilations_show_under_the_step_that_meets_them():
+    """A flush grows the graph and so the layout's table shapes.  The
+    flush runs on the host and compiles nothing itself: the compilations
+    its new shapes bring are recorded under the first ``serve.step``
+    after it, and counted in ``stats["compiles"]``."""
+    jax.clear_caches()
+    srv, g = make_server(window=400, rf_watermark=1.01, restream_passes=2)
+    srv.submit("score", program="pagerank", vertices=[0])
+    srv.step()
+    before = srv.stats["compiles"]
+    assert before >= 1                  # the first step compiled its cell
+    shapes = (srv.sess.partition_layout.e_max,
+              srv.sess.partition_layout.l_max)
+    t0 = time.perf_counter()
+    rng = np.random.default_rng(11)
+    n = g.num_vertices
+    for _ in range(4):
+        srv.ingest(rng.integers(0, n, 110), rng.integers(0, n, 110))
+    assert srv.stats["windows"] == 1
+    assert (srv.sess.partition_layout.e_max,
+            srv.sess.partition_layout.l_max) != shapes
+    assert srv.stats["compiles"] == before
+    t = srv.submit("score", program="pagerank", vertices=[0])
+    srv.step()
+    assert srv.result(t).error is None
+    assert srv.stats["compiles"] > before
+    recs = obs.spans(t0)
+    names = [r[0] for r in recs]
+    assert {"serve.flush", "serve.swap", "serve.step"} <= set(names)
+    compiles = [r for r in recs if r[0] == obs.COMPILE]
+    assert compiles
+    assert all(r[4]["stack"][0] == "serve.step" for r in compiles)
+    step = next(r for r in recs if r[0] == "serve.step")
+    assert step[4] == {"requests": 1, "cells": 1}
+    assert srv.stats["compiles"] - before == len(compiles)
 
 
 def test_ingest_can_grow_the_vertex_set():
