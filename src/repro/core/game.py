@@ -241,16 +241,19 @@ def jax_greedy_assign(sizes, k: int, k_real=None):
     return assign
 
 
-def jax_game_rounds(xs, xd, sizes, row_tot, k: int, lam, *,
+def jax_game_rounds(row, col, w, sizes, row_tot, k: int, lam, *,
                     batch_size: int, max_rounds: int, seed: int,
                     use_pallas: bool = False, block_m: int = 256,
                     axis: str | None = None, damping: float = 0.5,
                     k_real=None):
     """Batched best-response rounds (Alg. 3 + §V-D) as a pure jax program.
 
-    The cluster graph arrives as its cross-edge list: ``xs``/``xd`` are the
-    (padded) cluster endpoints of every inter-cluster edge — padding uses
-    the out-of-range sentinel ``m_cap`` so scatter-adds drop it.  Each
+    The cluster graph arrives as a pair list (``row``, ``col``, ``w``;
+    pad row ``m_cap`` drops): the aggregated distinct pairs of
+    ``jax_cluster_csr`` where ``pair_keys_fit(m_cap)``, else the raw
+    cross-edge list of ``raw_cluster_pairs``.  Both give the same
+    ``cut_mass`` table bit for bit, so the game plays the same rounds on
+    either, and the aggregated one has far fewer lanes.  Each
     batch recomputes its cut-mass rows from the live assignment (the
     host's per-batch snapshot refresh), plays Jacobi *within* the batch,
     and updates the load table between batches (Gauss–Seidel across
@@ -312,11 +315,7 @@ def jax_game_rounds(xs, xd, sizes, row_tot, k: int, lam, *,
 
     def batch_body(b, carry):
         assign, loads, moved, rnd = carry
-        aff = (jnp.zeros((m_cap, kpad), jnp.float32)
-               .at[xs, assign[jnp.clip(xd, 0, m_cap - 1)]]
-               .add(1.0, mode="drop")
-               .at[xd, assign[jnp.clip(xs, 0, m_cap - 1)]]
-               .add(1.0, mode="drop"))
+        aff = cut_mass(row, col, w, assign, kpad)
         if use_pallas:
             from ..kernels.game_bestresponse import game_bestresponse
             best, best_cost = game_bestresponse(
@@ -362,13 +361,9 @@ def jax_game_rounds(xs, xd, sizes, row_tot, k: int, lam, *,
 
     def potential(assign, loads):
         """Φ (Definition 4) from the live tables — the cut mass is
-        recomputed from the cross-edge list; Σ_i (row_tot − aff[i,a_i])
+        recomputed from the pair list; Σ_i (row_tot − aff[i,a_i])
         double-counts each symmetrized pair, hence the 0.25."""
-        aff = (jnp.zeros((m_cap, kpad), jnp.float32)
-               .at[xs, assign[jnp.clip(xd, 0, m_cap - 1)]]
-               .add(1.0, mode="drop")
-               .at[xd, assign[jnp.clip(xs, 0, m_cap - 1)]]
-               .add(1.0, mode="drop"))
+        aff = cut_mass(row, col, w, assign, kpad)
         cut = psum_(jnp.sum(row_tot - aff[ar, assign]))
         load_sq = jnp.sum(loads * loads)        # loads are already global
         return (lam / (2 * kf)) * load_sq + 0.25 * cut
@@ -401,42 +396,77 @@ def jax_game_rounds(xs, xd, sizes, row_tot, k: int, lam, *,
     return best_assign, rounds
 
 
+def pair_keys_fit(m_cap: int) -> bool:
+    """Whether the symmetric pair keys ``row·m_cap + col`` of an
+    ``m_cap``-cluster id space fit int32 (m_cap ≤ 46,340): the static
+    test that puts the games on the aggregated pair list."""
+    return m_cap * (m_cap + 1) < 2 ** 31
+
+
+def raw_cluster_pairs(xs, xd):
+    """The cross-edge list as an unaggregated pair list: both directions
+    of every edge, weight one each (sentinel lanes keep row ``m_cap``
+    and drop).  The games play on it where ``pair_keys_fit`` fails."""
+    return (jnp.concatenate([xs, xd]), jnp.concatenate([xd, xs]),
+            jnp.float32(1.0))
+
+
+def cut_mass(row, col, w, assign, lanes: int):
+    """The cut-mass table aff[i, p] = Σ_{j: a_j = p} S[i, j] of the pair
+    list (``row``, ``col``, ``w``) under ``assign``: one gather and one
+    weighted scatter-add over the list's lanes into (m_cap, lanes).
+    Every entry is an integer count below 2²⁴, so float32 sums are exact
+    in any order: the aggregated and the raw list give the same table
+    bit for bit."""
+    m_cap = assign.shape[0]
+    return (jnp.zeros((m_cap, lanes), jnp.float32)
+            .at[row, assign[jnp.clip(col, 0, m_cap - 1)]]
+            .add(w, mode="drop"))
+
+
 def jax_cluster_csr(xs, xd, m_cap: int, nnz_cap: int):
     """In-graph aggregated edge list of the cluster multigraph from its
     cross-edge endpoints (padded lanes = ``m_cap``): the distinct
-    symmetrized (row, col) pairs with their multiplicities, compacted
-    into ``nnz_cap`` lanes (pad row = ``m_cap``).  Returns (row, col, w,
-    overflow) — callers retry with a doubled ``nnz_cap`` when the flag
-    fires, like the partitioner's other adaptive caps.  Aggregation
-    matters twice: the per-round cut-mass scatter walks nnz lanes at
-    ~100 ns each on XLA:CPU, and distinct pairs are ~10× fewer than raw
-    cross edges on web graphs."""
-    # int32 keys: fine while m_cap·(m_cap+1) < 2³¹, i.e. m_cap ≤ ~46k —
-    # the partitioner backends fall back to the Jacobi game above that
-    if m_cap * (m_cap + 1) >= 2 ** 31:
+    symmetrized (row, col) pairs in key order with their multiplicities,
+    compacted into ``nnz_cap`` lanes (pad row = ``m_cap``, col 0, w 0).
+    Returns (row, col, w, n_pairs): ``n_pairs`` counts every distinct
+    pair, so ``n_pairs > nnz_cap`` is the overflow on which callers
+    retry with a doubled ``nnz_cap``, like the partitioner's other
+    adaptive caps.  Both games play on this list (``cut_mass``) where
+    ``pair_keys_fit(m_cap)``: distinct pairs are ~10–20× fewer than the
+    raw cross edges' two directions on web graphs, and every round's two
+    cut-mass scatters walk the list's lanes.
+
+    Built once per body run with two sorts and no scatter: the keys,
+    then the positions of the runs' first lanes (the rest sort last), so
+    each multiplicity is the distance to the next run's start."""
+    if not pair_keys_fit(m_cap):
         raise ValueError(
             f"jax_cluster_csr: m_cap={m_cap} overflows the int32 "
-            f"pair-key space (limit ~46340); use the 'xla'/'pallas' "
-            f"game kernel instead")
+            f"pair-key space (limit ~46340); play on the raw list of "
+            f"raw_cluster_pairs instead")
+    n = 2 * xs.shape[0]
     big = jnp.int32(m_cap * m_cap)
     ok = (xs < m_cap) & (xd < m_cap)
     key = jnp.concatenate([xs * m_cap + xd, xd * m_cap + xs])
     key = jnp.where(jnp.concatenate([ok, ok]), key, big)
     sk = jnp.sort(key)
+    live = sk < big
+    n_live = live.sum().astype(jnp.int32)
     first = jnp.concatenate([jnp.ones((1,), bool), sk[1:] != sk[:-1]])
-    first = first & (sk < big)
-    start = jnp.searchsorted(sk, sk, side="left")
-    mult = jnp.searchsorted(sk, sk, side="right") - start
-    rank = jnp.cumsum(first.astype(jnp.int32)) - 1
-    slot = jnp.where(first, rank, nnz_cap)
-    row = jnp.full((nnz_cap,), m_cap, jnp.int32).at[slot].set(
-        (sk // m_cap).astype(jnp.int32), mode="drop")
-    col = jnp.zeros((nnz_cap,), jnp.int32).at[slot].set(
-        (sk % m_cap).astype(jnp.int32), mode="drop")
-    w = jnp.zeros((nnz_cap,), jnp.float32).at[slot].set(
-        mult.astype(jnp.float32), mode="drop")
-    overflow = (jnp.where(first, rank, -1).max() + 1) > nnz_cap
-    return row, col, w, overflow
+    first = first & live
+    n_pairs = first.sum().astype(jnp.int32)
+    # run starts in order, then n_live: start[r + 1] - start[r] is run r's
+    # length, and the lanes past the last run read 0
+    pos = jnp.where(first, jnp.arange(n, dtype=jnp.int32), n_live)
+    if n < nnz_cap + 1:
+        pos = jnp.concatenate(
+            [pos, jnp.broadcast_to(n_live, (nnz_cap + 1 - n,))])
+    start = jnp.sort(pos)[:nnz_cap + 1]
+    w = (start[1:] - start[:-1]).astype(jnp.float32)
+    slot = jnp.arange(nnz_cap, dtype=jnp.int32)
+    keys = jnp.where(slot < n_pairs, sk[jnp.clip(start[:-1], 0, n - 1)], big)
+    return keys // m_cap, keys % m_cap, w, n_pairs
 
 
 def jax_game_rounds_gs(row, col, w, sizes, row_tot, k: int, lam, *,
@@ -446,7 +476,7 @@ def jax_game_rounds_gs(row, col, w, sizes, row_tot, k: int, lam, *,
     the CPU-fast form of Alg. 3 (the batched-Jacobi ``jax_game_rounds``
     needs damping and ~10× the rounds).  Per round the cut-mass table
     aff[i, p] is computed once from the round-start assignment (one
-    aggregated scatter over the distinct cluster pairs); the sweep then
+    ``cut_mass`` scatter over the pair list); the sweep then
     plays clusters sequentially against the LIVE load table, i.e. one
     round = one §V-D batch snapshot for the cut term with Gauss–Seidel
     load accounting.  The snapshot approximation can cycle instead of
@@ -492,11 +522,6 @@ def jax_game_rounds_gs(row, col, w, sizes, row_tot, k: int, lam, *,
         assign = assign.at[i].set(newa)     # i is streamed in → in-place
         return (assign, loads, moved + move.astype(jnp.int32)), None
 
-    def aff_of(assign):
-        return (jnp.zeros((m_cap, k), jnp.float32)
-                .at[row, assign[jnp.clip(col, 0, m_cap - 1)]]
-                .add(w, mode="drop"))
-
     def phi_of(assign, loads, aff):
         """Φ (Definition 4); Σ_i (row_tot − aff[i,a_i]) double-counts
         each symmetrized pair, hence the 0.25."""
@@ -507,7 +532,7 @@ def jax_game_rounds_gs(row, col, w, sizes, row_tot, k: int, lam, *,
 
     def round_body(carry):
         assign, loads, rnd, _, best_assign, best_phi, stall = carry
-        aff = aff_of(assign)
+        aff = cut_mass(row, col, w, assign, k)
         phi = phi_of(assign, loads, aff)
         better = phi < best_phi
         best_assign = jnp.where(better, assign, best_assign)
@@ -537,7 +562,7 @@ def jax_game_rounds_gs(row, col, w, sizes, row_tot, k: int, lam, *,
         (assign0, loads0, jnp.int32(0), jnp.int32(1), assign0,
          jnp.float32(3e38), jnp.int32(0)))
     # the final sweep's state was never Φ-checked inside the loop
-    phi = phi_of(assign, loads, aff_of(assign))
+    phi = phi_of(assign, loads, cut_mass(row, col, w, assign, k))
     best_assign = jnp.where(phi < best_phi, assign, best_assign)
     return best_assign, rounds
 

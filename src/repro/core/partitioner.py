@@ -48,8 +48,8 @@ import jax.numpy as jnp
 from .clustering import ClusteringResult, default_vmax
 from .game import contract
 from .pipeline import CLUGPConfig, CLUGPResult
-from .stages import (HOST_STAGES, JAX_STAGES, StageCtx, resolve_game_mode,
-                     restream_loop, run_clugp_body)
+from .stages import (HOST_STAGES, JAX_STAGES, StageCtx, game_list,
+                     resolve_game_mode, restream_loop, run_clugp_body)
 from . import metrics
 from .. import obs
 
@@ -235,7 +235,7 @@ def _jit_body(src, dst, *, num_vertices: int, cfg: CLUGPConfig, vmax: float,
     out = run_clugp_body(src, dst, ctx, cfg, JAX_STAGES)
     return (out.assign, out.cluster.compact, out.cluster.deg,
             out.cluster.divided, out.cluster.replicas, out.cluster.m,
-            out.rounds, out.cluster_assign, out.overflow,
+            out.rounds, out.cluster_assign, out.pairs,
             out.cluster.next_id)
 
 
@@ -248,14 +248,16 @@ def _run_jit(src: np.ndarray, dst: np.ndarray, num_vertices: int,
         # a span per run of the body: it ends where the caps are read
         # back, which waits for the device
         with obs.span("partition.attempt", attempt=attempt,
+                      game_list=game_list(cfg, caps.m_cap),
                       **caps._asdict()):
             out = _jit_body(
                 jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32),
                 num_vertices=num_vertices, cfg=cfg, vmax=float(vmax),
                 game_mode=resolve_game_mode(cfg.kernel, caps.m_cap),
                 id_cap=caps.id_cap, m_cap=caps.m_cap, nnz_cap=caps.nnz_cap)
+            pairs = int(out[-2])
             caps, ok = _grow_caps(caps, next_id=int(out[-1]), m=int(out[5]),
-                                  overflow=bool(out[-2]),
+                                  overflow=pairs > caps.nnz_cap,
                                   num_vertices=num_vertices, e_per=E)
         if ok:
             break
@@ -272,6 +274,8 @@ def _run_jit(src: np.ndarray, dst: np.ndarray, num_vertices: int,
         res.stats = metrics.summarize(src, dst, assign, num_vertices, cfg.k)
     res.stats["num_clusters"] = m
     res.stats["game_rounds"] = rounds
+    res.stats["game_list"] = game_list(cfg, caps.m_cap)
+    res.stats["game_pairs"] = pairs
     res.stats["backend"] = "jit"
     return res
 
@@ -307,7 +311,7 @@ def _jit_sweep_body(src, dst, ks, vmaxs, *, num_vertices: int,
                        nnz_cap=nnz_cap, k_real=k_real)
         out = run_clugp_body(src, dst, ctx, cfg, JAX_STAGES)
         return carry, (out.assign, out.cluster.m, out.rounds,
-                       out.overflow, out.cluster.next_id)
+                       out.pairs, out.cluster.next_id)
 
     _, outs = jax.lax.scan(body, 0, (ks, vmaxs))
     return outs
@@ -334,15 +338,16 @@ def partition_sweep(src: np.ndarray, dst: np.ndarray, num_vertices: int,
     ks_arr = np.array(ks, np.int32)
     caps = _init_caps(num_vertices, E)
     while True:
-        assigns, ms, rounds, overflows, next_ids = _jit_sweep_body(
+        assigns, ms, rounds, pairs, next_ids = _jit_sweep_body(
             jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32),
             jnp.asarray(ks_arr), jnp.asarray(vmaxs),
             num_vertices=num_vertices, cfg=sweep_cfg,
             game_mode=resolve_game_mode(cfg.kernel, caps.m_cap),
             id_cap=caps.id_cap, m_cap=caps.m_cap, nnz_cap=caps.nnz_cap)
+        pairs = np.asarray(pairs)
         caps, ok = _grow_caps(caps, next_id=int(np.asarray(next_ids).max()),
                               m=int(np.asarray(ms).max()),
-                              overflow=int(np.asarray(overflows).max()) > 0,
+                              overflow=int(pairs.max()) > caps.nnz_cap,
                               num_vertices=num_vertices, e_per=E)
         if ok:
             break
@@ -353,6 +358,8 @@ def partition_sweep(src: np.ndarray, dst: np.ndarray, num_vertices: int,
         res.stats = metrics.summarize(src, dst, assign, num_vertices, k)
         res.stats["num_clusters"] = int(ms[i])
         res.stats["game_rounds"] = int(rounds[i])
+        res.stats["game_list"] = game_list(sweep_cfg, caps.m_cap)
+        res.stats["game_pairs"] = int(pairs[i])
         res.stats["backend"] = "jit"
         res.stats["sweep"] = True
         res.stats["k_max"] = k_max
@@ -401,8 +408,7 @@ def _make_sharded_fn(mesh, e_per: int, num_vertices: int,
                        nnz_cap=nnz_cap)
         out = run_clugp_body(s, d, ctx, cfg, JAX_STAGES)
         return (out.assign, out.cluster.m[None], out.rounds[None],
-                out.cluster.next_id[None],
-                out.overflow.astype(jnp.int32)[None])
+                out.cluster.next_id[None], out.pairs[None])
 
     # check_vma=False: the stage body is the one the single-device jit
     # strategy runs, and its scan/while carries start from constants
@@ -440,19 +446,21 @@ def _run_sharded(src: np.ndarray, dst: np.ndarray, num_vertices: int,
     caps = _init_caps(num_vertices, e_per)
     for attempt in itertools.count():
         with obs.span("partition.attempt", attempt=attempt,
+                      game_list=game_list(cfg, caps.m_cap),
                       **caps._asdict()):
             run = _make_sharded_fn(
                 mesh, e_per, num_vertices, cfg,
                 resolve_game_mode(cfg.kernel, caps.m_cap),
                 caps.id_cap, caps.m_cap, caps.nnz_cap)
             with mesh:
-                assign_p, m_locals, rounds_arr, next_ids, overflows = run(
+                assign_p, m_locals, rounds_arr, next_ids, pairs = run(
                     jnp.asarray(src_p), jnp.asarray(dst_p),
                     jnp.asarray(mask))
+            pairs = np.asarray(pairs)
             caps, ok = _grow_caps(
                 caps, next_id=int(np.asarray(next_ids).max()),
                 m=int(np.asarray(m_locals).max()),
-                overflow=int(np.asarray(overflows).max()) > 0,
+                overflow=int(pairs.max()) > caps.nnz_cap,
                 num_vertices=num_vertices, e_per=e_per)
         if ok:
             break
@@ -465,6 +473,8 @@ def _run_sharded(src: np.ndarray, dst: np.ndarray, num_vertices: int,
         res.stats = metrics.summarize(src, dst, assign, num_vertices, cfg.k)
     res.stats["num_clusters"] = int(m_locals.sum())
     res.stats["game_rounds"] = rounds
+    res.stats["game_list"] = game_list(cfg, caps.m_cap)
+    res.stats["game_pairs"] = int(pairs.sum())   # over private id spaces
     res.stats["backend"] = "sharded"
     res.stats["nodes"] = n
     res.stats["per_node"] = [

@@ -46,7 +46,7 @@ from .clustering import (compact_labels_jax, streaming_clustering_jax,
 from .game import (ClusterGraph, best_response_rounds, contract,
                    greedy_assign, jax_cluster_csr, jax_game_rounds,
                    jax_game_rounds_gs, jax_greedy_assign, lambda_from_weight,
-                   lambda_max)
+                   lambda_max, pair_keys_fit, raw_cluster_pairs)
 from .transform import (majority_vertex_map_jax, majority_vertex_map_np,
                         transform_jax, transform_np)
 
@@ -67,7 +67,7 @@ class StageCtx:
     game_mode: str = "scan"    # resolved kernel: "scan" | "xla" | "pallas"
     id_cap: int = 0            # cluster-id space (jax clustering scan)
     m_cap: int = 0             # compacted-cluster cap (game tables)
-    nnz_cap: int = 0           # aggregated cluster-CSR lanes (GS game)
+    nnz_cap: int = 0           # aggregated cluster-pair lanes (game)
     k_real: Any = None         # traced live-partition count of a k_max-
     #                            padded sweep step; None = cfg.k is real
 
@@ -85,7 +85,7 @@ class ContractStage(Protocol):
 
 
 class GameStage(Protocol):
-    """Pass 2: cluster graph → (cluster→partition, rounds, overflow)."""
+    """Pass 2: cluster graph → (cluster→partition, rounds, pairs)."""
     def __call__(self, gstate, ctx: StageCtx, cfg) -> tuple: ...
 
 
@@ -147,7 +147,8 @@ class PipelineOut(NamedTuple):
     graph: Any                 # HostGraph / JaxGraph
     cluster_assign: Any
     rounds: Any
-    overflow: Any              # GS nnz-cap overflow flag (host: False)
+    pairs: Any                 # distinct cluster pairs of the game's list
+    #                            (> nnz_cap: overflow; 0: raw list, host)
     trace: tuple               # pre-pass RF per restream (host runs only)
 
 
@@ -166,7 +167,7 @@ def run_clugp_body(src, dst, ctx: StageCtx, cfg, stages: StageSet
     with jax.named_scope("clugp/contract"):
         gstate = stages.contract(src, dst, cstate, ctx, cfg)
     with jax.named_scope("clugp/game"):
-        cluster_assign, rounds, overflow = stages.game(gstate, ctx, cfg)
+        cluster_assign, rounds, pairs = stages.game(gstate, ctx, cfg)
     with jax.named_scope("clugp/vertex_part"):
         vp = stages.vertex_part(cluster_assign, cstate, ctx)
     with jax.named_scope("clugp/transform"):
@@ -176,7 +177,7 @@ def run_clugp_body(src, dst, ctx: StageCtx, cfg, stages: StageSet
                                       [(None, cstate, ctx)], ctx, cfg,
                                       stages)
     return PipelineOut(assign, cstate, gstate, cluster_assign, rounds,
-                       overflow, trace)
+                       pairs, trace)
 
 
 def restream_loop(src, dst, assign, parts, ctx: StageCtx, cfg,
@@ -225,7 +226,7 @@ def _host_contract(src, dst, cstate, ctx, cfg):
 
 def _host_game(gstate, ctx, cfg):
     if not cfg.game:
-        return greedy_assign(gstate.game_cg, cfg.k), 0, False
+        return greedy_assign(gstate.game_cg, cfg.k), 0, 0
     lam = (lambda_max(gstate.game_cg, cfg.k)
            if cfg.relative_weight is None
            else lambda_from_weight(gstate.game_cg, cfg.k,
@@ -233,7 +234,7 @@ def _host_game(gstate, ctx, cfg):
     game = best_response_rounds(gstate.game_cg, cfg.k, lam=lam,
                                 batch_size=cfg.batch_size,
                                 max_rounds=cfg.max_rounds, seed=cfg.seed)
-    return game.assign, game.rounds, False
+    return game.assign, game.rounds, 0
 
 
 def _host_vertex_part(cluster_assign, cstate, ctx):
@@ -267,17 +268,30 @@ def resolve_game_mode(kernel: str, m_cap: int) -> str:
     over clusters (the CPU-fast host-exact form), ``pallas`` / ``xla`` =
     batched-Jacobi rounds on the ``game_bestresponse`` kernel / its XLA
     fallback (the MXU-shaped form).  ``auto`` picks pallas on TPU and the
-    scan everywhere else; the scan falls back to ``xla`` when ``m_cap``
-    overflows its int32 pair-key space (~46k clusters)."""
+    scan everywhere else.  Every mode plays on the aggregated pair list
+    where ``m_cap``'s int32 pair keys fit (~46k clusters, see
+    ``game_list``); above that pallas and xla play on the raw cross-edge
+    list, and the scan falls back to ``xla``."""
     if kernel not in ("auto", "scan", "pallas", "xla"):
         raise ValueError(f"unknown game kernel {kernel!r}; expected "
                          "'auto', 'scan', 'pallas' or 'xla'")
     mode = kernel
     if kernel == "auto":
         mode = "pallas" if jax.default_backend() == "tpu" else "scan"
-    if mode == "scan" and m_cap * (m_cap + 1) >= 2 ** 31:
+    if mode == "scan" and not pair_keys_fit(m_cap):
         return "xla"
     return mode
+
+
+def game_list(cfg, m_cap: int) -> str:
+    """Which list the device game builds its cut-mass tables from, known
+    before the body runs: ``"pairs"`` (the aggregated distinct cluster
+    pairs, ``jax_cluster_csr``) where ``m_cap``'s pair keys fit int32,
+    ``"edges"`` (the raw cross-edge list) above that, ``"none"`` for the
+    greedy ablation, which plays no game."""
+    if not cfg.game:
+        return "none"
+    return "pairs" if pair_keys_fit(m_cap) else "edges"
 
 
 def resolve_cluster_kernel(kernel: str) -> str:
@@ -357,10 +371,9 @@ def _jax_contract(src, dst, cstate, ctx, cfg):
 
 
 def _jax_game(gstate, ctx, cfg):
-    overflow = jnp.bool_(False)
     if not cfg.game:
         return (jax_greedy_assign(gstate.sizes, cfg.k, k_real=ctx.k_real),
-                jnp.int32(0), overflow)
+                jnp.int32(0), jnp.int32(0))
     # λ from the LOCAL cluster graph on every strategy: Thm 5's feasible
     # range is a per-id-space quantity (sharded global totals under-weight
     # the balance term by ~n — measured +22% RF at n=4); the load vector
@@ -371,20 +384,25 @@ def _jax_game(gstate, ctx, cfg):
     # steps play the identical XLA fallback math instead
     mode = ("xla" if ctx.game_mode == "pallas" and ctx.k_real is not None
             else ctx.game_mode)
+    # the list is built once; every round's cut-mass tables walk it
+    if game_list(cfg, ctx.m_cap) == "pairs":
+        row, col, w, pairs = jax_cluster_csr(gstate.xs, gstate.xd,
+                                             ctx.m_cap, ctx.nnz_cap)
+    else:
+        row, col, w = raw_cluster_pairs(gstate.xs, gstate.xd)
+        pairs = jnp.int32(0)
     if mode == "scan":
-        row, col, w, overflow = jax_cluster_csr(gstate.xs, gstate.xd,
-                                                ctx.m_cap, ctx.nnz_cap)
         cluster_assign, rounds = jax_game_rounds_gs(
             row, col, w, gstate.sizes, gstate.row_tot, cfg.k, lam,
             max_rounds=cfg.max_rounds, seed=cfg.seed, axis=ctx.axis,
             k_real=ctx.k_real)
     else:
         cluster_assign, rounds = jax_game_rounds(
-            gstate.xs, gstate.xd, gstate.sizes, gstate.row_tot, cfg.k, lam,
+            row, col, w, gstate.sizes, gstate.row_tot, cfg.k, lam,
             batch_size=cfg.batch_size, max_rounds=cfg.max_rounds,
             seed=cfg.seed, use_pallas=mode == "pallas",
             axis=ctx.axis, k_real=ctx.k_real)
-    return cluster_assign, rounds, overflow
+    return cluster_assign, rounds, pairs
 
 
 def _jax_vertex_part(cluster_assign, cstate, ctx):

@@ -105,9 +105,9 @@ def test_jit_balance_cap_respected(graph10):
 
 
 def test_cluster_csr_rejects_int32_overflow():
-    """Backstop for the GS game's int32 pair-key space: above ~46k
-    clusters the builder must refuse (the partitioner backends fall back
-    to the Jacobi game before ever calling it)."""
+    """Backstop for the games' int32 pair-key space: above ~46k clusters
+    the builder must refuse (the partitioner's games play on the raw
+    cross-edge list there and never call it)."""
     import jax.numpy as jnp
 
     from repro.core.game import jax_cluster_csr
@@ -115,6 +115,159 @@ def test_cluster_csr_rejects_int32_overflow():
     xs = jnp.zeros((4,), jnp.int32)
     with pytest.raises(ValueError, match="overflows the int32"):
         jax_cluster_csr(xs, xs, 65536, 64)
+
+
+# --------------------------------------------- the game's cluster-pair list
+
+def _cross_list(seed, n, m_cap, live, n_distinct):
+    """A padded cross-edge list: ``live`` lanes drawn from ``n_distinct``
+    ordered cluster pairs (so pairs repeat, in both directions), the rest
+    the drop sentinel ``m_cap``."""
+    rng = np.random.default_rng(seed)
+    m = min(m_cap, 64)
+    pool = rng.integers(0, m, size=(n_distinct, 2))
+    pool = pool[pool[:, 0] != pool[:, 1]]
+    xs = np.full(n, m_cap, np.int32)
+    xd = np.full(n, m_cap, np.int32)
+    lanes = rng.choice(n, size=live, replace=False)
+    pick = pool[rng.integers(0, len(pool), size=live)]
+    xs[lanes], xd[lanes] = pick[:, 0], pick[:, 1]
+    return xs, xd
+
+
+def _pairs_reference(xs, xd, m_cap, nnz_cap):
+    """``np.unique`` over the symmetric keys of the live lanes."""
+    ok = (xs < m_cap) & (xd < m_cap)
+    s, d = xs[ok].astype(np.int64), xd[ok].astype(np.int64)
+    keys, counts = np.unique(np.concatenate([s * m_cap + d, d * m_cap + s]),
+                             return_counts=True)
+    row = np.full(nnz_cap, m_cap, np.int64)
+    col = np.zeros(nnz_cap, np.int64)
+    w = np.zeros(nnz_cap, np.float32)
+    n = min(len(keys), nnz_cap)
+    row[:n], col[:n], w[:n] = keys[:n] // m_cap, keys[:n] % m_cap, counts[:n]
+    return row, col, w, len(keys)
+
+
+@pytest.mark.parametrize("n,live,n_distinct,nnz_cap", [
+    (4096, 1500, 300, 1024),    # sentinel padding, repeated pairs
+    (4096, 4096, 300, 1024),    # every lane live
+    (4096, 0, 300, 1024),       # every lane padding
+    (4096, 1500, 3000, 256),    # more distinct pairs than lanes: overflow
+    (64, 40, 8, 1024),          # fewer key lanes than nnz_cap + 1
+], ids=["sentinel", "all-live", "all-pad", "overflow", "short"])
+def test_cluster_csr_matches_unique_reference(n, live, n_distinct, nnz_cap):
+    """The pair-list builder's (row, col, w) and distinct-pair count equal
+    an ``np.unique`` reference; past ``nnz_cap`` it keeps the first pairs
+    in key order and its count reports the overflow."""
+    from repro.core.game import jax_cluster_csr
+    m_cap = 256
+    xs, xd = _cross_list(7, n, m_cap, live, n_distinct)
+    row, col, w, n_pairs = jax_cluster_csr(xs, xd, m_cap, nnz_cap)
+    want = _pairs_reference(xs, xd, m_cap, nnz_cap)
+    np.testing.assert_array_equal(np.asarray(row), want[0])
+    np.testing.assert_array_equal(np.asarray(col), want[1])
+    np.testing.assert_array_equal(np.asarray(w), want[2])
+    assert int(n_pairs) == want[3]
+    assert (int(n_pairs) > nnz_cap) == (n_distinct == 3000)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_pair_list_cut_mass_equals_raw_list(seed):
+    """The cut-mass table from the aggregated pair list equals the one
+    from the raw cross-edge list to the bit (and a dense reference), on
+    padded lists whose pairs repeat."""
+    from repro.core.game import cut_mass, jax_cluster_csr, raw_cluster_pairs
+    m_cap, k = 128, 8
+    xs, xd = _cross_list(seed, 8192, m_cap, 5000, 400)
+    assign = np.random.default_rng(seed).integers(0, k, m_cap).astype(
+        np.int32)
+    row, col, w, n_pairs = jax_cluster_csr(xs, xd, m_cap, 4096)
+    assert int(n_pairs) <= 4096
+    pairs = np.asarray(cut_mass(row, col, w, assign, k))
+    raw = np.asarray(cut_mass(*raw_cluster_pairs(xs, xd), assign, k))
+    np.testing.assert_array_equal(pairs, raw)
+    want = np.zeros((m_cap, k), np.float32)
+    ok = xs < m_cap
+    np.add.at(want, (xs[ok], assign[xd[ok]]), 1.0)
+    np.add.at(want, (xd[ok], assign[xs[ok]]), 1.0)
+    np.testing.assert_array_equal(pairs, want)
+
+
+@pytest.mark.parametrize("m_cap,want", [(256, "pairs"), (46340, "pairs"),
+                                        (46341, "edges")])
+def test_game_list_follows_the_int32_key_limit(m_cap, want):
+    from repro.core.stages import game_list
+    assert game_list(CLUGPConfig(k=4), m_cap) == want
+    assert game_list(CLUGPConfig(k=4, game=False), m_cap) == "none"
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_jit_game_on_pair_list_matches_raw_list(graph10, kernel):
+    """The jit backend's batched-Jacobi game (pallas in interpret mode on
+    the CPU) plays on the pair list at graph10's m_cap; fed the raw list
+    directly, ``jax_game_rounds`` gives the same assignment and rounds."""
+    import jax
+
+    from repro.core import partitioner as P
+    from repro.core import stages as S
+    from repro.core.game import jax_game_rounds, raw_cluster_pairs
+    g = graph10
+    cfg = CLUGPConfig(k=8, kernel=kernel)
+    res = partition(g.src, g.dst, g.num_vertices, cfg, backend="jit")
+    assert res.stats["game_list"] == "pairs"
+    assert 0 < res.stats["game_pairs"]
+    caps = P._init_caps(g.num_vertices, g.num_edges)
+    ctx = S.StageCtx(num_vertices=g.num_vertices,
+                     vmax=float(P._resolve_vmax(cfg, g.num_edges)),
+                     game_mode=kernel, id_cap=caps.id_cap,
+                     m_cap=caps.m_cap, nnz_cap=caps.nnz_cap)
+
+    @jax.jit
+    def raw_game(src, dst):
+        cstate = S.JAX_STAGES.cluster(src, dst, ctx, cfg)
+        gs = S.JAX_STAGES.contract(src, dst, cstate, ctx, cfg)
+        lam = S.lambda_jax(gs.sizes.sum(), gs.n_cross, cfg.k,
+                           cfg.relative_weight)
+        return jax_game_rounds(
+            *raw_cluster_pairs(gs.xs, gs.xd), gs.sizes, gs.row_tot, cfg.k,
+            lam, batch_size=cfg.batch_size, max_rounds=cfg.max_rounds,
+            seed=cfg.seed, use_pallas=kernel == "pallas")
+
+    assign, rounds = raw_game(g.src.astype(np.int32),
+                              g.dst.astype(np.int32))
+    m = res.stats["num_clusters"]
+    np.testing.assert_array_equal(np.asarray(assign)[:m], res.cluster_assign)
+    assert int(rounds) == res.stats["game_rounds"]
+
+
+@pytest.mark.parametrize("kernel", ["xla", "pallas"])
+def test_jit_game_pair_overflow_retries(graph10, monkeypatch, kernel):
+    """A pair list past ``nnz_cap`` makes the partitioner run its body
+    again with the cap doubled; the second attempt's partition is the
+    clean run's, bit for bit."""
+    import time
+
+    from repro import obs
+    from repro.core import partitioner as P
+    g = graph10
+    cfg = CLUGPConfig(k=8, kernel=kernel)
+    clean = partition(g.src, g.dst, g.num_vertices, cfg, backend="jit")
+    pairs = clean.stats["game_pairs"]
+    init = P._init_caps
+    monkeypatch.setattr(P, "_init_caps", lambda v, e: init(v, e)._replace(
+        nnz_cap=pairs - 1))
+    t0 = time.perf_counter()
+    res = partition(g.src, g.dst, g.num_vertices, cfg, backend="jit")
+    attempts = [r[4] for r in obs.spans(t0) if r[0] == "partition.attempt"]
+    assert [(a["attempt"], a["nnz_cap"], a["game_list"])
+            for a in attempts] == [(0, pairs - 1, "pairs"),
+                                   (1, 2 * pairs - 2, "pairs")]
+    np.testing.assert_array_equal(res.assign, clean.assign)
+    np.testing.assert_array_equal(res.cluster_assign, clean.cluster_assign)
+    assert res.stats["game_rounds"] == clean.stats["game_rounds"]
+    assert (res.stats["game_list"], res.stats["game_pairs"]) == (
+        "pairs", pairs)
 
 
 def test_jit_tiny_stream_with_self_loops_bit_identical():
@@ -172,6 +325,8 @@ def test_sweep_matches_per_k_jit_bitwise(graph10):
         assert res.assign.min() >= 0 and res.assign.max() < k
         assert res.stats["rf"] == ref.stats["rf"]
         assert res.stats["sweep"] and res.stats["k_max"] == ks[-1]
+        assert (res.stats["game_list"], res.stats["game_pairs"]) == (
+            ref.stats["game_list"], ref.stats["game_pairs"])
 
 
 def test_sweep_repeat_adds_zero_compiles(graph10):
@@ -274,6 +429,8 @@ assert r_sh.stats["rf"] <= r_np.stats["rf"] * 1.10, (
 assert len(r_sh.stats["per_node"]) == nodes
 assert r_sh.stats["num_clusters"] == sum(
     n["clusters"] for n in r_sh.stats["per_node"])
+# each device's game plays on its own pair list; the stat sums them
+assert r_sh.stats["game_list"] == "pairs" and r_sh.stats["game_pairs"] > 0
 # greedy path is bit-identical to the host combine on every device
 cfg_g = CLUGPConfig(k=k, game=False)
 a_np = partition(g.src, g.dst, g.num_vertices, cfg_g, backend="np",
