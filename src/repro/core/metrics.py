@@ -8,18 +8,29 @@ from __future__ import annotations
 import numpy as np
 
 
+def _vertex_sets(src: np.ndarray, dst: np.ndarray, assign: np.ndarray,
+                 num_vertices: int, k: int):
+    """For each non-empty partition, the (V,) mask of the vertices its
+    edges touch: one stable sort of the edges by partition (a radix sort
+    of 16-bit keys), then a bincount per partition."""
+    assign = np.asarray(assign)
+    order = np.argsort(assign.astype(np.int16) if k <= (1 << 15) else assign,
+                       kind="stable")
+    s, d = np.asarray(src)[order], np.asarray(dst)[order]
+    bounds = np.searchsorted(assign[order], np.arange(k + 1))
+    for p in range(k):
+        lo, hi = bounds[p], bounds[p + 1]
+        if hi > lo:
+            yield (np.bincount(s[lo:hi], minlength=num_vertices)
+                   + np.bincount(d[lo:hi], minlength=num_vertices)) > 0
+
+
 def replication_factor(src: np.ndarray, dst: np.ndarray,
                        assign: np.ndarray, num_vertices: int,
                        k: int) -> float:
     """Σ_p |distinct vertices in p| / |V| — memory-light (no V×k table)."""
-    total = 0
-    order = np.argsort(assign, kind="stable")
-    s, d, a = src[order], dst[order], assign[order]
-    bounds = np.searchsorted(a, np.arange(k + 1))
-    for p in range(k):
-        lo, hi = bounds[p], bounds[p + 1]
-        if hi > lo:
-            total += np.unique(np.concatenate([s[lo:hi], d[lo:hi]])).shape[0]
+    total = sum(int(np.count_nonzero(seen)) for seen in
+                _vertex_sets(src, dst, assign, num_vertices, k))
     return total / float(num_vertices)
 
 
@@ -28,14 +39,8 @@ def vertex_partition_counts(src: np.ndarray, dst: np.ndarray,
                             k: int) -> np.ndarray:
     """|P(v)| per vertex (used by the graph engine's mirror tables)."""
     counts = np.zeros(num_vertices, dtype=np.int32)
-    order = np.argsort(assign, kind="stable")
-    s, d, a = src[order], dst[order], assign[order]
-    bounds = np.searchsorted(a, np.arange(k + 1))
-    for p in range(k):
-        lo, hi = bounds[p], bounds[p + 1]
-        if hi > lo:
-            verts = np.unique(np.concatenate([s[lo:hi], d[lo:hi]]))
-            counts[verts] += 1
+    for seen in _vertex_sets(src, dst, assign, num_vertices, k):
+        counts += seen
     return counts
 
 

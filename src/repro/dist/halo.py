@@ -52,15 +52,25 @@ the exact backends and a pytree of lane-shaped reference/residual arrays
 for the quantized one, so it threads through ``fori_loop`` carries):
 
   init_state(dev, dtype, combine)                  -> state
-  reduce_to_masters(partial, dev, combine, state)  -> (total, state)
-  broadcast_from_masters(master, dev, combine, state) -> (values, state)
-  reduce_stacked / broadcast_stacked               — same, on (k, …) stacks
+  reduce_to_masters(partials, dev, combine, state)  -> (total, state)
+  broadcast_from_masters(masters, dev, combine, state) -> (values, state)
+  reduce_stacked / broadcast_stacked                — same, on (k, …) stacks
 
-``dev`` is the layout's ``device_arrays()`` pytree — per-device slices in
-the shard_map forms, full (k, …) stacks in the stacked forms.  ``combine``
-is ``"sum"`` (pagerank) or ``"min"`` (label propagation).  The stacked
+``dev`` is the layout's ``device_arrays()`` pytree — full (k, …) stacks
+in the stacked forms, and in the shard_map forms the device's **local
+stacks**: its m = k/D partitions, (m, …), where D is the size of the mesh
+axis and device d holds partitions d·m … d·m + m − 1.  ``combine`` is
+``"sum"`` (pagerank) or ``"min"`` (label propagation).  The stacked
 forms model the collective with a transpose (all_to_all) / broadcast
 (all_gather), so tests and host benchmarks run the identical math.
+
+Only ``DenseExchange`` and ``HaloExchange`` route several partitions per
+device (one collective over the D devices; lanes between partitions on
+the same chip stay there).  The quantized and ragged wires route one
+partition per device and refuse local stacks of more than one
+(``_one_partition``); the quantized wire's exact payloads ride the halo
+wire and so run at any m.  The fused ``*_multi`` per-device halves take
+one partition, unstacked.
 
 **Multi-lane (fused multi-program) operations.**  N homogeneous GAS
 programs over the same layout can share one exchange per phase: values
@@ -135,6 +145,30 @@ def _unpack(new_master, recv, dev):
     scattered = jnp.zeros((l_max + 1,), new_master.dtype).at[
         dev["halo_send"].reshape(-1)].set(recv.reshape(-1))[:l_max]
     return jnp.where(dev["is_master"], new_master, scattered)
+
+
+def _transposed(send):
+    """The stacked form's all_to_all: (k, k, H_max) lanes by (source,
+    destination) → by (destination, source)."""
+    return jnp.swapaxes(send, 0, 1)
+
+
+def _one_partition(wire: str, values, dev, state):
+    """The one partition of a device's local stacks, for the wires that
+    route one partition per device: (values, tables, state) without
+    their leading axis.  Local stacks of more than one are refused."""
+    m = values.shape[0]
+    if m != 1:
+        raise ValueError(
+            f"the {wire!r} wire routes one partition per device and this "
+            f"mesh puts {m} on each; use the 'halo' or 'dense' wire, or a "
+            "mesh of k devices")
+    return jax.tree_util.tree_map(lambda x: x[0], (values, dev, state))
+
+
+def _restacked(values, state):
+    """What a one-partition half returns, as local stacks of one."""
+    return values[None], jax.tree_util.tree_map(lambda x: x[None], state)
 
 
 # --------------------------------------------------- multi-lane helpers
@@ -230,16 +264,22 @@ class DenseExchange:
     def init_state(self, dev, dtype, combine: str = "sum"):
         return ()
 
-    # -- per-device halves (inside shard_map over ``axis``) --
-    def reduce_to_masters(self, partial, dev, combine: str = "sum",
+    # -- per-device halves (inside shard_map over ``axis``): gather every
+    # device's (m, L_max) local stack into the (k, L_max) stack, then the
+    # stacked half over this device's m partitions --
+    def reduce_to_masters(self, partials, dev, combine: str = "sum",
                           state=()):
-        g = jax.lax.all_gather(partial, self.axis)          # (k, L_max)
-        return self._reduce_flat(g.reshape(-1), dev, combine), state
+        return self.reduce_stacked(self._gather(partials), dev, combine,
+                                   state)
 
-    def broadcast_from_masters(self, new_master, dev, combine: str = "sum",
+    def broadcast_from_masters(self, new_masters, dev, combine: str = "sum",
                                state=()):
-        g = jax.lax.all_gather(new_master, self.axis)       # (k, L_max)
-        return g[dev["owner"], dev["own_slot"]], state
+        return self.broadcast_stacked(self._gather(new_masters), dev,
+                                      combine, state)
+
+    def _gather(self, local):
+        g = jax.lax.all_gather(local, self.axis)            # (D, m, L_max)
+        return g.reshape(-1, local.shape[-1])               # (k, L_max)
 
     # -- stacked halves ((k, L_max) arrays on one device) --
     def reduce_stacked(self, partials, dev, combine: str = "sum", state=()):
@@ -315,30 +355,45 @@ class HaloExchange:
     def init_state(self, dev, dtype, combine: str = "sum"):
         return ()
 
-    # -- per-device halves (inside shard_map over ``axis``) --
-    def reduce_to_masters(self, partial, dev, combine: str = "sum",
+    # -- per-device halves (inside shard_map over ``axis``): the device's
+    # m partitions pack (m, k, H_max) lanes, one all_to_all over the D
+    # devices routes them (``_routed``), and each local partition
+    # combines or scatters the lanes it received, as in the stacked form --
+    def reduce_to_masters(self, partials, dev, combine: str = "sum",
                           state=()):
-        l_max = partial.shape[0]
-        send = _pack(partial, dev["halo_send"], combine)
-        recv = jax.lax.all_to_all(send, self.axis, 0, 0)    # (k, H_max)
-        agg = _segment_combine(recv.reshape(-1),
-                               dev["halo_recv"].reshape(-1),
-                               l_max + 1, combine)[:l_max]
-        return _merge(partial, agg, combine), state
+        return self._reduce(partials, dev, combine, self._routed), state
 
-    def broadcast_from_masters(self, new_master, dev, combine: str = "sum",
+    def broadcast_from_masters(self, new_masters, dev, combine: str = "sum",
                                state=()):
-        send = _pack(new_master, dev["halo_recv"], combine)
-        recv = jax.lax.all_to_all(send, self.axis, 0, 0)    # (k, H_max)
-        return _unpack(new_master, recv, dev), state
+        return self._broadcast(new_masters, dev, combine,
+                               self._routed), state
+
+    def _routed(self, send):
+        """(m, k, H_max) lanes by (local source, destination partition) →
+        (m, k, H_max) by (local destination, source partition).  Lanes go
+        out in one (D, m, m, H_max) block per destination device; the
+        device's own block, the lanes between partitions on the same
+        chip, never leaves it."""
+        m, k, h = send.shape
+        blocks = jnp.swapaxes(send.reshape(m, k // m, m, h), 0, 1)
+        got = jax.lax.all_to_all(blocks, self.axis, 0, 0)  # (D, m, m, H)
+        return got.transpose(2, 0, 1, 3).reshape(m, k, h)
 
     # -- stacked halves: all_to_all over k virtual devices == transpose --
     def reduce_stacked(self, partials, dev, combine: str = "sum", state=()):
+        return self._reduce(partials, dev, combine, _transposed), state
+
+    def broadcast_stacked(self, masters, dev, combine: str = "sum",
+                          state=()):
+        return self._broadcast(masters, dev, combine, _transposed), state
+
+    @staticmethod
+    def _reduce(partials, dev, combine: str, route):
         l_max = partials.shape[1]
         send = jax.vmap(
             lambda v, idx: _pack(v, idx, combine)
-        )(partials, dev["halo_send"])                       # (k, k, H_max)
-        recv = jnp.swapaxes(send, 0, 1)
+        )(partials, dev["halo_send"])                       # (·, k, H_max)
+        recv = route(send)
 
         def one(recv_q, slots_q, partial_q):
             agg = _segment_combine(recv_q.reshape(-1),
@@ -346,17 +401,17 @@ class HaloExchange:
                                    l_max + 1, combine)[:l_max]
             return _merge(partial_q, agg, combine)
 
-        return jax.vmap(one)(recv, dev["halo_recv"], partials), state
+        return jax.vmap(one)(recv, dev["halo_recv"], partials)
 
-    def broadcast_stacked(self, masters, dev, combine: str = "sum",
-                          state=()):
+    @staticmethod
+    def _broadcast(masters, dev, combine: str, route):
         send = jax.vmap(
             lambda v, idx: _pack(v, idx, combine)
-        )(masters, dev["halo_recv"])                        # (k, k, H_max)
-        recv = jnp.swapaxes(send, 0, 1)
+        )(masters, dev["halo_recv"])                        # (·, k, H_max)
+        recv = route(send)
         return jax.vmap(
             lambda m, r, d: _unpack(m, r, d)
-        )(masters, recv, dev), state
+        )(masters, recv, dev)
 
     # -- multi-lane halves (fused programs; values carry a leading N) --
     def init_state_multi(self, dev, dtype, combine: str, n: int):
@@ -481,12 +536,14 @@ class QuantizedHaloExchange:
         return varying({"reduce": lane_state, "bcast": dict(lane_state)},
                        self.axis)
 
-    # -- per-device halves (inside shard_map over ``axis``) --
-    def reduce_to_masters(self, partial, dev, combine: str = "sum",
+    # -- per-device halves (inside shard_map over ``axis``); the lossy
+    # payload routes one partition per device --
+    def reduce_to_masters(self, partials, dev, combine: str = "sum",
                           state=()):
         if not state:
-            return self._exact.reduce_to_masters(partial, dev, combine,
+            return self._exact.reduce_to_masters(partials, dev, combine,
                                                  state)
+        partial, dev, state = _one_partition(self.name, partials, dev, state)
         st = state["reduce"]
         l_max = partial.shape[0]
         lanes = _pack(partial, dev["halo_send"], combine)
@@ -499,14 +556,16 @@ class QuantizedHaloExchange:
                                dev["halo_recv"].reshape(-1),
                                l_max + 1, combine)[:l_max]
         total = _merge(partial, agg, combine)
-        return total, {**state, "reduce": {"sref": sref, "sres": sres,
-                                           "rref": rref}}
+        return _restacked(total, {**state, "reduce": {
+            "sref": sref, "sres": sres, "rref": rref}})
 
-    def broadcast_from_masters(self, new_master, dev, combine: str = "sum",
+    def broadcast_from_masters(self, new_masters, dev, combine: str = "sum",
                                state=()):
         if not state:
-            return self._exact.broadcast_from_masters(new_master, dev,
+            return self._exact.broadcast_from_masters(new_masters, dev,
                                                       combine, state)
+        new_master, dev, state = _one_partition(self.name, new_masters, dev,
+                                                state)
         st = state["bcast"]
         lanes = _pack(new_master, dev["halo_recv"], combine)
         sref, sres, codes, scales = _ef_encode(lanes, st["sref"],
@@ -515,8 +574,8 @@ class QuantizedHaloExchange:
         rscales = jax.lax.all_to_all(scales, self.axis, 0, 0)
         rref = st["rref"] + dequantize_rows(rcodes, rscales)
         values = _unpack(new_master, rref, dev)
-        return values, {**state, "bcast": {"sref": sref, "sres": sres,
-                                           "rref": rref}}
+        return _restacked(values, {**state, "bcast": {
+            "sref": sref, "sres": sres, "rref": rref}})
 
     # -- stacked halves: all_to_all over k virtual devices == transpose --
     def reduce_stacked(self, partials, dev, combine: str = "sum", state=()):
@@ -747,16 +806,29 @@ class RaggedHaloExchange:
     def init_state(self, dev, dtype, combine: str = "sum"):
         return ()
 
-    # -- per-device halves (inside shard_map over ``axis``) --
-    def reduce_to_masters(self, partial, dev, combine: str = "sum",
+    # -- per-device halves (inside shard_map over ``axis``): one
+    # partition per device --
+    def reduce_to_masters(self, partials, dev, combine: str = "sum",
                           state=(), *, hopwise: bool = False):
+        partial, dev, _ = _one_partition(self.name, partials, dev, ())
+        return _restacked(self._reduce_one(partial, dev, combine, hopwise),
+                          state)
+
+    def broadcast_from_masters(self, new_masters, dev, combine: str = "sum",
+                               state=()):
+        new_master, dev, _ = _one_partition(self.name, new_masters, dev, ())
+        return _restacked(self._broadcast_one(new_master, dev, combine),
+                          state)
+
+    def _reduce_one(self, partial, dev, combine: str, hopwise: bool):
+        """Reduce of one partition's (L_max,) partial over the ring."""
         l_max = partial.shape[0]
         k = self.k
         me = jax.lax.axis_index(self.axis)
         if hopwise:
             hops = self._hops()
             if not hops:
-                return partial, state
+                return partial
             acc = _acc_init((l_max + 1,), partial.dtype, combine)
             for s, h in hops:
                 send = _pack(partial,
@@ -767,7 +839,7 @@ class RaggedHaloExchange:
                 acc = _hop_accumulate(
                     acc, _row(dev["halo_recv"], (me - s) % k, h), recv,
                     combine)
-            return _merge(partial, acc[:l_max], combine), state
+            return _merge(partial, acc[:l_max], combine)
         recvs, slots = [], []
         for s, h in self._hops():
             send = _pack(partial, _row(dev["halo_send"], (me + s) % k, h),
@@ -777,14 +849,14 @@ class RaggedHaloExchange:
             recvs.append(recv)
             slots.append(_row(dev["halo_recv"], (me - s) % k, h))
         if not recvs:
-            return partial, state
+            return partial
         agg = _segment_combine(jnp.concatenate(recvs),
                                jnp.concatenate(slots),
                                l_max + 1, combine)[:l_max]
-        return _merge(partial, agg, combine), state
+        return _merge(partial, agg, combine)
 
-    def broadcast_from_masters(self, new_master, dev, combine: str = "sum",
-                               state=()):
+    def _broadcast_one(self, new_master, dev, combine: str):
+        """Broadcast of one partition's (L_max,) masters over the ring."""
         l_max = new_master.shape[0]
         k = self.k
         me = jax.lax.axis_index(self.axis)
@@ -798,8 +870,7 @@ class RaggedHaloExchange:
                 send, self.axis, [(p, (p - s) % k) for p in range(k)])
             wslot = _row(dev["halo_send"], (me + s) % k, h)
             scattered = scattered.at[wslot].set(recv)
-        return jnp.where(dev["is_master"], new_master,
-                         scattered[:l_max]), state
+        return jnp.where(dev["is_master"], new_master, scattered[:l_max])
 
     # -- stacked halves: ppermute over k virtual devices == jnp.roll --
     def reduce_stacked(self, partials, dev, combine: str = "sum", state=(),
@@ -864,14 +935,13 @@ class RaggedHaloExchange:
 
     def reduce_to_masters_multi(self, partials, dev, combine: str = "sum",
                                 state=(), *, hopwise: bool = False):
-        outs = [self.reduce_to_masters(p, dev, combine, hopwise=hopwise)[0]
+        outs = [self._reduce_one(p, dev, combine, hopwise)
                 for p in partials]
         return jnp.stack(outs), state
 
     def broadcast_from_masters_multi(self, new_masters, dev,
                                      combine: str = "sum", state=()):
-        outs = [self.broadcast_from_masters(m, dev, combine)[0]
-                for m in new_masters]
+        outs = [self._broadcast_one(m, dev, combine) for m in new_masters]
         return jnp.stack(outs), state
 
     def reduce_stacked_multi(self, partials, dev, combine: str = "sum",
@@ -962,12 +1032,29 @@ class RaggedQuantizedHaloExchange:
         return _scatter_last(ridx.astype(jnp.int32),
                              dequantize_rows(rcodes, rscales), h)
 
-    # -- per-device halves (inside shard_map over ``axis``) --
-    def reduce_to_masters(self, partial, dev, combine: str = "sum",
+    # -- per-device halves (inside shard_map over ``axis``): one
+    # partition per device --
+    def reduce_to_masters(self, partials, dev, combine: str = "sum",
                           state=(), *, hopwise: bool = False):
         if not state:
-            return self._exact.reduce_to_masters(partial, dev, combine,
+            return self._exact.reduce_to_masters(partials, dev, combine,
                                                  state, hopwise=hopwise)
+        partial, dev, state = _one_partition(self.name, partials, dev, state)
+        return _restacked(*self._reduce_one(partial, dev, combine, state,
+                                            hopwise))
+
+    def broadcast_from_masters(self, new_masters, dev, combine: str = "sum",
+                               state=()):
+        if not state:
+            return self._exact.broadcast_from_masters(new_masters, dev,
+                                                      combine, state)
+        new_master, dev, state = _one_partition(self.name, new_masters, dev,
+                                                state)
+        return _restacked(*self._broadcast_one(new_master, dev, combine,
+                                               state))
+
+    def _reduce_one(self, partial, dev, combine: str, state, hopwise: bool):
+        """Lossy reduce of one partition's (L_max,) partial."""
         l_max = partial.shape[0]
         k = self.k
         me = jax.lax.axis_index(self.axis)
@@ -1003,11 +1090,8 @@ class RaggedQuantizedHaloExchange:
         return _merge(partial, agg, combine), \
             {**state, "reduce": tuple(new_st)}
 
-    def broadcast_from_masters(self, new_master, dev, combine: str = "sum",
-                               state=()):
-        if not state:
-            return self._exact.broadcast_from_masters(new_master, dev,
-                                                      combine, state)
+    def _broadcast_one(self, new_master, dev, combine: str, state):
+        """Lossy broadcast of one partition's (L_max,) masters."""
         l_max = new_master.shape[0]
         k = self.k
         me = jax.lax.axis_index(self.axis)
@@ -1108,8 +1192,7 @@ class RaggedQuantizedHaloExchange:
                 partials, dev, combine, state, hopwise=hopwise)
         outs, sts = [], []
         for p, st in zip(partials, state):
-            o, ns = self.reduce_to_masters(p, dev, combine, st,
-                                           hopwise=hopwise)
+            o, ns = self._reduce_one(p, dev, combine, st, hopwise)
             outs.append(o)
             sts.append(ns)
         return jnp.stack(outs), tuple(sts)
@@ -1121,7 +1204,7 @@ class RaggedQuantizedHaloExchange:
                 new_masters, dev, combine, state)
         outs, sts = [], []
         for m, st in zip(new_masters, state):
-            o, ns = self.broadcast_from_masters(m, dev, combine, st)
+            o, ns = self._broadcast_one(m, dev, combine, st)
             outs.append(o)
             sts.append(ns)
         return jnp.stack(outs), tuple(sts)

@@ -33,8 +33,9 @@ runs any program:
 
 - ``simulate_gas(program, …)``   : stacked (k, …) arrays on one device —
                                    tests and host-side benchmarks.
-- ``shard_map_gas(program, …)``  : one partition per mesh device over axis
-                                   ``parts`` — the production path.
+- ``shard_map_gas(program, …)``  : k/D partitions on each of the D mesh
+                                   devices over axis ``parts``, compiled
+                                   once per shape — the production path.
 
 ``simulate_pagerank`` / ``shard_map_pagerank`` / ``simulate_cc`` /
 ``shard_map_cc`` are thin instantiations of ``pagerank_program()`` /
@@ -51,12 +52,12 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .partition import PartitionLayout
 from .. import obs
 from ..dist import collectives as coll
-from ..dist.halo import RAGGED_EXCHANGES, get_exchange
+from ..dist.halo import RAGGED_EXCHANGES, get_exchange, lossy_payload
 
 DAMPING = 0.85
 # CC labels are int32 vertex ids; the min-identity sentinel marks padded /
@@ -413,10 +414,13 @@ def _gas_body(program: GASProgram, ex, dev, axis: str | None = None,
               overlap: bool = False):
     """One GAS iteration as a ``fori_loop`` body over (value, state).
 
-    ``axis=None`` is the stacked form: ``dev`` holds full (k, …) stacks,
-    per-device callables vmap over the leading axis, and the exchange's
-    ``*_stacked`` halves model the collectives.  With a mesh axis it is
-    the per-device form run inside shard_map.  Both forms call the same
+    The per-partition callables vmap over the leading axis of ``dev``.
+    ``axis=None`` is the stacked form: ``dev`` holds full (k, …) stacks
+    and the exchange's ``*_stacked`` halves model the collectives.  With
+    a mesh axis it is the per-device form run inside shard_map: ``dev``
+    holds the device's local stacks (its k/D partitions), the global aux
+    is psum'd over the axis after a sum over them, and the exchange's
+    per-device halves run the collectives.  Both forms call the same
     ``program`` callables, so the simulated and production paths cannot
     drift.
 
@@ -433,43 +437,24 @@ def _gas_body(program: GASProgram, ex, dev, axis: str | None = None,
     body is bit-identical to the phase-ordered one — same collectives,
     same values, shorter critical path."""
     stacked = axis is None
+    reduce = ex.reduce_stacked if stacked else ex.reduce_to_masters
+    broadcast = ex.broadcast_stacked if stacked else ex.broadcast_from_masters
 
     def step(carry):
         value, state = carry
-        if program.aux is not None:
-            aux = (jnp.sum(jax.vmap(program.aux)(value, dev)) if stacked
-                   else coll.psum(program.aux(value, dev), axis))
+        aux = (coll.psum(jnp.sum(jax.vmap(program.aux)(value, dev)), axis)
+               if program.aux is not None else None)
+        apply = jax.vmap(lambda t, d: program.apply(t, aux, d))
+        partial_ = jax.vmap(program.local)(value, dev)
+        if overlap:
+            total, state = reduce(partial_, dev, program.combine, state,
+                                  hopwise=True)
+            new_master = jnp.where(dev["frontier"], apply(total, dev),
+                                   apply(partial_, dev))
         else:
-            aux = None
-        if stacked:
-            partial_ = jax.vmap(program.local)(value, dev)
-            if overlap:
-                total, state = ex.reduce_stacked(
-                    partial_, dev, program.combine, state, hopwise=True)
-                app = jax.vmap(lambda t, d: program.apply(t, aux, d))
-                new_master = jnp.where(dev["frontier"], app(total, dev),
-                                       app(partial_, dev))
-            else:
-                total, state = ex.reduce_stacked(partial_, dev,
-                                                 program.combine, state)
-                new_master = jax.vmap(
-                    lambda t, d: program.apply(t, aux, d))(total, dev)
-            value, state = ex.broadcast_stacked(new_master, dev,
-                                                program.combine, state)
-        else:
-            partial_ = program.local(value, dev)
-            if overlap:
-                total, state = ex.reduce_to_masters(
-                    partial_, dev, program.combine, state, hopwise=True)
-                new_master = jnp.where(
-                    dev["frontier"], program.apply(total, aux, dev),
-                    program.apply(partial_, aux, dev))
-            else:
-                total, state = ex.reduce_to_masters(partial_, dev,
-                                                    program.combine, state)
-                new_master = program.apply(total, aux, dev)
-            value, state = ex.broadcast_from_masters(new_master, dev,
-                                                     program.combine, state)
+            total, state = reduce(partial_, dev, program.combine, state)
+            new_master = apply(total, dev)
+        value, state = broadcast(new_master, dev, program.combine, state)
         return value, state
 
     return _scoped_body(step, program.name)
@@ -632,71 +617,143 @@ def simulate_cc(layout: PartitionLayout, iters: int = 30,
 
 # ----------------------------------------------------------- shard_map driver
 
+# traces of the mesh GAS loop: ``_mesh_gas`` bumps it while jax traces
+# it, never when a cached executable runs (the ``traced`` attribute of
+# ``gas.run``)
+_MESH_TRACES = [0]
+
+
+def mesh_traces() -> int:
+    """Traces of the mesh GAS loop in this process so far."""
+    return _MESH_TRACES[0]
+
+
+def _parts_per_device(layout: PartitionLayout, mesh: Mesh,
+                      axis: str) -> int:
+    devices = mesh.shape[axis]
+    if layout.k % devices:
+        raise ValueError(f"mesh axis {axis!r} has {devices} devices, which "
+                         f"do not divide k = {layout.k} partitions")
+    return layout.k // devices
+
+
+def _mesh_dev(layout: PartitionLayout, exchange: str, mesh: Mesh,
+              axis: str):
+    """The layout's tables, each device holding its k/D partitions."""
+    return jax.device_put(layout.device_arrays(exchange),
+                          NamedSharding(mesh, P(axis)))
+
+
 def shard_map_gas(program: GASProgram, layout: PartitionLayout, mesh: Mesh,
                   iters: int = 30, axis: str = "parts",
                   exchange: str = "dense", *, tol: float | None = None,
                   overlap: bool = False, init_values=None,
                   return_iters: bool = False):
-    """Production path: one partition per device along ``axis``.
-    Requires mesh axis size == layout.k.  ``exchange`` picks the mirror
-    wire format (see module docstring).  Returns (V,) master values.
-    ``tol`` / ``overlap`` / ``init_values`` / ``return_iters`` as in
-    ``simulate_gas`` — the residual is pmax'd across the mesh so every
-    device exits the while_loop on the same iteration."""
+    """Production path: the k partitions spread over the D devices of
+    mesh ``axis``, k/D to a device (D must divide k); each device runs
+    the GAS body over its partitions as one batch.  Several partitions
+    per device need the ``halo`` or ``dense`` wire; the others route one
+    partition per device and refuse more (``dist.halo``).  ``exchange``
+    picks the mirror wire format (see module docstring).  Returns (V,)
+    master values.  ``tol`` / ``overlap`` / ``init_values`` /
+    ``return_iters`` as in ``simulate_gas`` — the residual is a max over
+    the device's partitions, pmax'd across the mesh so every device
+    exits the while_loop on the same iteration.
+
+    The loop is jitted and cached per (program, wire, mesh, static
+    shapes), like ``_sim_gas``: a second run of the same shapes traces
+    and compiles nothing (``_mesh_run``)."""
     _check_overlap(exchange, overlap)
+    parts = _parts_per_device(layout, mesh, axis)
     with obs.span("gas." + program.name) as run_span:
         with obs.span("gas.upload"):
-            dev = _stack_dev(layout, exchange)
+            dev = _mesh_dev(layout, exchange, mesh, axis)
             ex = get_exchange(exchange, layout, axis=axis)
             warm = (None if init_values is None
                     else _warm_tables(layout, program.dtype, init_values))
-        with obs.span("gas.run"):
-            vals, iters_run = _shard_map_run(program, dev, warm, ex, mesh,
-                                             iters, axis, tol, overlap)
+        vals, iters_run = _mesh_run(program, layout, exchange, dev, warm,
+                                    ex, mesh, axis, iters, tol, overlap)
         with obs.span("gas.collect"):
             dense = _master_values(layout, vals)
         run_span.attrs["iters"] = iters_run
     return (dense, iters_run) if return_iters else dense
 
 
-def _shard_map_run(program: GASProgram, dev, warm, ex, mesh: Mesh,
-                   iters: int, axis: str, tol: float | None,
-                   overlap: bool) -> tuple:
-    """Dispatch the shard_map'd loop; (host (k, L_max) values, iterations
-    run) once both are back on the host."""
-    spec = P(axis)
-    args = (dev,) if warm is None else (dev, warm)
-    specs = tuple(jax.tree_util.tree_map(lambda _: spec, a) for a in args)
+def _mesh_run(program, layout: PartitionLayout, exchange: str, dev, warm,
+              ex, mesh: Mesh, axis: str, iters: int, tol: float | None,
+              overlap: bool) -> tuple:
+    """``gas.run`` around the cached mesh loop of a program or a fused
+    bundle: (host values, iterations run) once both are back on the
+    host.  The span records ``devices``, ``parts_per_device``,
+    ``ici_bytes`` (what one chip sends over the interconnect per
+    iteration, both phases, as padded on the wire: the layout's
+    ``comm_bytes(parts_per_device=…)``) and ``traced`` (traces of the
+    loop in this call)."""
+    parts = _parts_per_device(layout, mesh, axis)
+    fused = isinstance(program, FusedGAS)
+    ici = layout.comm_bytes(
+        exchange, programs=len(program.programs) if fused else 1,
+        fused=fused, lossy=lossy_payload(program.combine, program.dtype),
+        value_bytes=jnp.dtype(program.dtype).itemsize,
+        parts_per_device=parts)
+    with obs.span("gas.run", devices=mesh.shape[axis],
+                  parts_per_device=parts, ici_bytes=ici) as gas_run:
+        traced = mesh_traces()
+        out = _mesh_gas(program, dev, iters, ex, mesh, axis, tol, overlap,
+                        warm)
+        values, iters_run = (out, iters) if tol is None else out
+        vals = np.asarray(values)
+        iters_run = int(np.asarray(iters_run).reshape(-1)[0])
+        gas_run.attrs["traced"] = mesh_traces() - traced
+    return vals, iters_run
 
-    @partial(jax.shard_map, mesh=mesh, in_specs=specs,
-             out_specs=spec if tol is None else (spec, spec))
+
+def _mesh_loop(program: GASProgram, ex, iters: int, axis: str,
+               tol: float | None, overlap: bool):
+    """The per-device GAS loop inside shard_map, over the device's local
+    stacks: (m, L_max) values out, and with ``tol`` the (1,) iteration
+    count."""
     def run(dev, *warm_arg):
-        dev = jax.tree_util.tree_map(lambda x: x[0], dev)
-        value = program.init(dev)
+        value = jax.vmap(program.init)(dev)
         if warm_arg:
-            wvals, wmask = jax.tree_util.tree_map(lambda x: x[0],
-                                                  warm_arg[0])
+            wvals, wmask = warm_arg[0]
             value = jnp.where(wmask, wvals, value)
         # a program whose init ignores the layout (degree's zeros) starts
         # the same on every device; the loop carry must vary from step 0
         value = coll.varying(value, axis)
         if not iters:
-            return (value[None] if tol is None
-                    else (value[None], jnp.zeros((1,), jnp.int32)))
+            return (value if tol is None
+                    else (value, jnp.zeros((1,), jnp.int32)))
         state = ex.init_state(dev, program.dtype, program.combine)
         body = _gas_body(program, ex, dev, axis, overlap=overlap)
         if tol is None:
             value, _ = jax.lax.fori_loop(0, iters, body, (value, state))
-            return value[None]
+            return value
         mask = dev["vert_mask"] & dev["is_master"]
         value, i = _converge_loop(body, value, state, iters, tol, mask,
                                   axis)
-        return value[None], i[None]
+        return value, i[None]
 
-    with mesh:
-        out = run(*args)
-    values, iters_run = (out, iters) if tol is None else out
-    return np.asarray(values), int(np.asarray(iters_run).reshape(-1)[0])
+    return run
+
+
+@partial(jax.jit, static_argnames=("program", "iters", "ex", "mesh", "axis",
+                                   "tol", "overlap"))
+def _mesh_gas(program, dev, iters: int, ex, mesh: Mesh, axis: str,
+              tol: float | None = None, overlap: bool = False, warm=None):
+    """The shard_map'd loop of a program or a fused bundle, compiled once
+    per (program, wire instance, mesh, static shapes) as ``_sim_gas`` is:
+    (k, L_max) values ((k, N, L_max) fused), and with ``tol`` the
+    iterations run, one entry per device."""
+    _MESH_TRACES[0] += 1
+    spec = P(axis)
+    args = (dev,) if warm is None else (dev, warm)
+    loop = (_mesh_loop_many if isinstance(program, FusedGAS)
+            else _mesh_loop)
+    run = jax.shard_map(loop(program, ex, iters, axis, tol, overlap),
+                        mesh=mesh, in_specs=(spec,) * len(args),
+                        out_specs=spec if tol is None else (spec, spec))
+    return run(*args)
 
 
 def shard_map_pagerank(layout: PartitionLayout, mesh: Mesh,
@@ -902,21 +959,27 @@ def shard_map_gas_many(programs, layout: PartitionLayout, mesh: Mesh,
                        exchange: str = "dense", *,
                        tol: float | None = None, overlap: bool = False,
                        init_values=None, return_iters: bool = False):
-    """Production fused path: N programs per device along ``axis``, one
-    mirror-sync collective per phase for the whole bundle.  ``tol`` /
-    ``overlap`` / ``init_values`` / ``return_iters`` as in
+    """Production fused path: N programs on one partition per device
+    along ``axis`` (mesh axis size == k; more partitions per device are
+    refused), one mirror-sync collective per phase for the whole bundle.
+    ``tol`` / ``overlap`` / ``init_values`` / ``return_iters`` as in
     ``simulate_gas_many``."""
     _check_overlap(exchange, overlap)
+    if _parts_per_device(layout, mesh, axis) != 1:
+        raise ValueError(
+            f"the fused mesh driver runs one partition per device; mesh "
+            f"axis {axis!r} has {mesh.shape[axis]} devices for k = "
+            f"{layout.k}: run the programs one by one (shard_map_gas), or "
+            "on a mesh of k devices")
     fused = fuse_programs(programs)
     with obs.span("gas." + fused.name) as run_span:
         with obs.span("gas.upload"):
-            dev = _stack_dev(layout, exchange)
+            dev = _mesh_dev(layout, exchange, mesh, axis)
             ex = get_exchange(exchange, layout, axis=axis)
             warm = (None if init_values is None
                     else _warm_tables_many(layout, fused, init_values))
-        with obs.span("gas.run"):
-            vals, iters_run = _shard_map_run_many(fused, dev, warm, ex, mesh,
-                                                  iters, axis, tol, overlap)
+        vals, iters_run = _mesh_run(fused, layout, exchange, dev, warm, ex,
+                                    mesh, axis, iters, tol, overlap)
         with obs.span("gas.collect"):
             dense = [_master_values(layout, vals[:, i])
                      for i in range(len(fused.programs))]
@@ -924,17 +987,11 @@ def shard_map_gas_many(programs, layout: PartitionLayout, mesh: Mesh,
     return (dense, iters_run) if return_iters else dense
 
 
-def _shard_map_run_many(fused: FusedGAS, dev, warm, ex, mesh: Mesh,
-                        iters: int, axis: str, tol: float | None,
-                        overlap: bool) -> tuple:
-    """``_shard_map_run`` for a fused bundle: (host (k, N, L_max) values,
-    iterations run)."""
-    spec = P(axis)
-    args = (dev,) if warm is None else (dev, warm)
-    specs = tuple(jax.tree_util.tree_map(lambda _: spec, a) for a in args)
-
-    @partial(jax.shard_map, mesh=mesh, in_specs=specs,
-             out_specs=spec if tol is None else (spec, spec))
+def _mesh_loop_many(fused: FusedGAS, ex, iters: int, axis: str,
+                    tol: float | None, overlap: bool):
+    """The per-device fused loop inside shard_map, over the device's one
+    partition: (1, N, L_max) values out, and with ``tol`` the (1,)
+    iteration count."""
     def run(dev, *warm_arg):
         dev = jax.tree_util.tree_map(lambda x: x[0], dev)
         value = jnp.stack([p.init(dev) for p in fused.programs])
@@ -959,10 +1016,7 @@ def _shard_map_run_many(fused: FusedGAS, dev, warm, ex, mesh: Mesh,
                                   axis)
         return value[None], i[None]
 
-    with mesh:
-        out = run(*args)
-    values, iters_run = (out, iters) if tol is None else out
-    return np.asarray(values), int(np.asarray(iters_run).reshape(-1)[0])
+    return run
 
 
 def gas_step_for_dryrun(program, layout: PartitionLayout,
@@ -983,34 +1037,25 @@ def gas_step_for_dryrun(program, layout: PartitionLayout,
     dev = _stack_dev(layout, exchange)
     ex = get_exchange(exchange, layout, axis=axis)
     spec = P(axis)
-    fused = (None if isinstance(program, GASProgram)
-             else fuse_programs(program))
+    if isinstance(program, GASProgram):
+        step = jax.shard_map(
+            _mesh_loop(program, ex, iters, axis, None, overlap), mesh=mesh,
+            in_specs=(spec,), out_specs=spec)
+        return jax.jit(step), (dev,)
+    fused = fuse_programs(program)
 
-    @partial(jax.shard_map, mesh=mesh,
-             in_specs=(jax.tree_util.tree_map(lambda _: spec, dev),),
-             out_specs=spec)
-    def step(dev):
+    @partial(jax.shard_map, mesh=mesh, in_specs=(spec,), out_specs=spec)
+    def step_many(dev):
         dev = jax.tree_util.tree_map(lambda x: x[0], dev)
-        if fused is None:
-            value = program.init(dev)
-            if iters:
-                state = ex.init_state(dev, program.dtype, program.combine)
-                body = _gas_body(program, ex, dev, axis, overlap=overlap)
-                value, _ = jax.lax.fori_loop(0, iters, body,
-                                             (value, state))
-        else:
-            value = jnp.stack([p.init(dev) for p in fused.programs])
-            if iters:
-                state = ex.init_state_multi(dev, fused.dtype,
-                                            fused.combine,
-                                            len(fused.programs))
-                body = _gas_body_multi(fused, ex, dev, axis,
-                                       overlap=overlap)
-                value, _ = jax.lax.fori_loop(0, iters, body,
-                                             (value, state))
+        value = jnp.stack([p.init(dev) for p in fused.programs])
+        if iters:
+            state = ex.init_state_multi(dev, fused.dtype, fused.combine,
+                                        len(fused.programs))
+            body = _gas_body_multi(fused, ex, dev, axis, overlap=overlap)
+            value, _ = jax.lax.fori_loop(0, iters, body, (value, state))
         return value[None]
 
-    return jax.jit(step), (dev,)
+    return jax.jit(step_many), (dev,)
 
 
 def pagerank_step_for_dryrun(layout: PartitionLayout, mesh: Mesh,

@@ -49,7 +49,9 @@ replication factors (the common case on web graphs) make Σ_s H_s ≪
 """
 from __future__ import annotations
 
+import os
 import warnings
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 
 import numpy as np
@@ -153,7 +155,8 @@ class PartitionLayout:
 
     def comm_bytes(self, exchange: str | None = None, *, programs: int = 1,
                    fused: bool = False, lossy: bool = True,
-                   value_bytes: int = 4, top_delta: float = 0.25):
+                   value_bytes: int = 4, top_delta: float = 0.25,
+                   parts_per_device: int | None = None):
         """Modelled mirror-sync wire bytes per GAS iteration, keyword-
         routed:
 
@@ -167,7 +170,19 @@ class PartitionLayout:
         - ``comm_bytes(exchange, programs=N, fused=True)`` — N
           homogeneous programs as one fused step (single collective per
           phase; the int4 fused wire when quantized + lossy).
+        - ``comm_bytes(exchange, parts_per_device=m)`` — what ONE chip of
+          a mesh of k/m devices, m partitions on each, sends to the other
+          chips over the interconnect; lanes between partitions on the
+          same chip never leave it.  The halo and dense wires hold any m
+          dividing k (halo: its m partitions' H_max-padded lanes to the
+          k − m partitions elsewhere; dense: its (m, L_max) block to the
+          k/m − 1 other chips); the other wires route one partition a
+          chip, where each chip sends 1/k of the whole wire.
         """
+        if parts_per_device is not None:
+            return self._bytes_per_chip(
+                exchange, parts_per_device, programs=programs, fused=fused,
+                lossy=lossy, value_bytes=value_bytes, top_delta=top_delta)
         if exchange is None:
             if fused or programs != 1:
                 raise ValueError(
@@ -201,6 +216,27 @@ class PartitionLayout:
             "allreduce": lambda: self._bytes_allreduce(value_bytes),
         }[exchange]()
         return programs * single
+
+    def _bytes_per_chip(self, exchange: str, m: int, *, programs: int,
+                        fused: bool, lossy: bool, value_bytes: int,
+                        top_delta: float) -> int:
+        """``comm_bytes(exchange, parts_per_device=m)``."""
+        if m < 1 or self.k % m:
+            raise ValueError(f"{m} partitions a device do not divide "
+                             f"k = {self.k}")
+        if exchange == "halo" or (exchange == "quantized" and not lossy):
+            return programs * 2 * m * (self.k - m) * self.h_max * value_bytes
+        if exchange in ("dense", "dense_gather"):
+            return programs * 2 * (self.k - m) * self.l_max * value_bytes
+        if exchange not in ("quantized", "ragged", "ragged_quantized"):
+            raise ValueError(f"{exchange!r} is not a wire of the mesh "
+                             "engine")
+        if m != 1:
+            raise ValueError(f"the {exchange!r} wire routes one partition "
+                             "per device")
+        return self.comm_bytes(exchange, programs=programs, fused=fused,
+                               lossy=lossy, value_bytes=value_bytes,
+                               top_delta=top_delta) // self.k
 
     def _bytes_dense_gather(self, value_bytes: int = 4) -> int:
         """Dense backend: all_gather(k, L_max) twice — every device receives
@@ -345,12 +381,58 @@ def _pad_to(n: int, pad_multiple: int) -> int:
     return int(np.ceil(max(n, 1) / pad_multiple) * pad_multiple)
 
 
+# numpy's sorts, gathers and bincounts release the GIL: the layout's
+# per-partition passes over disjoint slices run side by side
+HOST_THREADS = min(8, os.cpu_count() or 1)
+
+
+def _host_map(fn, items) -> list:
+    """``[fn(x) for x in items]``, on ``HOST_THREADS`` threads.  A pool
+    is made per call (a pool kept across calls would not survive a
+    fork)."""
+    items = list(items)
+    if HOST_THREADS < 2 or len(items) < 2:
+        return [fn(x) for x in items]
+    with ThreadPoolExecutor(min(HOST_THREADS, len(items))) as pool:
+        return list(pool.map(fn, items))
+
+
+def _edges_by_partition(assign: np.ndarray, k: int) -> list:
+    """For each partition, the indices of its edges in stream order.
+
+    The stream is cut into one contiguous chunk a thread; each chunk is
+    sorted by partition with a stable sort of 16-bit keys (numpy's radix
+    sort), and a partition's runs are then joined in chunk order."""
+    assign = np.asarray(assign)
+    E = assign.shape[0]
+    cuts = [E * c // HOST_THREADS for c in range(HOST_THREADS + 1)]
+
+    def sort(c):
+        lo, hi = cuts[c], cuts[c + 1]
+        a = assign[lo:hi]
+        order = np.argsort(a.astype(np.int16) if k <= (1 << 15) else a,
+                           kind="stable")
+        order += lo
+        ends = np.zeros(k + 1, dtype=np.int64)
+        np.cumsum(np.bincount(a, minlength=k)[:k], out=ends[1:])
+        return order, ends
+
+    runs = _host_map(sort, range(HOST_THREADS))
+    return _host_map(lambda p: np.concatenate(
+        [order[ends[p]:ends[p + 1]] for order, ends in runs]), range(k))
+
+
 def build_layout(src: np.ndarray, dst: np.ndarray, assign: np.ndarray,
                  num_vertices: int, k: int,
                  pad_multiple: int = 8) -> PartitionLayout:
-    """Vectorized layout builder — pure np.unique/searchsorted/bincount
+    """Vectorized layout builder — bincount/radix-sort/searchsorted
     passes, no per-vertex Python loops (≥5× the reference builder at 10k
     vertices; see ``build_layout_reference`` for the retained oracle).
+    The edges are grouped by partition, and each partition's vertices
+    counted in a (V,) table where k·V ≤ 2^25, else found by sorting its
+    endpoints: the same tables either way.  The per-partition passes run
+    side by side on ``HOST_THREADS`` threads (numpy releases the GIL);
+    only the master election walks the partitions in order.
 
     Accepts device-resident (jax) arrays directly: the jit/sharded
     partitioner backends hand their edge→partition assignment straight in
@@ -364,126 +446,114 @@ def build_layout(src: np.ndarray, dst: np.ndarray, assign: np.ndarray,
 def _build_layout(src, dst, assign, num_vertices: int, k: int,
                   pad_multiple: int) -> PartitionLayout:
     E = src.shape[0]
-    src = np.asarray(src, dtype=np.int64)
-    dst = np.asarray(dst, dtype=np.int64)
-    assign = np.asarray(assign)
-    order = np.argsort(assign, kind="stable")
-    s, d, a = src[order], dst[order], assign[order].astype(np.int64)
-    bounds = np.searchsorted(a, np.arange(k + 1))
+    V = num_vertices
+    src = np.asarray(src)
+    dst = np.asarray(dst)
+    # a partition's vertices are found in a (V,) count table when k·V is
+    # small enough (≤ 2^25), else by sorting its endpoints
+    dense = k * V <= (1 << 25)
+    edges = _edges_by_partition(assign, k)
+    gdeg = np.bincount(src, minlength=V)          # global out degree
 
-    # global out degree
-    gdeg = np.bincount(src, minlength=num_vertices)
+    def scan(p):
+        """Partition p's edge endpoints, its vertices (ascending: the
+        order of its local slots) and each one's endpoint count."""
+        s, d = src[edges[p]], dst[edges[p]]
+        if dense:
+            cnt = np.bincount(s, minlength=V) + np.bincount(d, minlength=V)
+            verts = np.flatnonzero(cnt)
+            return s, d, verts, cnt[verts]
+        verts, cnt = np.unique(np.concatenate([s, d]), return_counts=True)
+        return s, d, verts, cnt
 
-    # one row per (partition, vertex) replica, with its endpoint count.
-    # np.unique on the fused key sorts by (partition, vertex), so rows are
-    # grouped by partition with vertices ascending — the same order the
-    # reference builder's per-partition np.unique produces.
-    key = np.concatenate([a, a]) * num_vertices + np.concatenate([s, d])
-    uniq, cnt = np.unique(key, return_counts=True)
-    up = uniq // num_vertices        # partition of each replica row
-    uv = uniq % num_vertices         # vertex gid of each replica row
-    n_rows = uniq.shape[0]
+    parts = _host_map(scan, range(k))
 
     # master election: per vertex, the partition with max endpoint count,
-    # ties → lowest partition id.  lexsort is keyed last-to-first.
-    elect = np.lexsort((up, -cnt, uv))
-    uv_e, up_e = uv[elect], up[elect]
-    first = np.ones(n_rows, dtype=bool)
-    np.not_equal(uv_e[1:], uv_e[:-1], out=first[1:])
-    master_of = np.full(num_vertices, -1, dtype=np.int64)
-    master_of[uv_e[first]] = up_e[first]
-
-    part_sizes = np.bincount(up, minlength=k)
-    l_max = _pad_to(int(part_sizes.max(initial=1)), pad_multiple)
-    e_max = _pad_to(int(max(bounds[1:] - bounds[:-1], default=1)),
+    # ties → lowest partition id
+    best = np.zeros(V, dtype=np.int64)
+    master_of = np.zeros(V, dtype=np.int32)
+    replic = np.zeros(V, dtype=np.int32)
+    for p, (_, _, verts, cnt) in enumerate(parts):
+        win = cnt > best[verts]
+        best[verts[win]] = cnt[win]
+        master_of[verts[win]] = p
+        replic[verts] += 1
+    # each vertex's slot in its master partition
+    own_slot_of = np.zeros(V, dtype=np.int32)
+    owners = []
+    for p, (_, _, verts, _) in enumerate(parts):
+        own = master_of[verts]
+        owners.append(own)
+        mine = np.flatnonzero(own == p)
+        own_slot_of[verts[mine]] = mine
+    l_max = _pad_to(max((v.shape[0] for _, _, v, _ in parts), default=1),
                     pad_multiple)
+    e_max = _pad_to(max((s.shape[0] for s, _, _, _ in parts), default=1),
+                    pad_multiple)
+    # mirror lanes per ordered (mirror partition, owner partition) pair
+    halo_cnt = np.stack([np.bincount(own[own != p], minlength=k)
+                         for p, own in enumerate(owners)]).astype(np.int32)
+    h_max = _pad_to(int(halo_cnt.max(initial=0)), pad_multiple)
 
-    # local slot of each replica row = rank within its partition group
-    row_start = np.searchsorted(up, np.arange(k + 1))
-    slot = np.arange(n_rows) - row_start[up]
-
-    if k * num_vertices <= (1 << 25):
-        # dense inverse map: O(1) per lookup, ≤128 MiB of int32
-        _lookup = np.empty(k * num_vertices, dtype=np.int32)
-        _lookup[uniq] = slot
-
-        def slot_of(parts: np.ndarray, verts: np.ndarray) -> np.ndarray:
-            """Vectorized (partition, gid) → local slot."""
-            return _lookup[parts * num_vertices + verts]
-    else:
-        def slot_of(parts: np.ndarray, verts: np.ndarray) -> np.ndarray:
-            """Vectorized (partition, gid) → local slot via sorted keys."""
-            return slot[np.searchsorted(uniq, parts * num_vertices + verts)]
-
-    replic = np.bincount(uv, minlength=num_vertices)
-
-    vert_gid = np.full((k, l_max), num_vertices, dtype=np.int32)
+    vert_gid = np.empty((k, l_max), dtype=np.int32)
     vert_mask = np.zeros((k, l_max), dtype=bool)
     is_master = np.zeros((k, l_max), dtype=bool)
     out_deg = np.zeros((k, l_max), dtype=np.int32)
     owner = np.zeros((k, l_max), dtype=np.int32)
     own_slot = np.zeros((k, l_max), dtype=np.int32)
     frontier = np.zeros((k, l_max), dtype=bool)
-    row_owner = master_of[uv]
-    row_own_slot = slot_of(row_owner, uv)
-    row_is_master = row_owner == up
-    row_deg = gdeg[uv]
-    row_frontier = replic[uv] > 1
-    # rows are grouped by partition, so per-partition contiguous slice
-    # copies beat a (k, slot) fancy scatter by ~5×
-    for p in range(k):
-        r0, r1 = int(row_start[p]), int(row_start[p + 1])
-        n = r1 - r0
-        if n == 0:
-            continue
-        rows = slice(r0, r1)
-        vert_gid[p, :n] = uv[rows]
-        vert_mask[p, :n] = True
-        is_master[p, :n] = row_is_master[rows]
-        out_deg[p, :n] = row_deg[rows]
-        owner[p, :n] = row_owner[rows]
-        own_slot[p, :n] = row_own_slot[rows]
-        frontier[p, :n] = row_frontier[rows]
-
     # reduce map: flat all_gather entry (j*L_max + slot) → my slot (if I am
     # the owner of that entry's vertex) else l_max (dropped)
-    red_index = np.full((k, k * l_max), l_max, dtype=np.int32)
-    red_index[row_owner, up * l_max + slot] = row_own_slot
-
-    edge_src = np.full((k, e_max), l_max, dtype=np.int32)
-    edge_dst = np.full((k, e_max), l_max, dtype=np.int32)
+    red_index = np.empty((k, k * l_max), dtype=np.int32)
+    edge_src = np.empty((k, e_max), dtype=np.int32)
+    edge_dst = np.empty((k, e_max), dtype=np.int32)
     edge_mask = np.zeros((k, e_max), dtype=bool)
-    if E:
-        src_slots = slot_of(a, s)
-        dst_slots = slot_of(a, d)
-        # edges are sorted by partition: contiguous copies, no scatter
-        for p in range(k):
-            lo, hi = int(bounds[p]), int(bounds[p + 1])
-            n = hi - lo
-            if n == 0:
-                continue
-            edge_src[p, :n] = src_slots[lo:hi]
-            edge_dst[p, :n] = dst_slots[lo:hi]
-            edge_mask[p, :n] = True
-
     # halo routing tables: one lane per mirror replica, grouped by the
-    # ordered (mirror partition, owner partition) pair and padded to the
-    # max pair population H_max — every mirror is routed exactly once.
-    mir = row_owner != up
-    mp_, mq = up[mir], row_owner[mir]
-    m_slot, m_own_slot = slot[mir], row_own_slot[mir]
-    pair = mp_ * k + mq
-    po = np.argsort(pair, kind="stable")
-    pair_s = pair[po]
-    lane = np.arange(pair_s.shape[0]) - np.searchsorted(pair_s, pair_s)
-    h_max = _pad_to(int(lane.max(initial=-1)) + 1, pad_multiple)
-    halo_send = np.full((k, k, h_max), l_max, dtype=np.int32)
-    halo_recv = np.full((k, k, h_max), l_max, dtype=np.int32)
-    halo_send[mp_[po], mq[po], lane] = m_slot[po]
-    halo_recv[mq[po], mp_[po], lane] = m_own_slot[po]
-    halo_cnt = np.bincount(pair, minlength=k * k).reshape(k, k) \
-        .astype(np.int32)
+    # ordered (mirror, owner) pair in local-slot order and padded to the
+    # max pair population H_max — every mirror is routed exactly once
+    halo_send = np.empty((k, k, h_max), dtype=np.int32)
+    halo_recv = np.empty((k, k, h_max), dtype=np.int32)
 
+    def fill(p):
+        """Row p of every table, and partition p's columns of red_index
+        and halo_recv: the partitions write disjoint cells."""
+        s, d, verts, _ = parts[p]
+        own, n, m = owners[p], verts.shape[0], s.shape[0]
+        slots = own_slot_of[verts]
+        vert_gid[p, :n] = verts
+        vert_gid[p, n:] = V
+        vert_mask[p, :n] = True
+        is_master[p, :n] = own == p
+        out_deg[p, :n] = gdeg[verts]
+        owner[p, :n] = own
+        own_slot[p, :n] = slots
+        frontier[p, :n] = replic[verts] > 1
+        cols = red_index[:, p * l_max:(p + 1) * l_max]
+        cols.fill(l_max)
+        cols[own, np.arange(n)] = slots
+        if dense:
+            local = np.empty(V, dtype=np.int32)     # vertex → local slot
+            local[verts] = np.arange(n, dtype=np.int32)
+            edge_src[p, :m] = local[s]
+            edge_dst[p, :m] = local[d]
+        else:
+            edge_src[p, :m] = np.searchsorted(verts, s)
+            edge_dst[p, :m] = np.searchsorted(verts, d)
+        edge_src[p, m:] = l_max
+        edge_dst[p, m:] = l_max
+        edge_mask[p, :m] = True
+        mirror = np.flatnonzero(own != p)
+        mirror = mirror[np.argsort(own[mirror], kind="stable")]
+        to = own[mirror]
+        first = np.zeros(k, dtype=np.int64)
+        np.cumsum(halo_cnt[p, :-1], out=first[1:])
+        lane = np.arange(mirror.shape[0]) - first[to]
+        halo_send[p].fill(l_max)
+        halo_recv[:, p].fill(l_max)
+        halo_send[p, to, lane] = mirror
+        halo_recv[to, p, lane] = slots[mirror]
+
+    _host_map(fill, range(k))
     mirrors_total = int(np.maximum(replic - 1, 0).sum())
 
     return PartitionLayout(

@@ -12,6 +12,8 @@ meshes are entered only by ``shard_map``, which takes every axis into
 manual control whatever its type."""
 from __future__ import annotations
 
+import warnings
+
 import jax
 from jax.sharding import AxisType
 
@@ -32,8 +34,26 @@ def make_test_mesh(n_data: int = 2, n_model: int = 4):
 
 
 def make_graph_mesh(k: int):
-    """The graph engine's mesh: k partitions on one flat axis."""
-    return _auto_mesh((k,), ("parts",))
+    """The graph engine's mesh for k partitions: one flat axis ``parts``
+    over the largest number of visible devices that divides k — k itself
+    when k ≤ devices, else k/D partitions on each of D devices (16
+    partitions on four chips put four on each).  The engine reads the
+    partitions per device off the layout and the axis size.  Where no
+    count of devices above one divides k (a prime k above the device
+    count) it raises, rather than put every partition on one chip of a
+    multi-chip host; where the divisor leaves devices idle (k = 20 on
+    eight) it warns."""
+    n = len(jax.devices())
+    devices = max(d for d in range(1, min(k, n) + 1) if k % d == 0)
+    if k > n:
+        if devices == 1 < n:
+            raise ValueError(
+                f"no count of the {n} devices above one divides k = {k}: "
+                f"pick a k that {n} devices or fewer divide")
+        if devices < n:
+            warnings.warn(f"k = {k} partitions on {devices} of the {n} "
+                          f"devices: {n - devices} stay idle", stacklevel=2)
+    return _auto_mesh((devices,), ("parts",))
 
 
 def make_stream_mesh(n: int):

@@ -24,7 +24,8 @@ assignment (baselines) so the layout/engine/accounting half of the session
 works on it; ``run`` takes a program name (any of ``PROGRAMS`` — the
 ``repro.graph.engine`` library: pagerank/cc/labelprop/sssp/bfs/degree/
 centrality/ppr) or any ``GASProgram`` and simulates on one device
-(``mesh=None``) or shard_maps one partition per device; ``run_many``
+(``mesh=None``) or shard_maps k/D partitions onto each of D devices;
+``run_many``
 executes N homogeneous programs as one fused loop with a single mirror
 exchange per phase; ``dryrun_step`` hands the compile-only cell (single
 or fused) to ``launch.dryrun --graph``; ``comm_bytes(programs=...,
@@ -324,9 +325,11 @@ class GraphSession:
             init_values=None, return_iters: bool = False):
         """Run a GAS program on the session's layout and return the dense
         (V,) master values.  ``mesh=None`` simulates the stacked k-device
-        engine on one device; with a mesh (axis size == k) the program
-        shard_maps one partition per device — bit-identical results by
-        construction (shared ``_gas_body``).
+        engine on one device; with a mesh the program shard_maps the k
+        partitions over the axis's D devices, k/D to a device (D must
+        divide k; ``make_graph_mesh(k)`` picks it), compiled once per
+        shape — the same results by construction (shared ``_gas_body``),
+        up to the float32 summation order of a global aux.
 
         ``tol`` turns ``iters`` into a cap: the loop exits once the
         master residual max-norm drops to ``tol`` (``return_iters=True``
@@ -360,7 +363,8 @@ class GraphSession:
         (``repro.graph.engine.FusedGAS``).  Returns one dense (V,) array
         per program, in input order.  ``tol`` / ``overlap`` /
         ``init_values`` (one dense vector or None per program) /
-        ``return_iters`` as in ``run``."""
+        ``return_iters`` as in ``run``; a mesh must hold one partition
+        per device."""
         lay = self.partition_layout
         progs = [resolve_program(p, self._num_vertices) for p in programs]
         iters = self.cfg.iters if iters is None else iters

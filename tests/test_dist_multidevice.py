@@ -1,5 +1,6 @@
 """Multi-device (8 virtual CPU devices) integration tests, run in
-subprocesses: shard_map graph engine (single and fused multi-program),
+subprocesses: shard_map graph engine (single and fused multi-program, one
+and several partitions per device),
 SP decode, pipeline parallelism, compressed psum, sharded train step."""
 import pytest
 
@@ -218,6 +219,130 @@ def test_shard_map_fused_many_matches_simulation(multidevice):
     np.testing.assert_array_equal(
         z[0], np.full(V, np.float32(1.0 / V), np.float32))
     print('fused shard_map ok')
+    """)
+
+
+@pytest.mark.parametrize("exchange", ["halo", "dense"])
+@pytest.mark.parametrize("k", [16, 32])
+def test_mesh_gas_several_partitions_per_device(multidevice, k, exchange):
+    """k = 16 and 32 partitions on 8 devices (2 and 4 a device): mesh
+    PageRank to tol and CC through the session.  PageRank against
+    ``simulate_gas``: the same iteration count, and values within 1e-6
+    relative, because the two differ only in the float32 summation order
+    of the dangling mass (a sum per device, then psum, against one sum
+    over k), a few ulps.  Against the float64 reference at that count:
+    1e-5 relative, the float32 rounding of ~40 iterations (5.9e-7
+    measured).  CC is exact.  The halo step lowers to all-to-all and no
+    all-gather."""
+    multidevice(f"""
+    import numpy as np
+    from repro.analysis.ir import hlo_collectives
+    from repro.core import CLUGPConfig, web_graph
+    from repro.graph import reference_cc, reference_pagerank
+    from repro.launch.mesh import make_graph_mesh
+    from repro.session import GraphSession, SessionConfig
+
+    g = web_graph(scale=10, edge_factor=6, seed=3)
+    sess = GraphSession(SessionConfig(clugp=CLUGPConfig.optimized({k}),
+                                      exchange="{exchange}"))
+    sess.partition(g.src, g.dst, g.num_vertices).layout()
+    mesh = make_graph_mesh({k})
+    assert mesh.shape["parts"] == 8
+    pr, it = sess.run("pagerank", iters=100, tol=1e-6, mesh=mesh,
+                      return_iters=True)
+    sim, it_sim = sess.run("pagerank", iters=100, tol=1e-6,
+                           return_iters=True)
+    assert it == it_sim and 0 < it < 100, (it, it_sim)
+    assert np.max(np.abs(pr - sim) / sim) <= 1e-6
+    ref = reference_pagerank(g.src, g.dst, g.num_vertices, iters=it)
+    assert np.max(np.abs(pr - ref) / ref) <= 1e-5
+    cc = sess.run("cc", iters=60, mesh=mesh)
+    np.testing.assert_array_equal(
+        cc, reference_cc(g.src, g.dst, g.num_vertices))
+    if "{exchange}" == "halo":
+        jitted, args = sess.dryrun_step("pagerank", mesh=mesh)
+        kinds = [kind for kind, _, _ in
+                 hlo_collectives(jitted.lower(*args).compile().as_text())]
+        assert "all-to-all" in kinds and "all-gather" not in kinds, kinds
+    print("ok")
+    """)
+
+
+def test_mesh_gas_compiles_once_and_refuses_what_it_cannot_route(
+        multidevice):
+    """A second mesh run of the same shapes neither traces the loop
+    (``gas.run``'s ``traced`` is 0 and the trace counter holds) nor
+    leaves a compile record; ``gas.run`` says how the partitions lie.
+    The quantized (lossy payload) and ragged wires refuse two partitions
+    a device, as does the fused driver.  ``make_graph_mesh`` takes the
+    largest divisor of k that the 8 devices allow, warns where that
+    leaves devices idle, and raises where only one device would hold
+    every partition (a prime k above 8)."""
+    multidevice("""
+    import time
+    import warnings
+    import numpy as np
+    from repro import obs
+    from repro.core import CLUGPConfig, web_graph
+    from repro.graph.engine import mesh_traces
+    from repro.launch.mesh import make_graph_mesh
+    from repro.session import GraphSession, SessionConfig
+
+    with warnings.catch_warnings(record=True) as caught:
+        warnings.simplefilter("always")
+        assert [make_graph_mesh(k).shape["parts"]
+                for k in (1, 4, 6, 8, 12, 16, 20, 64)] == [1, 4, 6, 8, 6, 8,
+                                                            5, 8]
+    assert [str(w.message) for w in caught] == [
+        "k = 12 partitions on 6 of the 8 devices: 2 stay idle",
+        "k = 20 partitions on 5 of the 8 devices: 3 stay idle"]
+    for k in (13, 17):
+        try:
+            make_graph_mesh(k)
+        except ValueError as e:
+            assert f"divides k = {k}" in str(e), e
+        else:
+            raise AssertionError(f"k = {k} went onto one device")
+    g = web_graph(scale=9, edge_factor=6, seed=1)
+    sess = GraphSession(SessionConfig(clugp=CLUGPConfig.optimized(16),
+                                      exchange="halo"))
+    sess.partition(g.src, g.dst, g.num_vertices).layout()
+    mesh = make_graph_mesh(16)
+    first = sess.run("pagerank", iters=50, tol=1e-6, mesh=mesh)
+    traces = mesh_traces()
+    t0 = time.perf_counter()
+    again = sess.run("pagerank", iters=50, tol=1e-6, mesh=mesh)
+    recs = obs.spans(t0)
+    np.testing.assert_array_equal(first, again)
+    assert mesh_traces() == traces
+    assert not [r for r in recs if r[0].startswith("compile")], recs
+    attrs = next(r[4] for r in recs if r[0] == "gas.run")
+    lay = sess.partition_layout
+    assert attrs["traced"] == 0
+    assert attrs["devices"] == 8 and attrs["parts_per_device"] == 2
+    assert attrs["ici_bytes"] == 2 * 2 * lay.h_max * (16 - 2) * 4
+    assert attrs["ici_bytes"] == lay.comm_bytes("halo", parts_per_device=2)
+
+    for exchange, program in (("quantized", "pagerank"),
+                              ("ragged", "cc"),
+                              ("ragged_quantized", "pagerank")):
+        try:
+            sess.run(program, iters=3, exchange=exchange, mesh=mesh)
+        except ValueError as e:
+            assert "routes one partition per device" in str(e), e
+        else:
+            raise AssertionError(exchange + " ran at 2 partitions a device")
+    try:
+        sess.run_many(["pagerank", "centrality"], iters=3, mesh=mesh)
+    except ValueError as e:
+        assert "one partition per device" in str(e), e
+    else:
+        raise AssertionError("the fused driver ran at 2 a device")
+    # exact payloads of the quantized wire ride the halo wire at any m
+    np.testing.assert_array_equal(
+        sess.run("cc", iters=40, exchange="quantized", mesh=mesh),
+        sess.run("cc", iters=40, exchange="halo", mesh=mesh))
+    print("ok")
     """)
 
 

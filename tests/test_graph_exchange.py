@@ -30,6 +30,22 @@ def test_vectorized_layout_matches_reference(seed, k):
             assert a == b, (f.name, a, b)
 
 
+@pytest.mark.parametrize("k", [4, 32])
+def test_vectorized_layout_matches_reference_on_sorted_keys(k):
+    """Past k·V = 2^25 the builder finds (partition, vertex) keys by
+    sorting, not in a dense table: the same tables either way."""
+    src, dst, n, assign = _random_graph_and_assign(5, k)
+    big = (1 << 25) // k + 1                 # untouched ids above n
+    vec = build_layout(src, dst, assign, big, k)
+    ref = build_layout_reference(src, dst, assign, big, k)
+    for f in dataclasses.fields(vec):
+        a, b = getattr(vec, f.name), getattr(ref, f.name)
+        if isinstance(a, np.ndarray):
+            np.testing.assert_array_equal(a, b, err_msg=f.name)
+        else:
+            assert a == b, (f.name, a, b)
+
+
 def test_vectorized_layout_matches_reference_on_partition():
     g = web_graph(scale=9, edge_factor=6, seed=1)
     k = 8
@@ -115,6 +131,38 @@ def test_comm_model_halo_between_ideal_and_dense():
     # dense k²·L_max slab on any real partition
     assert lay.comm_bytes("ideal") <= lay.comm_bytes("halo")
     assert lay.comm_bytes("halo") < lay.comm_bytes("dense")
+
+
+@pytest.mark.parametrize("exchange,m,expect", [
+    ("halo", 1, lambda lay: lay.comm_bytes("halo") // 8),
+    ("halo", 2, lambda lay: 2 * 2 * (8 - 2) * lay.h_max * 4),
+    ("quantized", 4, lambda lay: 2 * 4 * (8 - 4) * lay.h_max * 4),
+    ("dense", 2, lambda lay: 2 * (8 - 2) * lay.l_max * 4),
+    ("dense", 8, lambda lay: 0),
+    ("ragged", 1, lambda lay: lay.comm_bytes("ragged") // 8),
+    ("ragged", 2, None),
+    ("ragged_quantized", 1,
+     lambda lay: lay.comm_bytes("ragged_quantized") // 8),
+    ("halo", 3, None),
+])
+def test_comm_model_per_chip(exchange, m, expect):
+    """``comm_bytes(parts_per_device=m)``: what one chip sends to the
+    others.  The halo and dense wires hold any m dividing k and keep the
+    lanes between a chip's own partitions off the wire; the other wires
+    route one partition a chip (1/k of the whole wire) and refuse more.
+    The quantized wire's exact payloads (``lossy=False``) ride the halo
+    wire at any m."""
+    src, dst, n, assign = _random_graph_and_assign(2, 8)
+    lay = build_layout(src, dst, assign, n, 8)
+    lossy = exchange != "quantized"
+    if expect is None:
+        with pytest.raises(ValueError):
+            lay.comm_bytes(exchange, parts_per_device=m)
+        return
+    assert lay.comm_bytes(exchange, parts_per_device=m,
+                          lossy=lossy) == expect(lay)
+    assert lay.comm_bytes(exchange, parts_per_device=m, lossy=lossy,
+                          programs=3) == 3 * expect(lay)
 
 
 # ------------------------------------------------- halo vs dense equivalence
