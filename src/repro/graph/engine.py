@@ -1,8 +1,11 @@
 """Distributed vertex-cut GAS engine (PowerGraph semantics) on shard_map.
 
-Per iteration (paper §II-B): local scatter/gather over the partition's edges
-(segment_sum — the ``csr_spmv`` Pallas kernel's op), mirror partials reduced
-to masters, masters apply, new values broadcast back to mirrors.  The two
+Per iteration (paper §II-B): local gather/combine over the partition's
+edges, mirror partials reduced to masters, masters apply, new values
+broadcast back to mirrors.  The local combine runs over each partition's
+edges sorted by destination slot as a segmented scan (``_edge_reduce``):
+the sorted views are built once per run, before the loop, so no iteration
+scatters per edge.  The two
 mirror-sync phases go through the pluggable exchange layer
 (``repro.dist.halo``):
 
@@ -57,7 +60,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .partition import PartitionLayout
 from .. import obs
 from ..dist import collectives as coll
-from ..dist.halo import RAGGED_EXCHANGES, get_exchange, lossy_payload
+from ..dist.halo import (RAGGED_EXCHANGES, _pad_value, get_exchange,
+                         lossy_payload)
 
 DAMPING = 0.85
 # CC labels are int32 vertex ids; the min-identity sentinel marks padded /
@@ -83,7 +87,9 @@ class GASProgram:
     ``combine`` ("sum" | "min") and ``dtype`` fix the mirror-sync wire
     semantics; the quantized exchange uses them to decide whether the
     payload may be lossily delta-coded (fp32 sum) or must ship exact
-    (int32 min)."""
+    (int32 min).  ``edges`` names the sorted edge view ``local`` reads:
+    "in" the directed edges by destination, "both" each edge in both
+    directions (undirected programs)."""
     name: str
     combine: str
     dtype: Any
@@ -91,20 +97,115 @@ class GASProgram:
     local: Callable
     apply: Callable
     aux: Callable | None = None
+    edges: str = "in"
+
+
+# ----------------------------------------------------------- sorted edge views
+#
+# ``local`` combines, per local slot, one value from each edge that targets
+# it.  Each view holds a partition's edge lanes sorted by target slot,
+# built once per run before the loop (``_with_edge_views``), so the
+# combine is a segmented scan over contiguous runs of equal targets and
+# one gather at each slot's last lane: dense vector work, with no
+# read-modify-write per lane.  Pad and masked lanes target slot L_max: they
+# sort last, into a run that no slot reads.
+
+
+def _view_edges(kind: str, dev):
+    """(target, source, mask) lanes of one partition's view ``kind``."""
+    s, d, m = dev["edge_src"], dev["edge_dst"], dev["edge_mask"]
+    if kind == "in":
+        return d, s, m
+    return (jnp.concatenate([d, s]), jnp.concatenate([s, d]),
+            jnp.concatenate([m, m]))
+
+
+def _edge_view(kind: str, dev) -> dict:
+    """One partition's edges of view ``kind`` sorted by target slot:
+    ``tgt``/``src`` per lane, and per local slot the lane that ends its
+    run (``last``) and whether it has one (``has``).
+
+    Two sorts and no gather or scatter: the first orders the edge lanes
+    together with one marker per slot, each marker right after its
+    slot's edges; the second moves the markers behind the edges, in slot
+    order, keeping their positions — slot v's edges end before the
+    position of its marker less v."""
+    l_max = dev["vert_gid"].shape[0]
+    tgt, src, mask = _view_edges(kind, dev)
+    n = tgt.shape[0]
+    slots = jnp.arange(l_max, dtype=jnp.int32)
+    # masked lanes join the pad lanes' run (target L_MAX), after every marker
+    key = jnp.concatenate([2 * jnp.where(mask, tgt, l_max), 2 * slots + 1])
+    key, src = jax.lax.sort(
+        (key, jnp.concatenate([src, jnp.zeros_like(slots)])),
+        num_keys=1, is_stable=True)
+    pos = jnp.arange(n + l_max, dtype=jnp.int32)
+    split, tgt, src = jax.lax.sort(
+        (jnp.where((key & 1) == 1, n + l_max + pos, pos), key >> 1, src),
+        num_keys=1)
+    ends = split[n:] - (n + l_max) - slots       # lanes with target <= v
+    starts = jnp.concatenate([jnp.zeros((1,), ends.dtype), ends[:-1]])
+    return {"tgt": tgt[:n], "src": src[:n],
+            "last": jnp.maximum(ends - 1, 0), "has": ends > starts}
+
+
+def _with_edge_views(programs, dev, batched: bool = True) -> dict:
+    """``dev`` plus the sorted views (``view_<kind>``) that the programs'
+    local phases read; ``batched`` vmaps the build over the leading
+    partition axis."""
+    kinds = sorted({p.edges for p in programs})
+
+    def build(d):
+        return {"view_" + kind: _edge_view(kind, d) for kind in kinds}
+
+    return {**dev, **(jax.vmap(build)(dev) if batched else build(dev))}
+
+
+def _sorted_lanes(programs, layout: PartitionLayout, parts: int) -> int:
+    """Edge lanes that the local phases of one iteration reduce by the
+    segmented scan on one device, summed over its ``parts`` partitions:
+    the ``sorted_lanes`` attribute of ``gas.run``."""
+    e_max = layout.edge_src.shape[1]
+    return parts * sum(e_max * (2 if p.edges == "both" else 1)
+                       for p in programs)
+
+
+def _segmented_scan(vals, keys, op):
+    """Inclusive scan of ``op`` over runs of equal ``keys`` (sorted):
+    log2(n) doubling steps, each a shifted, masked combine."""
+    n = vals.shape[0]
+    step = 1
+    while step < n:
+        prev_v = jnp.concatenate([vals[:step], vals[:-step]])
+        prev_k = jnp.concatenate([jnp.full((step,), -1, keys.dtype),
+                                  keys[:-step]])
+        vals = jnp.where(prev_k == keys, op(vals, prev_v), vals)
+        step *= 2
+    return vals
+
+
+def _edge_reduce(per_edge, view, combine: str):
+    """``combine`` of ``per_edge`` (lanes in the order of ``view``) per
+    local slot: (L_max,), the combine identity where no edge targets a
+    slot — what ``segment_sum``/``segment_min`` over the unsorted edges
+    gives, up to the order of a float sum.  Pad lanes may hold anything:
+    no slot reads their run."""
+    op = jnp.add if combine == "sum" else jnp.minimum
+    vals = _segmented_scan(per_edge, view["tgt"], op)
+    return jnp.where(view["has"], vals[view["last"]],
+                     _pad_value(combine, per_edge.dtype))
 
 
 # ----------------------------------------------------------- per-device math
 
 def _local_rank_partial(rank, dev):
-    """Scatter phase: Σ_{(u,w)∈E_p, w=v} rank[u]/outdeg[u] per local slot."""
-    l_max = dev["vert_gid"].shape[0]
+    """Σ_{(u,w)∈E_p, w=v} rank[u]/outdeg[u] per local slot."""
     safe_deg = jnp.maximum(dev["out_deg"], 1)
     contrib = jnp.where(dev["vert_mask"] & (dev["out_deg"] > 0),
                         rank / safe_deg, 0.0)
     contrib = jnp.concatenate([contrib, jnp.zeros((1,), contrib.dtype)])
-    per_edge = jnp.where(dev["edge_mask"], contrib[dev["edge_src"]], 0.0)
-    return jax.ops.segment_sum(per_edge, dev["edge_dst"],
-                               num_segments=l_max + 1)[:l_max]
+    view = dev["view_in"]
+    return _edge_reduce(contrib[view["src"]], view, "sum")
 
 
 def _local_dangle(rank, dev):
@@ -141,17 +242,14 @@ def _cc_init(dev):
 
 
 def _cc_local_min(label, dev):
-    """Edge-wise min exchange in both directions (undirected semantics)."""
-    l_max = dev["vert_gid"].shape[0]
+    """Edge-wise min exchange in both directions (undirected semantics),
+    one segmented min over the undirected view."""
     lab = jnp.concatenate([jnp.where(dev["vert_mask"], label, CC_SENTINEL),
                            jnp.full((1,), CC_SENTINEL, label.dtype)])
-    s, d, m = dev["edge_src"], dev["edge_dst"], dev["edge_mask"]
-    vs = jnp.where(m, lab[s], CC_SENTINEL)
-    vd = jnp.where(m, lab[d], CC_SENTINEL)
-    out = jax.ops.segment_min(vs, d, num_segments=l_max + 1)[:l_max]
-    out2 = jax.ops.segment_min(vd, s, num_segments=l_max + 1)[:l_max]
+    view = dev["view_both"]
+    out = _edge_reduce(lab[view["src"]], view, "min")
     cur = jnp.where(dev["vert_mask"], label, CC_SENTINEL)
-    return jnp.minimum(cur, jnp.minimum(out, out2))
+    return jnp.minimum(cur, out)
 
 
 def _cc_apply(total, aux, dev):
@@ -162,7 +260,8 @@ def _cc_apply(total, aux, dev):
 # label propagation / connected components: int32 labels are exact on the
 # wire, so every exchange (incl. "quantized") ships them unquantized
 CC_PROGRAM = GASProgram(name="cc", combine="min", dtype=jnp.int32,
-                        init=_cc_init, local=_cc_local_min, apply=_cc_apply)
+                        init=_cc_init, local=_cc_local_min, apply=_cc_apply,
+                        edges="both")
 
 
 # ------------------------------------------------------- program library
@@ -197,25 +296,23 @@ def _sssp_weight(gu, gv):
     return 1 + (3 * gu + 7 * gv) % 11
 
 
-def _edge_gids(dev):
+def _edge_gids(dev, view):
     gid_ext = jnp.concatenate([dev["vert_gid"],
                                jnp.full((1,), -1, jnp.int32)])
-    return gid_ext[dev["edge_src"]], gid_ext[dev["edge_dst"]]
+    return gid_ext[view["src"]], gid_ext[view["tgt"]]
 
 
 def _relax_local(dist, dev, weight_fn):
     """One Bellman-Ford relaxation over the local directed edges:
     min over incoming (u → v) of dist[u] + w(u, v), min'd with current."""
-    l_max = dev["vert_gid"].shape[0]
+    view = dev["view_in"]
     d_ext = _masked_ext(dist, dev["vert_mask"], CC_SENTINEL)
-    du = d_ext[dev["edge_src"]]
-    gu, gv = _edge_gids(dev)
-    w = weight_fn(gu, gv)
+    du = d_ext[view["src"]]
+    w = weight_fn(*_edge_gids(dev, view))
     # clamping before the add keeps sentinel+w from wrapping int32
-    cand = jnp.where(dev["edge_mask"] & (du < CC_SENTINEL),
+    cand = jnp.where(du < CC_SENTINEL,
                      jnp.minimum(du, CC_SENTINEL - 64) + w, CC_SENTINEL)
-    relaxed = jax.ops.segment_min(cand, dev["edge_dst"],
-                                  num_segments=l_max + 1)[:l_max]
+    relaxed = _edge_reduce(cand, view, "min")
     cur = jnp.where(dev["vert_mask"], dist, CC_SENTINEL)
     return jnp.minimum(cur, relaxed)
 
@@ -266,12 +363,9 @@ def labelprop_program(num_vertices: int,
                          CC_SENTINEL)
 
     def local(label, dev):
-        l_max = dev["vert_gid"].shape[0]
         lab_ext = _masked_ext(label, dev["vert_mask"], CC_SENTINEL)
-        prop = jnp.where(dev["edge_mask"], lab_ext[dev["edge_src"]],
-                         CC_SENTINEL)
-        out = jax.ops.segment_min(prop, dev["edge_dst"],
-                                  num_segments=l_max + 1)[:l_max]
+        view = dev["view_in"]
+        out = _edge_reduce(lab_ext[view["src"]], view, "min")
         cur = jnp.where(dev["vert_mask"], label, CC_SENTINEL)
         return jnp.minimum(cur, out)
 
@@ -287,15 +381,11 @@ def labelprop_program(num_vertices: int,
 
 
 def _degree_local(value, dev):
-    """Per-slot incident-edge count (out at src + in at dst); ignores the
-    carried value, so any iteration count ≥ 1 yields the same answer."""
-    l_max = dev["vert_gid"].shape[0]
-    ones = dev["edge_mask"].astype(jnp.int32)
-    out = jax.ops.segment_sum(ones, dev["edge_src"],
-                              num_segments=l_max + 1)[:l_max]
-    inc = jax.ops.segment_sum(ones, dev["edge_dst"],
-                              num_segments=l_max + 1)[:l_max]
-    return out + inc
+    """Per-slot incident-edge count (out at src + in at dst: the lanes of
+    the undirected view); ignores the carried value, so any iteration
+    count ≥ 1 yields the same answer."""
+    view = dev["view_both"]
+    return _edge_reduce(jnp.ones(view["tgt"].shape, jnp.int32), view, "sum")
 
 
 # total degree: the (sum, i32) wire cell — an integer sum combine ships
@@ -305,17 +395,16 @@ DEGREE_PROGRAM = GASProgram(
     init=lambda dev: jnp.zeros(dev["vert_gid"].shape, jnp.int32),
     local=_degree_local,
     apply=lambda total, aux, dev: jnp.where(
-        dev["vert_mask"] & dev["is_master"], total, 0))
+        dev["vert_mask"] & dev["is_master"], total, 0),
+    edges="both")
 
 
 def _cent_local(value, dev):
     """In-neighbor sum without degree normalization (A^T x)."""
-    l_max = dev["vert_gid"].shape[0]
     contrib = _masked_ext(value, dev["vert_mask"],
                           jnp.zeros((), value.dtype))
-    per_edge = jnp.where(dev["edge_mask"], contrib[dev["edge_src"]], 0.0)
-    return jax.ops.segment_sum(per_edge, dev["edge_dst"],
-                               num_segments=l_max + 1)[:l_max]
+    view = dev["view_in"]
+    return _edge_reduce(contrib[view["src"]], view, "sum")
 
 
 def _cent_aux(value, dev):
@@ -439,13 +528,15 @@ def _gas_body(program: GASProgram, ex, dev, axis: str | None = None,
     stacked = axis is None
     reduce = ex.reduce_stacked if stacked else ex.reduce_to_masters
     broadcast = ex.broadcast_stacked if stacked else ex.broadcast_from_masters
+    # built here, outside the loop: the loop body only reads the views
+    local_dev = _with_edge_views((program,), dev)
 
     def step(carry):
         value, state = carry
         aux = (coll.psum(jnp.sum(jax.vmap(program.aux)(value, dev)), axis)
                if program.aux is not None else None)
         apply = jax.vmap(lambda t, d: program.apply(t, aux, d))
-        partial_ = jax.vmap(program.local)(value, dev)
+        partial_ = jax.vmap(program.local)(value, local_dev)
         if overlap:
             total, state = reduce(partial_, dev, program.combine, state,
                                   hopwise=True)
@@ -590,7 +681,8 @@ def simulate_gas(program: GASProgram, layout: PartitionLayout,
             ex = get_exchange(exchange, layout)
             warm = (None if init_values is None
                     else _warm_tables(layout, program.dtype, init_values))
-        with obs.span("gas.run"):
+        with obs.span("gas.run", sorted_lanes=_sorted_lanes(
+                (program,), layout, layout.k)):
             out = _sim_gas(program, dev, iters, ex, tol, overlap, warm)
             values, iters_run = (out, iters) if tol is None else out
             vals, iters_run = np.asarray(values), int(iters_run)
@@ -687,17 +779,21 @@ def _mesh_run(program, layout: PartitionLayout, exchange: str, dev, warm,
     host.  The span records ``devices``, ``parts_per_device``,
     ``ici_bytes`` (what one chip sends over the interconnect per
     iteration, both phases, as padded on the wire: the layout's
-    ``comm_bytes(parts_per_device=…)``) and ``traced`` (traces of the
-    loop in this call)."""
+    ``comm_bytes(parts_per_device=…)``), ``sorted_lanes`` (as in
+    ``_sorted_lanes``) and ``traced`` (traces of the loop in this
+    call)."""
     parts = _parts_per_device(layout, mesh, axis)
     fused = isinstance(program, FusedGAS)
+    programs = program.programs if fused else (program,)
     ici = layout.comm_bytes(
-        exchange, programs=len(program.programs) if fused else 1,
+        exchange, programs=len(programs),
         fused=fused, lossy=lossy_payload(program.combine, program.dtype),
         value_bytes=jnp.dtype(program.dtype).itemsize,
         parts_per_device=parts)
     with obs.span("gas.run", devices=mesh.shape[axis],
-                  parts_per_device=parts, ici_bytes=ici) as gas_run:
+                  parts_per_device=parts, ici_bytes=ici,
+                  sorted_lanes=_sorted_lanes(programs, layout, parts)
+                  ) as gas_run:
         traced = mesh_traces()
         out = _mesh_gas(program, dev, iters, ex, mesh, axis, tol, overlap,
                         warm)
@@ -826,6 +922,7 @@ def _gas_body_multi(fused: FusedGAS, ex, dev, axis: str | None = None,
     stacked = axis is None
     programs = fused.programs
     n = len(programs)
+    local_dev = _with_edge_views(programs, dev, batched=stacked)
 
     def global_aux(value):
         idx = [i for i, p in enumerate(programs) if p.aux is not None]
@@ -848,7 +945,7 @@ def _gas_body_multi(fused: FusedGAS, ex, dev, axis: str | None = None,
         auxes = global_aux(value)
         if stacked:
             partials = jnp.stack(
-                [jax.vmap(programs[i].local)(value[:, i], dev)
+                [jax.vmap(programs[i].local)(value[:, i], local_dev)
                  for i in range(n)], axis=1)
 
             def apply_all(tot):
@@ -870,7 +967,7 @@ def _gas_body_multi(fused: FusedGAS, ex, dev, axis: str | None = None,
             value, state = ex.broadcast_stacked_multi(new_master, dev,
                                                       fused.combine, state)
         else:
-            partials = jnp.stack([programs[i].local(value[i], dev)
+            partials = jnp.stack([programs[i].local(value[i], local_dev)
                                   for i in range(n)])
 
             def apply_all(tot):
@@ -943,7 +1040,8 @@ def simulate_gas_many(programs, layout: PartitionLayout, iters: int = 30,
             ex = get_exchange(exchange, layout)
             warm = (None if init_values is None
                     else _warm_tables_many(layout, fused, init_values))
-        with obs.span("gas.run"):
+        with obs.span("gas.run", sorted_lanes=_sorted_lanes(
+                fused.programs, layout, layout.k)):
             out = _sim_gas_many(fused, dev, iters, ex, tol, overlap, warm)
             values, iters_run = (out, iters) if tol is None else out
             vals, iters_run = np.asarray(values), int(iters_run)
