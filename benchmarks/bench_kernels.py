@@ -1,5 +1,5 @@
-"""Kernel micro-benchmarks (interpret-mode correctness + host-side μs;
-TPU wall-time comes from the roofline terms, not this container)."""
+"""Partitioner kernel micro-benchmarks (interpret-mode correctness +
+host-CPU μs; device times come from the chip benchmark in bench/)."""
 from __future__ import annotations
 
 import time
@@ -23,17 +23,6 @@ def _time(fn, *args, reps=3):
 
 def kernels_microbench():
     rows = []
-    k1, k2, k3 = jax.random.split(jax.random.key(0), 3)
-    q = jax.random.normal(k1, (1, 4, 256, 64), jnp.float32)
-    k = jax.random.normal(k2, (1, 2, 256, 64), jnp.float32)
-    v = jax.random.normal(k3, (1, 2, 256, 64), jnp.float32)
-    t_kern = _time(lambda a, b, c: ops.flash_attention(a, b, c,
-                                                       interpret=True),
-                   q, k, v)
-    t_ref = _time(lambda a, b, c: ref.flash_attention_ref(a, b, c), q, k, v)
-    rows.append({"bench": "kernel_flash_attn", "us_kernel_interp":
-                 round(1e6 * t_kern, 1), "us_ref": round(1e6 * t_ref, 1)})
-
     rng = np.random.default_rng(0)
     aff = jnp.asarray(rng.random((1024, 256)), jnp.float32)
     sizes = jnp.asarray(rng.integers(1, 50, 1024), jnp.float32)
@@ -46,15 +35,6 @@ def kernels_microbench():
     t_ref = _time(lambda *a: ref.game_bestresponse_ref(*a, lam=2.0),
                   aff, sizes, rt, cur, loads)
     rows.append({"bench": "kernel_game_br", "us_kernel_interp":
-                 round(1e6 * t_kern, 1), "us_ref": round(1e6 * t_ref, 1)})
-
-    vals = jnp.asarray(rng.random((2048, 16)), jnp.float32)
-    cols = jnp.asarray(rng.integers(0, 4096, (2048, 16)), jnp.int32)
-    x = jnp.asarray(rng.random(4096), jnp.float32)
-    t_kern = _time(lambda *a: ops.ell_spmv(*a, interpret=True),
-                   vals, cols, x)
-    t_ref = _time(lambda *a: ref.ell_spmv_ref(*a), vals, cols, x)
-    rows.append({"bench": "kernel_ell_spmv", "us_kernel_interp":
                  round(1e6 * t_kern, 1), "us_ref": round(1e6 * t_ref, 1)})
 
     # cluster-scatter: the clustering inner loop on the Pallas fused

@@ -34,7 +34,6 @@ def main() -> None:
     from .bench_pagerank import (fig8_pagerank, layout_build_bench,
                                  program_matrix_bench)
     from .bench_kernels import kernels_microbench
-    from .bench_expert_placement import expert_placement_bench
 
     if args.tiny:
         suites = {
@@ -52,8 +51,6 @@ def main() -> None:
             "program_matrix": lambda: program_matrix_bench(
                 scale=8, k=4, iters=10),
             "layout_build": lambda: layout_build_bench(scale=8, k=4),
-            "expert_placement": lambda: expert_placement_bench(
-                E=16, K=2, shards=4),
         }
         run_suites(suites, args)
         return
@@ -74,7 +71,6 @@ def main() -> None:
             scale=scale, ks=(16, 64), nodes=4),
         "fig11_weight": lambda: bp.fig11_weight_and_balance(scale=scale),
         "kernels": kernels_microbench,
-        "expert_placement": expert_placement_bench,
     }
     run_suites(suites, args)
 
@@ -105,17 +101,6 @@ def run_suites(suites: dict, args) -> None:
     if args.tag:
         (RESULTS / f"BENCH_{args.tag}.json").write_text(
             json.dumps(all_rows, indent=1))
-
-    # roofline summary appended if dry-run records exist
-    try:
-        from .roofline import report
-        for sub, label in (("dryrun", "baseline"),
-                           ("dryrun_opt", "optimized")):
-            txt = report(subdir=sub)
-            print(f"\n# ---- roofline {label} (single-pod, per-device) ----")
-            print(txt)
-    except Exception as e:  # noqa: BLE001
-        print(f"# roofline unavailable: {e}")
 
 
 if __name__ == "__main__":

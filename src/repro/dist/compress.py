@@ -1,40 +1,16 @@
-"""Error-feedback gradient compression (int8) for cross-partition reduces.
+"""Row quantizer for the lossy exchange wires.
 
-The partitioning logic of the paper cuts mirror traffic by lowering the
-replication factor; this module cuts the *per-mirror payload*: gradients
-quantize to int8 (max-abs per-tensor scale) before the reduce, and the
-quantization error is carried in a residual that is re-added next step
-(error feedback), so the time-averaged update is unbiased.
-
-API:
-  zero_residual(tree)                    — initial residual state
-  compress_with_error_feedback(g, res)   — (compressed, new_residual)
-  compressed_psum(x, axis)               — int8 quantize → psum → dequant
-  make_grad_compressor()                 — stateless grads→grads callable
-                                           for make_train_step
-  quantize_rows(x) / dequantize_rows(c, s)
-                                         — int8 codes + max-abs scale per
-                                           trailing row; the lane-group
-                                           quantizer the halo exchange's
-                                           quantized wire format reuses
+``quantize_rows(x)`` / ``dequantize_rows(codes, scales)``: int8 codes
+plus one max-abs scale per trailing row — the lane-group quantizer the
+halo exchange's quantized wire formats (``repro.dist.halo``) use to cut
+the per-mirror payload, the bytes the partitioner's replication factor
+does not already remove.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 _QMAX = 127.0
-
-
-def _scale_of(x: jnp.ndarray) -> jnp.ndarray:
-    amax = jnp.max(jnp.abs(x))
-    return jnp.where(amax > 0, amax / _QMAX, 1.0)
-
-
-def _quantize_dequantize(x: jnp.ndarray) -> jnp.ndarray:
-    scale = _scale_of(x)
-    q = jnp.clip(jnp.round(x / scale), -_QMAX, _QMAX)
-    return q * scale
 
 
 def quantize_rows(x: jnp.ndarray, qmax: float = _QMAX):
@@ -56,58 +32,3 @@ def dequantize_rows(codes: jnp.ndarray, scales: jnp.ndarray) -> jnp.ndarray:
     (..., n) f32.  Exact for the codes produced by ``quantize_rows`` (the
     round-trip error lives in the encoder's residual, not here)."""
     return codes.astype(jnp.float32) * scales[..., None]
-
-
-def zero_residual(grads):
-    """Residual pytree of zeros matching ``grads``."""
-    return jax.tree_util.tree_map(
-        lambda g: jnp.zeros(g.shape, jnp.float32), grads)
-
-
-def compress_with_error_feedback(grads, residual):
-    """Returns (compressed_grads, new_residual).
-
-    Per leaf: t = g + residual; compressed = Q(t); new_residual = t − Q(t)
-    — so compressed + new_residual == g + residual exactly, and over T
-    steps the mean compressed gradient converges to the true gradient.
-    """
-    totals = jax.tree_util.tree_map(
-        lambda g, r: g.astype(jnp.float32) + r, grads, residual)
-    compressed = jax.tree_util.tree_map(_quantize_dequantize, totals)
-    new_residual = jax.tree_util.tree_map(
-        lambda t, c: t - c, totals, compressed)
-    return compressed, new_residual
-
-
-def make_grad_compressor():
-    """Stateless per-leaf int8 quantize-dequantize, in the grads→grads
-    shape ``make_train_step(compress_grads=…)`` accepts.  Unlike
-    ``compress_with_error_feedback`` this carries no residual across steps
-    — it is the launcher-facing hook (``--compress-grads``) for runs whose
-    step signature can't thread extra state.
-
-    Note on wire bytes: in the jit/GSPMD path the gradient all-reduces
-    happen inside the backward pass, *before* this hook runs, so it bounds
-    update precision without shrinking collectives (``launch.dryrun
-    --compress-grads`` measures exactly that: delta ≈ 0).  Cutting the
-    gradient wire itself needs ``compressed_psum`` inside a shard_map'd
-    step — the open follow-up in ROADMAP.md."""
-    def compress(grads):
-        return jax.tree_util.tree_map(_quantize_dequantize, grads)
-    return compress
-
-
-def compressed_psum(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
-    """int8-quantized psum inside shard_map: agree on a global scale
-    (pmax of |x|), quantize locally to int8 codes, sum as int16 (the
-    accumulator stays overflow-safe up to 256 participants: 256·127 <
-    2¹⁵), dequantize.  The wire payload is the int16 code tensor + one
-    scalar — a 2× byte reduction on the cross-partition reduce vs fp32
-    (ring all-reduce sends partial sums, so the accumulator width is the
-    wire width; a gather-based int8 layout would reach 4×)."""
-    xf = x.astype(jnp.float32)
-    amax = jax.lax.pmax(jnp.max(jnp.abs(xf)), axis_name)
-    scale = jnp.where(amax > 0, amax / _QMAX, 1.0)
-    q = jnp.clip(jnp.round(xf / scale), -_QMAX, _QMAX)
-    total = jax.lax.psum(q.astype(jnp.int16), axis_name)
-    return (total.astype(jnp.float32) * scale).astype(x.dtype)

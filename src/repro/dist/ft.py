@@ -1,53 +1,21 @@
-"""Fault tolerance: checkpoint-restart + straggler watch.
+"""Fault tolerance for the graph service: atomic checkpoints and a
+straggler watch.
 
-``run`` wraps any ``step_fn(params, opt_state, batch, i)`` in a loop that
-- restores the latest intact checkpoint on entry (elastic restart),
-- checkpoints every ``ckpt_every`` steps (optionally on a background
-  thread) plus once at completion,
-- times every step and flags stragglers (step > factor × running median),
-- can inject a failure at a given step for restart testing.
-
-``ServiceFT`` is the same machinery for a long-lived process instead of a
-bounded loop: the graph service (``repro.serve``) snapshots its resident
-edges/assignment through the atomic ``ckpt`` writes and restores them
-shape-blind after a kill, and times its microbatches through the same
-``StragglerWatch`` the trainer uses.
+``ServiceFT`` lets a long-lived process survive preemption: the graph
+service (``repro.serve``) snapshots its resident edges/assignment
+through the atomic ``ckpt`` writes and restores them shape-blind after a
+kill, and times its microbatches through ``StragglerWatch``.
 """
 from __future__ import annotations
 
-import dataclasses
 import statistics
 import threading
-import time
 from collections import deque
-from typing import Callable
 
 import jax
 import jax.numpy as jnp
 
 from .. import ckpt
-
-
-@dataclasses.dataclass
-class FTConfig:
-    ckpt_dir: str
-    ckpt_every: int = 50
-    resume: str = "auto"               # "auto" restores latest; "none" skips
-    async_checkpoint: bool = False     # save on a background thread
-    fail_at_step: int | None = None    # inject RuntimeError (tests)
-    straggler_factor: float = 0.0      # 0 disables detection
-    straggler_warmup: int = 2          # steps of timing history required
-
-
-@dataclasses.dataclass
-class FTState:
-    step: int = 0          # next step to execute (== total when done)
-    stragglers: int = 0
-    restarts: int = 0
-
-
-def _tree(params, opt_state):
-    return {"params": params, "opt": opt_state}
 
 
 class StragglerWatch:
@@ -109,74 +77,16 @@ class _Saver:
             raise RuntimeError("async checkpoint save failed") from err
 
 
-def run(step_fn: Callable, params, opt_state, data_fn: Callable,
-        total_steps: int, cfg: FTConfig, *, log_every: int = 10,
-        log_fn: Callable = print, on_straggler: Callable | None = None):
-    """Drive ``total_steps`` of training with checkpoint-restart.
-
-    Returns (params, opt_state, losses, state); ``losses`` covers only the
-    steps executed in *this* invocation (a restart resumes mid-stream).
-    """
-    state = FTState()
-    start = 0
-    if cfg.resume == "auto":
-        try:
-            restored, step = ckpt.restore_latest(
-                cfg.ckpt_dir, _tree(params, opt_state))
-        except (AssertionError, KeyError) as e:
-            raise RuntimeError(
-                f"checkpoint in {cfg.ckpt_dir!r} does not match the current "
-                f"model (different arch/config?) — pass resume='none' or a "
-                f"fresh ckpt_dir to start over: {e}") from e
-        if step >= 0:
-            params, opt_state = restored["params"], restored["opt"]
-            start = step + 1
-            state.restarts = 1
-            if log_every:
-                log_fn(f"[ft] restored step {step}, resuming at {start}")
-    saver = _Saver(cfg.async_checkpoint)
-    losses: list[float] = []
-    watch = StragglerWatch(cfg.straggler_factor, cfg.straggler_warmup)
-    last_saved = -1
-    for i in range(start, total_steps):
-        if cfg.fail_at_step is not None and i == cfg.fail_at_step:
-            saver.wait()
-            raise RuntimeError(f"injected failure at step {i}")
-        batch = data_fn(i)
-        t0 = time.perf_counter()
-        params, opt_state, loss = step_fn(params, opt_state, batch,
-                                          jnp.int32(i))
-        loss = jax.block_until_ready(loss)
-        dt = time.perf_counter() - t0
-        if watch.observe(dt):
-            state.stragglers += 1
-            if on_straggler is not None:
-                on_straggler(i, dt, watch.last_median)
-        losses.append(float(loss))
-        state.step = i + 1
-        if log_every and i % log_every == 0:
-            log_fn(f"[ft] step {i} loss {float(loss):.4f} {dt*1e3:.1f}ms")
-        if cfg.ckpt_every and i > 0 and i % cfg.ckpt_every == 0:
-            saver.save(cfg.ckpt_dir, i, _tree(params, opt_state))
-            last_saved = i
-    if total_steps > start and last_saved != total_steps - 1:
-        saver.save(cfg.ckpt_dir, total_steps - 1,
-                   _tree(params, opt_state))
-    saver.wait()
-    state.step = max(state.step, start)
-    return params, opt_state, losses, state
-
-
 class ServiceFT:
     """Preemption survival for a long-lived service (``repro.serve``).
 
-    The trainer's loop owns its arrays and their shapes; a graph service
-    does not — live ingest grows the resident edge arrays between
-    snapshots, so the template-checked ``ckpt.restore`` would reject its
-    own last checkpoint.  ``ServiceFT`` keeps the atomic-write/torn-read
-    contract but restores SHAPE-BLIND (``ckpt.restore_raw``), carrying a
-    JSON ``extra`` (session config blob, watermarks) alongside the
-    arrays.  It also hosts the microbatch ``StragglerWatch``.
+    A graph service does not own fixed shapes — live ingest grows the
+    resident edge arrays between snapshots, so the template-checked
+    ``ckpt.restore`` would reject its own last checkpoint.  ``ServiceFT``
+    keeps the atomic-write/torn-read contract but restores SHAPE-BLIND
+    (``ckpt.restore_raw``), carrying a JSON ``extra`` (session config
+    blob, watermarks) alongside the arrays.  It also hosts the
+    microbatch ``StragglerWatch``.
     """
 
     def __init__(self, ckpt_dir: str, *, async_checkpoint: bool = False,

@@ -1,48 +1,20 @@
-"""Named-axis sharding constraints (logical tags → mesh axes).
+"""Named-axis sharding rules (logical tags → mesh axes).
 
-Model code never names mesh axes; it tags each array dim with a logical
-name (``shard(x, "batch", "seq", "heads", None)``) and the *rule table*
-active via ``use_rules(rules, mesh)`` decides which mesh axis (if any)
-each tag lands on.  Swapping the table re-partitions the whole model —
-TP-serve vs CP-serve vs multi-pod train are one-line changes in the
-launchers, not edits to model code.
+The partitioner never names mesh axes: it tags each array dim with a
+logical name and ``resolve_spec`` looks the tag up in a rule table to
+find the mesh axis (if any) the dim lands on.
 
-Rule tables:
-- ``SINGLE_POD_RULES``  — DP×TP on a ("data", "model") mesh: batch on
-  data; heads / experts / vocab on model; decode KV caches sequence-
-  sharded on model (SP flash-decode).
-- ``MULTI_POD_RULES``   — same, with batch spread over ("pod", "data").
-- ``CP_SERVE_RULES``    — context-parallel serving: the *sequence* dim
-  shards over model (heads replicated, mp=1) — long-context cells where
-  head-sharding runs out.
+Rule table:
+- ``PARTITIONER_RULES`` — the §III-C stream split: the edge stream on a
+  flat ``stream`` axis, one contiguous slice per device; vertex state
+  replicated.
 
-Outside any ``use_rules`` context ``shard`` is the identity, so single-
-device tests and reference paths run unchanged.  Axes that do not evenly
-divide their dim are dropped (replicated) — mirroring
-``repro.train.shardings.sanitize_specs``: a bad tag can cost performance,
-never a compile failure.
+Axes that do not evenly divide their dim are dropped (replicated): a bad
+tag can cost performance, never a compile failure.
 """
 from __future__ import annotations
 
-import threading
-from contextlib import contextmanager
-
-import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-SINGLE_POD_RULES: dict = {
-    "batch": "data",
-    "seq": None,                      # sequence replicated in TP train
-    "heads": "model",
-    "kv_heads": None,                 # GQA KV replicated (cheap all-gather)
-    "kv_heads_sharded": "model",      # when kv_heads divide the mesh
-    "vocab": "model",
-    "experts": "model",
-    "sp_seq": "model",                # decode caches: sequence-parallel
-    "stage": None,
-}
-
-MULTI_POD_RULES: dict = {**SINGLE_POD_RULES, "batch": ("pod", "data")}
+from jax.sharding import PartitionSpec as P
 
 # the partitioner's edge stream: one contiguous stream slice per device
 # along a flat "stream" axis (repro.core.partitioner, paper §III-C)
@@ -50,45 +22,6 @@ PARTITIONER_RULES: dict = {
     "stream": "stream",
     "vertex": None,                   # vertex state replicated per node
 }
-
-CP_SERVE_RULES: dict = {
-    **SINGLE_POD_RULES,
-    "seq": "model",                   # context parallelism
-    "heads": None,
-    "kv_heads_sharded": None,
-    "sp_seq": "model",
-}
-
-_state = threading.local()
-
-
-def _stack() -> list:
-    if not hasattr(_state, "stack"):
-        _state.stack = []
-    return _state.stack
-
-
-@contextmanager
-def use_rules(rules: dict, mesh: Mesh):
-    """Activate ``rules`` over ``mesh`` for all ``shard()`` calls in scope
-    (re-entrant; innermost context wins).
-
-    The context is read at *trace* time: wrap the first call of a jitted
-    function (as the launchers do), not just later calls — a function
-    already traced outside the context hits the jit cache and keeps its
-    constraint-free compilation.
-    """
-    _stack().append((rules, mesh))
-    try:
-        yield
-    finally:
-        _stack().pop()
-
-
-def active_rules():
-    """(rules, mesh) of the innermost ``use_rules`` context, or None."""
-    stack = _stack()
-    return stack[-1] if stack else None
 
 
 def resolve_spec(shape: tuple, tags: tuple, rules: dict,
@@ -118,15 +51,3 @@ def resolve_spec(shape: tuple, tags: tuple, rules: dict,
         used.update(axes)
         entries.append(axes if len(axes) > 1 else axes[0])
     return P(*entries)
-
-
-def shard(x: jax.Array, *tags) -> jax.Array:
-    """Constrain ``x``'s sharding per the active rule table; identity when
-    no ``use_rules`` context is active.  One tag per dim ("batch", "seq",
-    "heads", "vocab", "experts", "sp_seq", ... or None)."""
-    ctx = active_rules()
-    if ctx is None:
-        return x
-    rules, mesh = ctx
-    spec = resolve_spec(tuple(x.shape), tags, rules, dict(mesh.shape))
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))

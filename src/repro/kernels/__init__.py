@@ -1,5 +1,4 @@
-"""Pallas TPU kernels (Mosaic on TPU, the Pallas interpreter elsewhere) +
-jnp oracles."""
+"""Pallas TPU kernels of the partitioner (Mosaic on TPU, the Pallas
+interpreter elsewhere) + jnp oracles."""
 from . import ops, ref  # noqa: F401
-from .ops import (flash_attention, game_best_response, ell_spmv,  # noqa: F401
-                  cluster_scatter)
+from .ops import game_best_response, cluster_scatter  # noqa: F401

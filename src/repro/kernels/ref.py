@@ -1,32 +1,11 @@
-"""Pure-jnp oracles for every Pallas kernel (the allclose targets)."""
+"""Pure-jnp oracle of the game best-response kernel (the allclose
+target).  The clustering and transform kernels are checked against the
+XLA scans they replace (``core.clustering``, ``core.transform``)."""
 from __future__ import annotations
 
-import math
-
-import jax
 import jax.numpy as jnp
 
-NEG_INF = -1e30
 BIG = 3.0e38
-
-
-def flash_attention_ref(q, k, v, *, causal: bool = True,
-                        sm_scale: float | None = None):
-    """q: (B,Hq,Sq,D); k/v: (B,Hkv,Skv,D)."""
-    B, Hq, Sq, D = q.shape
-    Hkv, Skv = k.shape[1], k.shape[2]
-    scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)
-    group = Hq // Hkv
-    kx = jnp.repeat(k, group, axis=1)
-    vx = jnp.repeat(v, group, axis=1)
-    s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
-                   kx.astype(jnp.float32)) * scale
-    if causal:
-        mask = jnp.arange(Skv)[None, :] <= jnp.arange(Sq)[:, None]
-        s = jnp.where(mask[None, None], s, NEG_INF)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhqk,bhkd->bhqd", p,
-                      vx.astype(jnp.float32)).astype(q.dtype)
 
 
 def game_bestresponse_ref(aff, sizes, row_tot, cur, loads, *, lam: float,
@@ -42,8 +21,3 @@ def game_bestresponse_ref(aff, sizes, row_tot, cur, loads, *, lam: float,
         + 0.5 * (row_tot[:, None].astype(jnp.float32) - aff)
     cost = jnp.where(pids < k, cost, BIG)
     return jnp.argmin(cost, 1).astype(jnp.int32), jnp.min(cost, 1)
-
-
-def ell_spmv_ref(vals, cols, x):
-    return (vals.astype(jnp.float32)
-            * x.astype(jnp.float32)[cols]).sum(axis=1)

@@ -1,18 +1,13 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
-"""Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
-
-For each cell this AOT-compiles the real train/prefill/decode step against
-ShapeDtypeStruct inputs (no allocation), prints memory_analysis() (fits?)
-and cost_analysis() (FLOPs/bytes), parses collective bytes out of the
-post-SPMD HLO, and appends a JSON record consumed by the roofline report
-(benchmarks/roofline.py → EXPERIMENTS.md §Dry-run/§Roofline).
+"""Graph dry-run: compile one GAS step per (program × exchange wire) on
+a k-device mesh of virtual CPU devices, parse the collective bytes out
+of the post-SPMD HLO, and gate their ordering (``--check``).
 
 Usage:
-  PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-7b \
-      --shape train_4k --mesh single            # one cell
-  PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
+  PYTHONPATH=src python -m repro.launch.dryrun --graph \
+      --graph-scale 13 --graph-k 8 --check
 """
 import argparse
 import json
@@ -22,53 +17,19 @@ import traceback
 import warnings
 from pathlib import Path
 
-import jax
-import jax.numpy as jnp
-from jax.sharding import NamedSharding, PartitionSpec as P
-
-from repro.configs import ARCHS, get_config
 from repro.dist.halo import EXCHANGE_NAMES
 from repro.launch.compile_cache import enable_compile_cache
-from repro.launch.mesh import make_production_mesh
-from repro.launch.specs import SHAPES, cell_is_skipped, input_specs
-from repro.dist.sharding import (CP_SERVE_RULES, MULTI_POD_RULES,
-                                 SINGLE_POD_RULES, use_rules)
-from repro.models import abstract_params
-from repro.train import (batch_specs, cache_specs, get_optimizer,
-                         make_decode_fn, make_prefill_step, make_train_step,
-                         param_specs)
-from repro.train.shardings import sanitize_specs
-
-
-def _shardings(specs, sds, mesh):
-    specs = sanitize_specs(specs, sds, mesh)
-    return jax.tree_util.tree_map(
-        lambda s: NamedSharding(mesh, s), specs,
-        is_leaf=lambda x: isinstance(x, P))
 
 RESULTS = Path(__file__).resolve().parents[3] / "results"
 
-# v5e hardware constants (assignment §ROOFLINE)
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # B/s / chip
-ICI_BW = 50e9                # B/s / link
-
-# The HLO collective parsers moved to repro.analysis.ir (PR 10) — the
-# names below are deprecation shims so external `dryrun.collective_bytes`
+# The HLO collective parsers live in repro.analysis.ir — the names
+# below are deprecation shims so external `dryrun.collective_bytes`
 # callers keep working; in-file call sites use the ir implementations.
 from repro.analysis.ir import (COLLECTIVE_KINDS, DTYPE_BYTES,  # noqa: F401
                                SHAPE_RE)
 from repro.analysis.ir import collective_bytes as _collective_bytes
 from repro.analysis.ir import \
     collective_permute_count as _collective_permute_count
-
-
-def cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict on new jax, [dict] on 0.4.x."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
 
 
 def collective_bytes(hlo_text: str) -> dict:
@@ -88,201 +49,6 @@ def collective_permute_count(hlo_text: str) -> int:
         "repro.analysis.ir.collective_permute_count", DeprecationWarning,
         stacklevel=2)
     return _collective_permute_count(hlo_text)
-
-
-def zero_default(cfg) -> bool:
-    from repro.models import param_count
-    # ZeRO-shard anything ≥ ~8B params (replicated fp32 wouldn't fit HBM)
-    return param_count(cfg, mp=16) >= 8e9
-
-
-def optimizer_default(cfg) -> str:
-    from repro.models import param_count
-    return "adafactor" if param_count(cfg, mp=16) >= 3e10 else "adamw"
-
-
-def cfg_with_counts(cfg, counts: dict):
-    """A config whose layer_groups() counts equal ``counts`` — the probe
-    models for per-layer cost extrapolation."""
-    import dataclasses
-    if cfg.family == "encdec":
-        return dataclasses.replace(cfg, n_encoder_layers=counts["enc"],
-                                   n_layers=counts["dec"])
-    if cfg.family == "hybrid":
-        return dataclasses.replace(cfg,
-                                   n_layers=counts["hyb"] * cfg.attn_period)
-    if cfg.family == "ssm":
-        return dataclasses.replace(cfg, n_layers=counts["ssd"])
-    if cfg.moe is not None and cfg.moe.first_k_dense:
-        moe = dataclasses.replace(cfg.moe, first_k_dense=counts["dense"])
-        return dataclasses.replace(
-            cfg, moe=moe, n_layers=counts["dense"] + counts["moe"])
-    if cfg.moe is not None:
-        return dataclasses.replace(cfg, n_layers=counts["moe"])
-    return dataclasses.replace(cfg, n_layers=counts["dense"])
-
-
-def build_cell(cfg, shape_name: str, mesh, rules, *, mp: int,
-               multi_pod: bool, block_kv: int = 1024, loss_chunk: int = 512,
-               zero: bool | None = None, unroll: bool = False,
-               compress: bool = False):
-    """Returns (jitted_fn, example_args_shapes) for lowering."""
-    kind = SHAPES[shape_name]["kind"]
-    if kind == "train":
-        params_sds = abstract_params(cfg, mp)
-        zero = zero_default(cfg) if zero is None else zero
-    else:
-        # serving: bf16 weights, no optimizer ⇒ drop ZeRO *when the bf16
-        # weights fit replicated over data* (≤8 GB/device after TP) —
-        # removes every per-layer all-gather from the serve path
-        # (hillclimb #3).  ≥100B archs keep data-axis weight sharding.
-        from repro.models import param_count
-        params_sds = abstract_params(cfg, mp, dtype=jnp.bfloat16)
-        if zero is None:
-            zero = (2 * param_count(cfg, mp=mp) / mesh.shape["model"]) \
-                > 8 * 2**30
-    pspecs = param_specs(params_sds, zero=zero, multi_pod=multi_pod)
-    p_shardings = _shardings(pspecs, params_sds, mesh)
-    specs = input_specs(cfg, shape_name, mp=mp)
-
-    if kind == "train":
-        opt = get_optimizer(optimizer_default(cfg))
-        opt_sds = jax.eval_shape(opt.init, params_sds)
-        o_specs = param_specs(opt_sds, zero=zero, multi_pod=multi_pod)
-        o_shardings = _shardings(o_specs, opt_sds, mesh)
-        b_specs = batch_specs(specs["batch"], multi_pod=multi_pod)
-        b_shardings = _shardings(b_specs, specs["batch"], mesh)
-        compress_fn = None
-        if compress:
-            from repro.dist.compress import make_grad_compressor
-            compress_fn = make_grad_compressor()
-        step_fn = make_train_step(cfg, opt, mp=mp, block_kv=block_kv,
-                                  loss_chunk=loss_chunk, unroll=unroll,
-                                  compress_grads=compress_fn)
-        jitted = jax.jit(
-            step_fn,
-            in_shardings=(p_shardings, o_shardings, b_shardings, None),
-            out_shardings=(p_shardings, o_shardings, None))
-        args = (params_sds, opt_sds, specs["batch"],
-                jax.ShapeDtypeStruct((), jnp.int32))
-        return jitted, args
-
-    if kind == "prefill":
-        b_specs = batch_specs(specs["batch"], multi_pod=multi_pod)
-        b_shardings = _shardings(b_specs, specs["batch"], mesh)
-        fn = make_prefill_step(cfg, mp=mp, block_kv=block_kv,
-                               unroll=unroll)
-        jitted = jax.jit(fn, in_shardings=(p_shardings, b_shardings))
-        return jitted, (params_sds, specs["batch"])
-
-    # decode
-    c_specs = cache_specs(specs["cache"], multi_pod=multi_pod)
-    c_shardings = _shardings(c_specs, specs["cache"], mesh)
-    da = ("pod", "data") if multi_pod else "data"
-    tok_sh = _shardings(P(da, None), specs["tokens"], mesh)
-    fn = make_decode_fn(cfg, mp=mp, unroll=unroll)
-    if cfg.family == "encdec":
-        mem_sh = _shardings(P(da, None, None), specs["memory"], mesh)
-        jitted = jax.jit(
-            lambda p, c, t, i, m: fn(p, c, t, i, memory=m),
-            in_shardings=(p_shardings, c_shardings, tok_sh, None, mem_sh),
-            out_shardings=(None, c_shardings))
-        args = (params_sds, specs["cache"], specs["tokens"],
-                specs["index"], specs["memory"])
-    else:
-        jitted = jax.jit(
-            fn,
-            in_shardings=(p_shardings, c_shardings, tok_sh, None),
-            out_shardings=(None, c_shardings))
-        args = (params_sds, specs["cache"], specs["tokens"], specs["index"])
-    return jitted, args
-
-
-def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: Path,
-             block_kv: int = 1024, loss_chunk: int = 512, tag: str = "",
-             mp_override: int | None = None, rules_name: str = "tp",
-             compress: bool = False) -> dict:
-    cfg = get_config(arch)
-    compress = compress and SHAPES[shape_name]["kind"] == "train"
-    rec = {"arch": arch, "shape": shape_name, "mesh": mesh_kind,
-           "tag": tag or "baseline", "compress_grads": compress}
-    skip = cell_is_skipped(cfg, shape_name)
-    if skip:
-        rec["status"] = skip
-        out_dir.mkdir(parents=True, exist_ok=True)
-        (out_dir / f"{arch}__{shape_name}__{mesh_kind}.json").write_text(
-            json.dumps(rec, indent=1))
-        print(f"[{arch} × {shape_name} × {mesh_kind}] {skip}")
-        return rec
-    multi_pod = mesh_kind == "multi"
-    mesh = make_production_mesh(multi_pod=multi_pod)
-    rules = MULTI_POD_RULES if multi_pod else SINGLE_POD_RULES
-    if rules_name == "cp":
-        rules = CP_SERVE_RULES
-    mp = mp_override or (1 if rules_name == "cp" else mesh.shape["model"])
-    t0 = time.time()
-    try:
-        with use_rules(rules, mesh):
-            jitted, args = build_cell(cfg, shape_name, mesh, rules, mp=mp,
-                                      multi_pod=multi_pod,
-                                      block_kv=block_kv,
-                                      loss_chunk=loss_chunk,
-                                      compress=compress)
-            lowered = jitted.lower(*args)
-            t1 = time.time()
-            compiled = lowered.compile()
-            t2 = time.time()
-            if compress:
-                # surface the collective-byte delta vs the uncompressed
-                # step (ROADMAP open item): compile the baseline too
-                base_jit, base_args = build_cell(
-                    cfg, shape_name, mesh, rules, mp=mp,
-                    multi_pod=multi_pod, block_kv=block_kv,
-                    loss_chunk=loss_chunk, compress=False)
-                base_coll = _collective_bytes(
-                    base_jit.lower(*base_args).compile().as_text())
-        mem = compiled.memory_analysis()
-        cost = cost_dict(compiled)
-        hlo = compiled.as_text()
-        coll = _collective_bytes(hlo)
-        if compress:
-            rec["collective_bytes_uncompressed"] = base_coll
-            rec["collective_delta_bytes"] = base_coll["total"] - coll["total"]
-            print(f"  compress-grads delta: {base_coll['total']:.3e}B → "
-                  f"{coll['total']:.3e}B "
-                  f"({rec['collective_delta_bytes']:+.3e}B)")
-        rec.update({
-            "status": "ok",
-            "lower_s": round(t1 - t0, 1),
-            "compile_s": round(t2 - t1, 1),
-            "flops": float(cost.get("flops", 0.0)),
-            "bytes_accessed": float(cost.get("bytes accessed", 0.0)),
-            "collective_bytes": coll,
-            "memory": {
-                "argument_bytes": int(getattr(mem, "argument_size_in_bytes",
-                                              0)),
-                "output_bytes": int(getattr(mem, "output_size_in_bytes", 0)),
-                "temp_bytes": int(getattr(mem, "temp_size_in_bytes", 0)),
-                "peak_bytes": int(
-                    getattr(mem, "peak_memory_in_bytes",
-                            getattr(mem, "temp_size_in_bytes", 0))),
-            },
-            "n_devices": mesh.size,
-        })
-        print(f"[{arch} × {shape_name} × {mesh_kind} × {rec['tag']}] OK  "
-              f"lower {rec['lower_s']}s compile {rec['compile_s']}s  "
-              f"flops={rec['flops']:.3e}  coll={coll['total']:.3e}B")
-        print("  memory_analysis:", rec["memory"])
-    except Exception as e:  # noqa: BLE001 — record the failure, keep going
-        rec["status"] = f"FAIL: {type(e).__name__}: {e}"
-        rec["traceback"] = traceback.format_exc()[-2000:]
-        print(f"[{arch} × {shape_name} × {mesh_kind}] FAIL: {e}",
-              file=sys.stderr)
-    out_dir.mkdir(parents=True, exist_ok=True)
-    fname = out_dir / f"{arch}__{shape_name}__{mesh_kind}" \
-        f"{('__' + tag) if tag else ''}.json"
-    fname.write_text(json.dumps(rec, indent=1))
-    return rec
 
 
 # every engine wire format, straight from the exchange registry —
@@ -610,94 +376,9 @@ def check_graph_ordering(recs: list[dict]) -> list[str]:
     return msgs
 
 
-def _lower_probe(cfg, shape_name, mesh, rules, *, mp, block_kv, loss_chunk):
-    """Compile one probe model (all scans UNROLLED) and return its raw
-    flops/bytes/collective-bytes — trip counts are real in the HLO text."""
-    from repro.dist.sharding import use_rules as _ur
-    with _ur(rules, mesh):
-        jitted, args = build_cell(cfg, shape_name, mesh, rules, mp=mp,
-                                  multi_pod=False, block_kv=block_kv,
-                                  loss_chunk=loss_chunk, unroll=True)
-        lowered = jitted.lower(*args)
-        compiled = lowered.compile()
-    cost = cost_dict(compiled)
-    coll = _collective_bytes(compiled.as_text())
-    return {"flops": float(cost.get("flops", 0.0)),
-            "bytes": float(cost.get("bytes accessed", 0.0)),
-            "coll": float(coll["total"])}
-
-
-def run_probe_cell(arch: str, shape_name: str, out_dir: Path,
-                   block_kv: int = 1024, loss_chunk: int = 512,
-                   tag: str = "", rules_name: str = "tp") -> dict:
-    """Per-layer cost extrapolation on the single-pod mesh:
-    total = outside + Σ_g L_g · layer_g, where layer_g comes from
-    (counts[g]=2) − (counts[g]=1) probe compiles with unrolled scans.
-    (XLA:CPU's cost analysis counts while bodies once — see EXPERIMENTS.md
-    §Method; probes make every trip count explicit.)"""
-    from repro.models.lm import layer_groups
-    cfg = get_config(arch)
-    rec = {"arch": arch, "shape": shape_name, "mesh": "single",
-           "tag": (tag or "baseline") + "-probe"}
-    skip = cell_is_skipped(cfg, shape_name)
-    if skip:
-        rec["status"] = skip
-        return rec
-    mesh = make_production_mesh(multi_pod=False)
-    rules = CP_SERVE_RULES if rules_name == "cp" else SINGLE_POD_RULES
-    mp = 1 if rules_name == "cp" else mesh.shape["model"]
-    groups = layer_groups(cfg)
-    base_counts = {name: 1 for name, _ in groups}
-    t0 = time.time()
-    try:
-        base = _lower_probe(cfg_with_counts(cfg, base_counts), shape_name,
-                            mesh, rules, mp=mp, block_kv=block_kv,
-                            loss_chunk=loss_chunk)
-        per_layer = {}
-        for name, _ in groups:
-            counts = dict(base_counts)
-            counts[name] = 2
-            probe = _lower_probe(cfg_with_counts(cfg, counts), shape_name,
-                                 mesh, rules, mp=mp, block_kv=block_kv,
-                                 loss_chunk=loss_chunk)
-            per_layer[name] = {k: probe[k] - base[k] for k in base}
-        outside = {k: base[k] - sum(per_layer[n][k] for n, _ in groups)
-                   for k in base}
-        totals = {k: outside[k] + sum(cnt * per_layer[n][k]
-                                      for n, cnt in groups)
-                  for k in base}
-        rec.update({
-            "status": "ok",
-            "probe_s": round(time.time() - t0, 1),
-            "base": base, "per_layer": per_layer, "outside": outside,
-            "totals": totals,
-            "groups": {n: c for n, c in groups},
-            "n_devices": mesh.size,
-        })
-        print(f"[probe {arch} × {shape_name} × {rec['tag']}] "
-              f"flops={totals['flops']:.3e} bytes={totals['bytes']:.3e} "
-              f"coll={totals['coll']:.3e} ({rec['probe_s']}s)")
-    except Exception as e:  # noqa: BLE001
-        rec["status"] = f"FAIL: {type(e).__name__}: {e}"
-        rec["traceback"] = traceback.format_exc()[-2000:]
-        print(f"[probe {arch} × {shape_name}] FAIL: {e}", file=sys.stderr)
-    out_dir.mkdir(parents=True, exist_ok=True)
-    fname = out_dir / (f"{arch}__{shape_name}__probe"
-                       f"{('__' + tag) if tag else ''}.json")
-    fname.write_text(json.dumps(rec, indent=1))
-    return rec
-
-
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default=None)
-    ap.add_argument("--shape", default=None, choices=list(SHAPES) + [None])
-    ap.add_argument("--mesh", default="single",
-                    choices=["single", "multi", "both"])
-    ap.add_argument("--all", action="store_true")
-    ap.add_argument("--probe", action="store_true",
-                    help="per-layer cost probes (single-pod only)")
-    ap.add_argument("--graph", action="store_true",
+    ap.add_argument("--graph", action="store_true", required=True,
                     help="GAS-engine cells: compile one step per (program "
                          "× exchange backend) for the full program "
                          "library plus the fused 3-program bundle, report "
@@ -717,62 +398,27 @@ def main():
                          "match their phase-ordered twins in bytes and "
                          "collective-permute count, and the tol cell "
                          "early-exits under its cap bit-identically")
-    ap.add_argument("--compress-grads", action="store_true",
-                    help="train cells: int8 gradient quantization; also "
-                         "compiles the uncompressed step and prints the "
-                         "collective-byte delta (≈0 in the jit path — "
-                         "GSPMD reduces grads before the hook runs; see "
-                         "repro.dist.compress.make_grad_compressor)")
     ap.add_argument("--tag", default="")
-    ap.add_argument("--rules", default="tp", choices=["tp", "cp"])
-    ap.add_argument("--block-kv", type=int, default=1024)
-    ap.add_argument("--loss-chunk", type=int, default=512)
     ap.add_argument("--out", default=str(RESULTS / "dryrun"))
     args = ap.parse_args()
     enable_compile_cache()
 
-    out_dir = Path(args.out)
-    if args.graph:
-        recs = run_graph_cell(out_dir, scale=args.graph_scale,
-                              k=args.graph_k, tag=args.tag)
-        n_fail = sum(str(r.get("status", "")).startswith("FAIL")
-                     for r in recs)
-        if args.check:
-            msgs = check_graph_ordering(recs)
-            for m in msgs:
-                print(f"collective-bytes gate: {m}", file=sys.stderr)
-            if not msgs:
-                print("collective-bytes gate: quantized < halo < dense, "
-                      "ragged ≤ halo and ragged_quantized < quantized "
-                      "hold for every program, the fused bundle "
-                      f"ships < {FUSED_GATE_RATIO}× its separate steps, "
-                      "overlap cells match phase-ordered bytes and "
-                      "collective-permute count, and tol early-exits "
-                      "under the cap bit-identically")
-            sys.exit(1 if msgs else 0)
-        sys.exit(1 if n_fail else 0)
-    archs = ARCHS if (args.all or not args.arch) else [args.arch]
-    shapes = list(SHAPES) if (args.all or not args.shape) else [args.shape]
-    meshes = (["single", "multi"] if args.mesh == "both" else [args.mesh])
-    n_fail = 0
-    for arch in archs:
-        for shape in shapes:
-            if args.probe:
-                rec = run_probe_cell(arch, shape, out_dir,
-                                     block_kv=args.block_kv,
-                                     loss_chunk=args.loss_chunk,
-                                     tag=args.tag, rules_name=args.rules)
-                if str(rec.get("status", "")).startswith("FAIL"):
-                    n_fail += 1
-                continue
-            for mk in meshes:
-                rec = run_cell(arch, shape, mk, out_dir,
-                               block_kv=args.block_kv,
-                               loss_chunk=args.loss_chunk, tag=args.tag,
-                               rules_name=args.rules,
-                               compress=args.compress_grads)
-                if str(rec.get("status", "")).startswith("FAIL"):
-                    n_fail += 1
+    recs = run_graph_cell(Path(args.out), scale=args.graph_scale,
+                          k=args.graph_k, tag=args.tag)
+    if args.check:
+        msgs = check_graph_ordering(recs)
+        for m in msgs:
+            print(f"collective-bytes gate: {m}", file=sys.stderr)
+        if not msgs:
+            print("collective-bytes gate: quantized < halo < dense, "
+                  "ragged ≤ halo and ragged_quantized < quantized "
+                  "hold for every program, the fused bundle "
+                  f"ships < {FUSED_GATE_RATIO}× its separate steps, "
+                  "overlap cells match phase-ordered bytes and "
+                  "collective-permute count, and tol early-exits "
+                  "under the cap bit-identically")
+        sys.exit(1 if msgs else 0)
+    n_fail = sum(str(r.get("status", "")).startswith("FAIL") for r in recs)
     sys.exit(1 if n_fail else 0)
 
 

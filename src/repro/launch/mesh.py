@@ -1,15 +1,13 @@
-"""Production mesh construction (assignment §MULTI-POD DRY-RUN).
+"""Mesh construction for the graph system.
 
-A FUNCTION, not a module constant — importing this module never touches
-jax device state.  Callers that need 512 host devices must set
-XLA_FLAGS=--xla_force_host_platform_device_count=512 before any jax import
+FUNCTIONS, not module constants — importing this module never touches
+jax device state.  Callers that need N host devices must set
+XLA_FLAGS=--xla_force_host_platform_device_count=N before any jax import
 (launch/dryrun.py does; tests spawn subprocesses).
 
-Every mesh here has ``Auto`` axes.  The LM stack places arrays with
-``with_sharding_constraint`` under a rule table (``dist.sharding``), which
-an ``Explicit`` axis (``jax.make_mesh``'s default) refuses; the graph
-meshes are entered only by ``shard_map``, which takes every axis into
-manual control whatever its type."""
+Every mesh here has ``Auto`` axes (``jax.make_mesh``'s default is
+``Explicit``).  The graph meshes are entered only by ``shard_map``, which
+takes every axis into manual control whatever its type."""
 from __future__ import annotations
 
 import warnings
@@ -20,17 +18,6 @@ from jax.sharding import AxisType
 
 def _auto_mesh(shape: tuple, axes: tuple):
     return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _auto_mesh(shape, axes)
-
-
-def make_test_mesh(n_data: int = 2, n_model: int = 4):
-    """Small mesh for multi-device subprocess tests (8 host devices)."""
-    return _auto_mesh((n_data, n_model), ("data", "model"))
 
 
 def make_graph_mesh(k: int):

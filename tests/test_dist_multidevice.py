@@ -1,7 +1,6 @@
 """Multi-device (8 virtual CPU devices) integration tests, run in
 subprocesses: shard_map graph engine (single and fused multi-program, one
-and several partitions per device),
-SP decode, pipeline parallelism, compressed psum, sharded train step."""
+and several partitions per device)."""
 import pytest
 
 pytestmark = pytest.mark.multidevice
@@ -344,119 +343,3 @@ def test_mesh_gas_compiles_once_and_refuses_what_it_cannot_route(
         sess.run("cc", iters=40, exchange="halo", mesh=mesh))
     print("ok")
     """)
-
-
-def test_sp_decode_matches_full_attention(multidevice):
-    multidevice("""
-    import numpy as np, jax, jax.numpy as jnp
-    from jax.sharding import Mesh
-    from repro.dist.decode import sp_decode_attention, sp_cache_update
-    from repro.dist.sharding import use_rules, SINGLE_POD_RULES
-    from repro.launch.mesh import make_test_mesh
-
-    mesh = make_test_mesh(2, 4)     # model axis = 4 shards the KV sequence
-    B, S, Hq, Hkv, D = 4, 64, 8, 2, 32
-    k1, k2, k3 = jax.random.split(jax.random.key(0), 3)
-    q = jax.random.normal(k1, (B, 1, Hq, D), jnp.float32)
-    kc = jax.random.normal(k2, (B, S, Hkv, D), jnp.float32)
-    vc = jax.random.normal(k3, (B, S, Hkv, D), jnp.float32)
-    idx = jnp.int32(37)
-
-    # single-shard reference (no mesh)
-    ref = sp_decode_attention(q, kc, vc, idx)
-    with use_rules(SINGLE_POD_RULES, mesh):
-        got = sp_decode_attention(q, kc, vc, idx)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-    # cache update writes only on the owning shard
-    new = jax.random.normal(k1, (B, 1, Hkv, D), jnp.float32)
-    ref_c = sp_cache_update(kc, new, idx)
-    with use_rules(SINGLE_POD_RULES, mesh):
-        got_c = sp_cache_update(kc, new, idx)
-    np.testing.assert_allclose(np.asarray(got_c), np.asarray(ref_c),
-                               rtol=1e-6)
-    print('sp decode ok')
-    """)
-
-
-def test_pipeline_parallel_matches_reference(multidevice):
-    multidevice("""
-    import numpy as np, jax, jax.numpy as jnp
-    from repro.dist.pipeline_parallel import pipeline_apply, reference_apply
-
-    mesh = jax.make_mesh((8,), ('stage',))
-    S, M, mb, d = 8, 6, 4, 16
-    key = jax.random.key(0)
-    w = jax.random.normal(key, (S, d, d), jnp.float32) / np.sqrt(d)
-    xs = jax.random.normal(jax.random.key(1), (M, mb, d), jnp.float32)
-
-    def block(x, wi):
-        return jnp.tanh(x @ wi)
-
-    got = pipeline_apply(mesh, 'stage', {'w': w}, xs,
-                         lambda x, p: block(x, p['w']))
-    ref = reference_apply(w, xs, block)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-    print('pipeline ok')
-    """)
-
-
-def test_compressed_psum_close_to_exact(multidevice):
-    multidevice("""
-    import numpy as np, jax, jax.numpy as jnp
-    from functools import partial
-    from jax.sharding import PartitionSpec as P
-    from repro.dist.compress import compressed_psum
-
-    mesh = jax.make_mesh((8,), ('d',))
-    x = jax.random.normal(jax.random.key(0), (8, 256), jnp.float32)
-
-    @partial(jax.shard_map, mesh=mesh, in_specs=P('d'), out_specs=P('d'),
-             check_vma=False)
-    def f(xl):
-        return compressed_psum(xl[0], 'd')[None]
-
-    got = np.asarray(f(x))[0]
-    exact = np.asarray(x).sum(0)
-    rel = np.abs(got - exact).max() / (np.abs(exact).max() + 1e-9)
-    assert rel < 0.05, rel     # int8 quantization error bound
-    print('compressed psum ok', rel)
-    """)
-
-
-def test_sharded_train_step_runs_and_improves(multidevice):
-    multidevice("""
-    import numpy as np, jax, jax.numpy as jnp
-    from jax.sharding import NamedSharding
-    from repro.configs import get_config
-    from repro.models import init_params
-    from repro.train import (get_optimizer, make_train_step, param_specs,
-                             batch_specs)
-    from repro.dist.sharding import use_rules, SINGLE_POD_RULES
-    from repro.launch.mesh import make_test_mesh
-    from repro.data.pipeline import DataConfig, batch_at
-
-    mesh = make_test_mesh(2, 4)
-    cfg = get_config('qwen2_7b').reduced()
-    with use_rules(SINGLE_POD_RULES, mesh):
-        params = init_params(cfg, jax.random.key(0), mp=4)
-        ps = param_specs(params, zero=True, multi_pod=False)
-        psh = jax.tree_util.tree_map(lambda s: NamedSharding(mesh, s), ps)
-        params = jax.tree_util.tree_map(jax.device_put, params, psh)
-        opt = get_optimizer('adamw', lr=1e-2)
-        opt_state = opt.init(params)
-        step = jax.jit(make_train_step(cfg, opt, mp=4, dtype=jnp.float32,
-                                       block_kv=32, loss_chunk=32))
-        dcfg = DataConfig(vocab=cfg.vocab, seq_len=64, global_batch=4)
-        losses = []
-        for i in range(8):
-            b = {k: jnp.asarray(v) for k, v in batch_at(dcfg, i).items()}
-            params, opt_state, loss = step(params, opt_state, b,
-                                           jnp.int32(i))
-            losses.append(float(loss))
-    assert np.isfinite(losses).all()
-    assert losses[-1] < losses[0], losses
-    print('sharded train ok', losses[0], '->', losses[-1])
-    """, n_devices=8, timeout=900)

@@ -17,7 +17,9 @@ from conftest import random_graph_and_assign as _random_graph_and_assign
 
 # ------------------------------------------------------- layout equivalence
 
-@pytest.mark.parametrize("seed,k", [(0, 2), (1, 4), (2, 8), (3, 7)])
+# k = 16 is the benchmark cells' cut; 32 and 64 the paper's larger ones
+@pytest.mark.parametrize("seed,k", [(0, 2), (1, 4), (2, 8), (3, 7), (4, 16),
+                                    (5, 32), (6, 64), (7, 16)])
 def test_vectorized_layout_matches_reference(seed, k):
     src, dst, n, assign = _random_graph_and_assign(seed, k)
     vec = build_layout(src, dst, assign, n, k)

@@ -1,11 +1,12 @@
 """GAS program library (repro.graph.engine): every program in the
-registry matches its NumPy oracle under all three exchange backends,
+registry matches its NumPy oracle under every exchange wire,
 fused multi-program execution matches the per-program runs, iters=0
 returns init values untouched (the dry-run byte parser depends on it),
 and the fused comm model / CI ordering gate behave."""
 import numpy as np
 import pytest
 
+from repro.dist.halo import EXCHANGE_NAMES
 from repro.graph import (CC_SENTINEL, FusedGAS, PROGRAM_NAMES, build_layout,
                          default_num_seeds, fuse_programs, get_program,
                          reference_bfs, reference_cc, reference_centrality,
@@ -28,6 +29,13 @@ EXCHANGES = ("dense", "halo", "quantized")
 ITERS = {"pagerank": 30, "cc": 40, "labelprop": 40, "sssp": 40, "bfs": 40,
          "degree": 2, "centrality": 30, "ppr": 30}
 
+# float tolerance against the oracle after ITERS iterations, per wire:
+# the top-Δ wire ships only the heaviest quarter of each hop's delta, so
+# 30 iterations leave it within 1e-3 (its error keeps shrinking, to under
+# 1e-6 by 100; test_graph_quantized pins both); every other single-program
+# wire is within 1e-5
+FLOAT_TOL = {"ragged_quantized": 1e-3}
+
 
 @pytest.fixture(scope="module")
 def case():
@@ -48,7 +56,7 @@ def case():
 
 # ------------------------------------------------- program × exchange matrix
 
-@pytest.mark.parametrize("exchange", EXCHANGES)
+@pytest.mark.parametrize("exchange", EXCHANGE_NAMES)
 @pytest.mark.parametrize("name", PROGRAM_NAMES)
 def test_program_matches_oracle(case, name, exchange):
     _, _, n, lay, refs = case
@@ -56,7 +64,7 @@ def test_program_matches_oracle(case, name, exchange):
                        exchange=exchange)
     ref = refs[name]
     if np.issubdtype(got.dtype, np.floating):
-        assert np.abs(got - ref).max() < 1e-5
+        assert np.abs(got - ref).max() < FLOAT_TOL.get(exchange, 1e-5)
     else:
         # min/int payloads ship exactly on every backend — incl. quantized,
         # whose EF path is bypassed for non-lossy payloads
@@ -79,52 +87,69 @@ def test_sssp_unreachable_vertices_keep_sentinel(case):
     assert got[0] == 0      # the source itself
 
 
-@pytest.mark.parametrize("backend", ["np", "jit"])
-def test_programs_match_oracle_on_partitioner_layouts(backend):
+@pytest.fixture(scope="module", params=["np", "jit"])
+def partitioner_layout(request):
+    from repro.core import CLUGPConfig, partition, web_graph
+    g = web_graph(scale=9, edge_factor=6, seed=1)
+    res = partition(g.src, g.dst, g.num_vertices, CLUGPConfig(k=4),
+                    backend=request.param)
+    lay = build_layout(g.src, g.dst, res.assign, g.num_vertices, 4)
+    return request.param, g, lay
+
+
+ORACLES = {
+    "pagerank": lambda g: reference_pagerank(g.src, g.dst, g.num_vertices,
+                                             iters=30),
+    "cc": lambda g: reference_cc(g.src, g.dst, g.num_vertices),
+    "labelprop": lambda g: reference_labelprop(g.src, g.dst, g.num_vertices,
+                                               iters=40),
+    "sssp": lambda g: reference_sssp(g.src, g.dst, g.num_vertices,
+                                     iters=40),
+    "bfs": lambda g: reference_bfs(g.src, g.dst, g.num_vertices, iters=40),
+    "degree": lambda g: reference_degree(g.src, g.dst, g.num_vertices),
+    "centrality": lambda g: reference_centrality(g.src, g.dst,
+                                                 g.num_vertices, iters=30),
+    "ppr": lambda g: reference_ppr(g.src, g.dst, g.num_vertices, iters=30),
+}
+
+
+@pytest.mark.parametrize("name", PROGRAM_NAMES)
+def test_programs_match_oracle_on_partitioner_layouts(partitioner_layout,
+                                                      name):
     """The oracle match holds on real CLUGP partitions from the host and
     device partitioner backends, not just random assignments (the
     sharded backend's layout is exercised in the multidevice suite —
     device count locks at first jax init)."""
-    from repro.core import CLUGPConfig, partition, web_graph
-    g = web_graph(scale=9, edge_factor=6, seed=1)
-    res = partition(g.src, g.dst, g.num_vertices, CLUGPConfig(k=4),
-                    backend=backend)
-    lay = build_layout(g.src, g.dst, res.assign, g.num_vertices, 4)
-    refs = {
-        "labelprop": reference_labelprop(g.src, g.dst, g.num_vertices,
-                                         iters=40),
-        "sssp": reference_sssp(g.src, g.dst, g.num_vertices, iters=40),
-        "ppr": reference_ppr(g.src, g.dst, g.num_vertices, iters=30),
-    }
-    for name, ref in refs.items():
-        prog = get_program(name, g.num_vertices)
-        for exchange in EXCHANGES:
-            got = simulate_gas(prog, lay, iters=ITERS[name],
-                               exchange=exchange)
-            if np.issubdtype(got.dtype, np.floating):
-                assert np.abs(got - ref).max() < 1e-5, (name, exchange)
-            else:
-                np.testing.assert_array_equal(
-                    got.astype(np.int64), ref,
-                    err_msg=f"{backend}/{name}/{exchange}")
+    backend, g, lay = partitioner_layout
+    ref = ORACLES[name](g)
+    prog = get_program(name, g.num_vertices)
+    for exchange in EXCHANGES:
+        got = simulate_gas(prog, lay, iters=ITERS[name], exchange=exchange)
+        if np.issubdtype(got.dtype, np.floating):
+            assert np.abs(got - ref).max() < 1e-5, (name, exchange)
+        else:
+            np.testing.assert_array_equal(
+                got.astype(np.int64), ref,
+                err_msg=f"{backend}/{name}/{exchange}")
 
 
 # ------------------------------------------------------------ fused driver
 
-@pytest.mark.parametrize("exchange", EXCHANGES)
+@pytest.mark.parametrize("exchange", EXCHANGE_NAMES)
 def test_fused_f32_bundle_matches_references(case, exchange):
     _, _, n, lay, refs = case
     names = ("pagerank", "ppr", "centrality")
     outs = simulate_gas_many([get_program(p, n) for p in names], lay,
                              iters=30, exchange=exchange)
     # the fused quantized wire is int4 (vs int8 separate) so its EF
-    # tolerance is wider; dense/halo fused math is the separate math
-    tol = 5e-4 if exchange == "quantized" else 1e-5
+    # tolerance is wider; the top-Δ wire keeps its 30-iteration bound;
+    # the exact wires' fused math is the separate math
+    tol = {"quantized": 5e-4}.get(exchange, FLOAT_TOL.get(exchange, 1e-5))
     for name, got in zip(names, outs):
         assert np.abs(got - refs[name]).max() < tol, name
 
 
-@pytest.mark.parametrize("exchange", EXCHANGES)
+@pytest.mark.parametrize("exchange", EXCHANGE_NAMES)
 def test_fused_i32_bundle_bit_exact(case, exchange):
     _, _, n, lay, refs = case
     names = ("sssp", "bfs", "labelprop")
@@ -152,7 +177,7 @@ def test_fused_rejects_heterogeneous_and_empty(case):
 
 # -------------------------------------------------------- iters=0 regression
 
-@pytest.mark.parametrize("exchange", EXCHANGES)
+@pytest.mark.parametrize("exchange", EXCHANGE_NAMES)
 def test_iters_zero_returns_init(case, exchange):
     """Regression: a trip-count-0 fori_loop still bakes its collectives
     into the HLO, so iters=0 must skip the loop entirely and return the

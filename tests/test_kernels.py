@@ -1,4 +1,4 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs jnp oracle."""
+"""The partitioner's Pallas kernels (interpret=True) vs their oracles."""
 import numpy as np
 import pytest
 
@@ -6,61 +6,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
-
-
-# ------------------------------------------------------------ flash attn
-
-@pytest.mark.parametrize("B,Hq,Hkv,Sq,Skv,D", [
-    (1, 4, 4, 128, 128, 64),
-    (2, 4, 2, 128, 256, 64),
-    (1, 8, 1, 256, 256, 128),   # MQA
-    (2, 6, 2, 128, 128, 32),    # GQA group 3
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_matches_ref(B, Hq, Hkv, Sq, Skv, D, dtype, causal):
-    if causal and Sq != Skv:
-        pytest.skip("causal requires square for this sweep")
-    k1, k2, k3 = jax.random.split(jax.random.key(0), 3)
-    q = jax.random.normal(k1, (B, Hq, Sq, D), dtype)
-    k = jax.random.normal(k2, (B, Hkv, Skv, D), dtype)
-    v = jax.random.normal(k3, (B, Hkv, Skv, D), dtype)
-    got = ops.flash_attention(q, k, v, causal=causal, block_q=64,
-                              block_kv=64, interpret=True)
-    want = ref.flash_attention_ref(q, k, v, causal=causal)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32),
-                               rtol=tol, atol=tol)
-
-
-def test_flash_attention_long_context_block_sweep():
-    k1, k2, k3 = jax.random.split(jax.random.key(1), 3)
-    q = jax.random.normal(k1, (1, 2, 512, 64), jnp.float32)
-    k = jax.random.normal(k2, (1, 2, 512, 64), jnp.float32)
-    v = jax.random.normal(k3, (1, 2, 512, 64), jnp.float32)
-    want = ref.flash_attention_ref(q, k, v, causal=True)
-    for bq, bkv in [(64, 128), (128, 64), (256, 256)]:
-        got = ops.flash_attention(q, k, v, causal=True, block_q=bq,
-                                  block_kv=bkv, interpret=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-5, atol=2e-5)
-
-
-def test_flash_matches_model_chunked_attention():
-    """Kernel ≡ the model's pure-jnp chunked attention (same math)."""
-    from repro.models.attention import chunked_attention
-    k1, k2, k3 = jax.random.split(jax.random.key(2), 3)
-    q = jax.random.normal(k1, (2, 128, 4, 64), jnp.float32)  # (B,S,H,D)
-    k = jax.random.normal(k2, (2, 128, 4, 64), jnp.float32)
-    v = jax.random.normal(k3, (2, 128, 4, 64), jnp.float32)
-    got_model = chunked_attention(q, k, v, causal=True, block_kv=64)
-    got_kernel = ops.flash_attention(
-        q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-        v.transpose(0, 2, 1, 3), causal=True, block_q=64, block_kv=64,
-        interpret=True).transpose(0, 2, 1, 3)
-    np.testing.assert_allclose(np.asarray(got_kernel), np.asarray(got_model),
-                               rtol=2e-5, atol=2e-5)
 
 
 # ------------------------------------------------------------ game BR
@@ -125,23 +70,6 @@ def test_game_kernel_agrees_with_host_game_step():
         cost = (lam / k) * sizes[i] * (loads_ex + sizes[i]) \
             + 0.5 * (row_tot[i] - aff[i])
         assert int(got_b[i]) == int(np.argmin(cost))
-
-
-# ------------------------------------------------------------ ELL SpMV
-
-@pytest.mark.parametrize("R,W,N", [(256, 8, 300), (512, 16, 1000),
-                                   (256, 1, 64)])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_ell_spmv_matches_ref(R, W, N, dtype):
-    rng = np.random.default_rng(0)
-    vals = jnp.asarray(rng.random((R, W)), dtype)
-    cols = jnp.asarray(rng.integers(0, N, (R, W)), jnp.int32)
-    x = jnp.asarray(rng.random(N), dtype)
-    got = ops.ell_spmv(vals, cols, x, block_m=128, interpret=True)
-    want = ref.ell_spmv_ref(vals, cols, x)
-    tol = 3e-2 if dtype == jnp.bfloat16 else 1e-5
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=tol,
-                               atol=tol)
 
 
 # ----------------------------------------------------- cluster scatter
@@ -269,30 +197,3 @@ def test_partition_cluster_kernel_bit_identical():
                       CLUGPConfig(k=4, cluster_kernel=kern), backend="jit")
         res[kern] = r.assign
     np.testing.assert_array_equal(res["xla"], res["pallas"])
-
-
-def test_ell_spmv_is_pagerank_gather():
-    """Kernel reproduces the engine's segment_sum local aggregate."""
-    rng = np.random.default_rng(3)
-    n, e = 64, 256
-    src = rng.integers(0, n, e)
-    dst = rng.integers(0, n, e)
-    contrib = rng.random(n).astype(np.float32)
-    # ELL by destination rows
-    width = int(np.bincount(dst, minlength=n).max())
-    vals = np.zeros((n, width), np.float32)
-    cols = np.zeros((n, width), np.int32)
-    fill = np.zeros(n, np.int32)
-    for s, d in zip(src, dst):
-        vals[d, fill[d]] = 1.0
-        cols[d, fill[d]] = s
-        fill[d] += 1
-    rows_pad = -(-n // 128) * 128
-    vals = np.pad(vals, ((0, rows_pad - n), (0, 0)))
-    cols = np.pad(cols, ((0, rows_pad - n), (0, 0)))
-    got = ops.ell_spmv(jnp.asarray(vals), jnp.asarray(cols),
-                       jnp.asarray(contrib), block_m=128, interpret=True)
-    want = np.zeros(n, np.float32)
-    np.add.at(want, dst, contrib[src])
-    np.testing.assert_allclose(np.asarray(got)[:n], want, rtol=1e-5,
-                               atol=1e-5)

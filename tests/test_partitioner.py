@@ -104,6 +104,32 @@ def test_jit_balance_cap_respected(graph10):
         assert sizes.max() <= int(np.ceil(tau * g.num_edges / 8)) + 1
 
 
+@pytest.fixture(scope="module")
+def graph9():
+    return web_graph(scale=9, edge_factor=6, seed=5)
+
+
+@pytest.mark.parametrize("profile", ["paper", "optimized"])
+@pytest.mark.parametrize("backend", ["np", "jit"])
+@pytest.mark.parametrize("k", [2, 4, 8, 16, 32, 64])
+def test_partition_invariants_across_k(graph9, k, backend, profile):
+    """Every edge lands in one part of [0, k); no part holds more than
+    ⌈τ·|E|/k⌉ + 1 edges; the reported RF is the host's recount.  k = 16
+    is the benchmark cells' cut, 64 the paper's large-k regime."""
+    g = graph9
+    cfg = getattr(CLUGPConfig, profile)(k)
+    res = partition(g.src, g.dst, g.num_vertices, cfg, backend=backend)
+    assign = np.asarray(res.assign)
+    assert assign.shape == (g.num_edges,)
+    assert assign.min() >= 0 and assign.max() < k
+    loads = np.bincount(assign, minlength=k)
+    assert loads.max() <= int(np.ceil(cfg.tau * g.num_edges / k)) + 1
+    replicas = sum(np.unique(np.concatenate([g.src[assign == p],
+                                             g.dst[assign == p]])).size
+                   for p in range(k))
+    assert res.stats["rf"] == replicas / g.num_vertices
+
+
 def test_cluster_csr_rejects_int32_overflow():
     """Backstop for the games' int32 pair-key space: above ~46k clusters
     the builder must refuse (the partitioner's games play on the raw

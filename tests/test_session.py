@@ -7,8 +7,9 @@ import numpy as np
 import pytest
 
 from repro.core import CLUGPConfig, partition, web_graph
-from repro.graph import build_layout, reference_pagerank, simulate_cc, \
-    simulate_pagerank
+from repro.core.graphgen import community_web
+from repro.graph import build_layout, reference_cc, reference_pagerank, \
+    simulate_cc, simulate_pagerank
 from repro.session import GraphSession, PROGRAMS, SessionConfig, \
     resolve_program
 
@@ -16,6 +17,37 @@ from repro.session import GraphSession, PROGRAMS, SessionConfig, \
 @pytest.fixture(scope="module")
 def graph10():
     return web_graph(scale=10, edge_factor=6, seed=3)
+
+
+# ------------------------------------------------- the cells' pipeline
+
+@pytest.fixture(scope="module", params=[0, 1, 2, 3])
+def crawl_session(request):
+    """The benchmark cells' pipeline at test size: a crawl-shaped graph
+    (site-local links, cross-site links to hubs, crawl order), cut 16
+    ways with the optimized profile on the jit backend, halo wire."""
+    g = community_web(2048, avg_deg=16, seed=request.param)
+    sess = GraphSession(SessionConfig(clugp=CLUGPConfig.optimized(16),
+                                      backend="jit", exchange="halo"))
+    sess.partition(g.src, g.dst, g.num_vertices).layout()
+    return g, sess
+
+
+def test_crawl_pipeline_pagerank_to_tol(crawl_session):
+    """PageRank stopped at tol 1e-6 is within 1e-5 relative of the
+    float64 reference run for the iterations it reports."""
+    g, sess = crawl_session
+    pr, it = sess.run("pagerank", iters=100, tol=1e-6, return_iters=True)
+    assert 0 < it < 100, it
+    ref = reference_pagerank(g.src, g.dst, g.num_vertices, iters=int(it))
+    assert np.max(np.abs(pr - ref) / ref) <= 1e-5
+
+
+def test_crawl_pipeline_wcc_exact(crawl_session):
+    g, sess = crawl_session
+    np.testing.assert_array_equal(
+        sess.run("cc", iters=200),
+        reference_cc(g.src, g.dst, g.num_vertices))
 
 
 # --------------------------------------------------------------- config

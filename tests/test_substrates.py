@@ -1,56 +1,11 @@
-"""Substrate tests: optimizer, checkpoint/FT, data pipeline, MoE dispatch,
-SSD chunked scan, gradient compression, expert placement."""
+"""Substrate tests: checkpoint writes and the graph service's straggler
+watch (``repro.dist.ft``)."""
 import numpy as np
-import pytest
 
-import jax
 import jax.numpy as jnp
 
 from repro import ckpt
-from repro.data.pipeline import DataConfig, batch_at
-from repro.dist.compress import (compress_with_error_feedback,
-                                 zero_residual)
-from repro.dist.ft import FTConfig, run as ft_run
-from repro.train import adafactor, adamw, cosine_schedule
-
-
-# ---------------------------------------------------------------- optimizer
-
-def _quadratic_params():
-    return {"a": jnp.array([1.5, -2.0, 3.0]), "b": jnp.array([[0.5, -0.5]])}
-
-
-@pytest.mark.parametrize("opt_fn", [adamw, adafactor])
-def test_optimizer_decreases_quadratic(opt_fn):
-    opt = opt_fn(lr=0.05, weight_decay=0.0)
-    params = _quadratic_params()
-    state = opt.init(params)
-
-    def loss(p):
-        return sum(jnp.sum(x ** 2) for x in jax.tree_util.tree_leaves(p))
-
-    l0 = float(loss(params))
-    for step in range(50):
-        grads = jax.grad(loss)(params)
-        params, state = opt.update(grads, state, params, step)
-    assert float(loss(params)) < 0.2 * l0
-
-
-def test_adafactor_factored_state_small():
-    opt = adafactor(min_factor_dim=4)
-    params = {"w": jnp.zeros((8, 16)), "v_small": jnp.zeros((3,))}
-    state = opt.init(params)
-    assert set(state["f"]["w"]) == {"vr", "vc"}
-    assert state["f"]["w"]["vr"].shape == (8,)
-    assert state["f"]["w"]["vc"].shape == (16,)
-    assert set(state["f"]["v_small"]) == {"v"}
-
-
-def test_cosine_schedule_shape():
-    s = cosine_schedule(1e-3, warmup=10, total=100)
-    assert float(s(0)) == 0.0
-    assert abs(float(s(10)) - 1e-3) < 1e-9
-    assert float(s(100)) < 1e-5
+from repro.dist.ft import StragglerWatch
 
 
 # ---------------------------------------------------------------- checkpoint
@@ -84,162 +39,20 @@ def test_checkpoint_latest_wins(tmp_path):
     assert float(got["a"][0]) == 5.0
 
 
-# ---------------------------------------------------------------- FT driver
+# ---------------------------------------------------------------- FT
 
-def _toy_step():
-    def step(params, opt_state, batch, i):
-        params = jax.tree_util.tree_map(lambda p: p - 0.1 * p, params)
-        loss = jnp.sum(params["w"] ** 2)
-        return params, opt_state, loss
-    return step
-
-
-def test_ft_restart_continues_from_checkpoint(tmp_path):
-    params = {"w": jnp.ones((4,))}
-    cfg = FTConfig(ckpt_dir=str(tmp_path), ckpt_every=5,
-                   async_checkpoint=False, fail_at_step=12)
-    with pytest.raises(RuntimeError, match="injected failure"):
-        ft_run(_toy_step(), params, {}, lambda s: None, 20, cfg,
-               log_every=0, log_fn=lambda *_: None)
-    # restart: resumes from step 10's checkpoint and completes
-    cfg2 = FTConfig(ckpt_dir=str(tmp_path), ckpt_every=5,
-                    async_checkpoint=False)
-    p2, _, losses, state = ft_run(_toy_step(), params, {}, lambda s: None,
-                                  20, cfg2, log_every=0,
-                                  log_fn=lambda *_: None)
-    assert state.step == 20
-    # resumed run executed steps 11..19 (9 steps), not all 20
-    assert len(losses) == 9
-    steps = ckpt.list_steps(tmp_path)
-    assert 10 in steps and 19 in steps
-
-
-def test_ft_straggler_detection(tmp_path):
-    import time as _t
-    calls = []
-
-    def slow_step(params, opt_state, batch, i):
-        if int(i) == 6:
-            _t.sleep(0.3)
-        return params, opt_state, jnp.float32(0.0)
-
-    cfg = FTConfig(ckpt_dir=str(tmp_path), ckpt_every=100,
-                   async_checkpoint=False, straggler_factor=3.0)
-    _, _, _, state = ft_run(slow_step, {"w": jnp.ones(2)}, {},
-                            lambda s: None, 10, cfg, log_every=0,
-                            on_straggler=lambda *a: calls.append(a),
-                            log_fn=lambda *_: None)
-    assert state.stragglers >= 1
-    assert calls
-
-
-# ---------------------------------------------------------------- data
-
-def test_data_pipeline_deterministic_and_seekable():
-    cfg = DataConfig(vocab=100, seq_len=32, global_batch=4)
-    b1 = batch_at(cfg, 7)
-    b2 = batch_at(cfg, 7)
-    np.testing.assert_array_equal(b1["tokens"], b2["tokens"])
-    b3 = batch_at(cfg, 8)
-    assert not np.array_equal(b1["tokens"], b3["tokens"])
-    # host sharding is disjoint streams
-    h0 = batch_at(DataConfig(100, 32, 4, n_hosts=2, host_id=0), 3)
-    h1 = batch_at(DataConfig(100, 32, 4, n_hosts=2, host_id=1), 3)
-    assert h0["tokens"].shape == (2, 32)
-    assert not np.array_equal(h0["tokens"], h1["tokens"])
-    # labels are next-token shifted
-    np.testing.assert_array_equal(b1["labels"][:, :-1], b1["tokens"][:, 1:])
-
-
-# ---------------------------------------------------------------- MoE
-
-def test_moe_dispatch_matches_reference_when_uncapped():
-    from repro.models.moe import moe_apply, moe_init, moe_reference
-    key = jax.random.key(0)
-    p = moe_init(key, 32, 64, n_experts=4, n_shared=1)
-    x = jax.random.normal(jax.random.key(1), (2, 16, 32), jnp.float32)
-    got = moe_apply(p, x, n_experts=4, top_k=2, capacity_factor=8.0)
-    want = moe_reference(p, x, n_experts=4, top_k=2)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               rtol=2e-4, atol=2e-4)
-
-
-def test_moe_capacity_drops_monotone():
-    from repro.models.moe import moe_apply, moe_init
-    key = jax.random.key(0)
-    p = moe_init(key, 16, 32, n_experts=4)
-    x = jax.random.normal(jax.random.key(1), (1, 32, 16), jnp.float32)
-    full = moe_apply(p, x, n_experts=4, top_k=2, capacity_factor=8.0)
-    tight = moe_apply(p, x, n_experts=4, top_k=2, capacity_factor=0.25)
-    # tight capacity zeroes some tokens' expert contribution
-    diff = np.abs(np.asarray(full) - np.asarray(tight)).max()
-    assert diff > 0
-
-
-# ---------------------------------------------------------------- SSD
-
-def test_ssd_chunked_matches_sequential_reference():
-    from repro.models.mamba import ssd_chunked, ssd_reference
-    rng = np.random.default_rng(0)
-    b, S, H, dh, N = 2, 64, 3, 8, 16
-    x = jnp.asarray(rng.normal(size=(b, S, H, dh)), jnp.float32)
-    dt = jnp.asarray(rng.random((b, S, H)) * 0.5 + 0.1, jnp.float32)
-    A = -jnp.asarray(rng.random(H) + 0.5, jnp.float32)
-    B = jnp.asarray(rng.normal(size=(b, S, N)), jnp.float32)
-    C = jnp.asarray(rng.normal(size=(b, S, N)), jnp.float32)
-    D = jnp.asarray(rng.random(H), jnp.float32)
-    for chunk in (8, 16, 32):
-        got = ssd_chunked(x, dt, A, B, C, D, chunk=chunk)
-        want = ssd_reference(x, dt, A, B, C, D)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   rtol=2e-4, atol=2e-4)
-
-
-def test_ssd_decode_matches_chunked():
-    from repro.models.mamba import (ssd_apply, ssd_decode_step, ssd_init)
-    key = jax.random.key(0)
-    d_model, d_inner, d_state, head_dim = 16, 32, 8, 8
-    p = ssd_init(key, d_model, d_inner, d_state, head_dim)
-    x = jax.random.normal(jax.random.key(1), (1, 16, d_model), jnp.float32)
-    full = ssd_apply(p, x, d_inner=d_inner, d_state=d_state,
-                     head_dim=head_dim, chunk=8)
-    state = jnp.zeros((1, d_inner // head_dim, d_state, head_dim),
-                      jnp.float32)
-    outs = []
-    for t in range(16):
-        y, state = ssd_decode_step(p, x[:, t:t + 1], state,
-                                   d_inner=d_inner, d_state=d_state,
-                                   head_dim=head_dim)
-        outs.append(np.asarray(y[:, 0]))
-    dec = np.stack(outs, 1)
-    np.testing.assert_allclose(dec, np.asarray(full), rtol=2e-3, atol=2e-3)
-
-
-# ---------------------------------------------------------------- compression
-
-def test_error_feedback_compression_converges():
-    grads = {"w": jnp.asarray(np.random.default_rng(0).normal(size=(64,)),
-                              jnp.float32)}
-    res = zero_residual(grads)
-    acc = jnp.zeros((64,))
-    for _ in range(50):
-        cg, res = compress_with_error_feedback(grads, res)
-        acc = acc + cg["w"]
-    # mean compressed gradient ≈ true gradient (error feedback property)
-    np.testing.assert_allclose(np.asarray(acc) / 50,
-                               np.asarray(grads["w"]), rtol=0.05, atol=0.02)
-
-
-# ---------------------------------------------------------------- experts
-
-def test_expert_placement_reduces_a2a():
-    from benchmarks.bench_expert_placement import (_correlated_routing,
-                                                   a2a_volume)
-    from repro.core.expert_placement import place_experts
-    top = _correlated_routing(T=4000, E=32, K=2, n_topics=4, seed=0)
-    rr = np.arange(32) // 4
-    perm = place_experts(top, 32, 8, seed=0)
-    assert sorted(perm.tolist()) == list(range(32))   # valid permutation
-    game = perm // 4
-    assert np.bincount(game, minlength=8).max() == 4  # balanced shards
-    assert a2a_volume(top, game, 8) <= a2a_volume(top, rr, 8)
+def test_ft_straggler_detection():
+    """A step three times slower than the running median is flagged; the
+    median it is judged against excludes the slow step itself."""
+    watch = StragglerWatch(factor=3.0)
+    times = [0.01, 0.011, 0.009, 0.01, 0.012, 0.01, 0.3, 0.01, 0.011, 0.01]
+    flags = [watch.observe(dt) for dt in times]
+    assert flags == [i == 6 for i in range(len(times))]
+    assert watch.flagged == 1
+    assert watch.last_median == 0.01
+    # factor 0 disables, and nothing is flagged before the warm-up
+    off = StragglerWatch(factor=0.0)
+    assert not any(off.observe(dt) for dt in times)
+    cold = StragglerWatch(factor=3.0, warmup=3)
+    assert [cold.observe(dt) for dt in (1.0, 1.0, 10.0, 10.0)] == [
+        False, False, False, True]
