@@ -9,15 +9,24 @@ pluggable wire format for those phases:
   (L_max,) slab from every device and index into it with the static
   ``red_index`` / ``(owner, own_slot)`` tables.  Bytes ∝ k²·L_max per
   phase, independent of partition quality.
-- ``HaloExchange`` — mirror-routed: each device packs only its mirror
-  slots into per-destination lanes (``halo_send``) and a single
-  ``all_to_all`` delivers every lane to its owner, which scatters via
-  ``halo_recv``.  Bytes ∝ k·(k−1)·H_max per phase — within per-pair
-  padding of the ideal 2·mirrors volume, so CLUGP's mirror reduction is
-  the engine's real wire cost.
+- ``HaloExchange`` — mirror-routed over the layout's **routes**
+  (``PartitionLayout.halo_routes``): each real mirror lane is routed
+  once.  A device with m partitions gathers its lanes out of the flat
+  stack of its partitions' values in one (D, H_dev) block per
+  destination device, padded only to the fullest device pair; one
+  ``all_to_all`` over the D devices ships them (the device's own block
+  stays on the chip), and the owner combines or scatters them with one
+  flat segment op.  A device packs D·H_dev lanes a phase and sends
+  (D−1)·H_dev: per chip 2·(D−1)·H_dev values an iteration.  On one chip
+  (D = 1) the lanes are the mirrors_total real ones, rounded up; at one
+  partition a device H_dev is the per-pair H_max, and the wire is
+  k·(k−1)·H_max per phase — within per-pair padding of the ideal
+  2·mirrors volume, so CLUGP's mirror reduction is the engine's real
+  wire cost.
 - ``RaggedHaloExchange`` — halo routing without the cross-pair padding:
-  the padded ``all_to_all`` ships H_max lanes for *every* ordered pair,
-  so one hot (p, q) cell inflates the whole collective.  The ragged
+  at one partition a device the halo wire's ``all_to_all`` ships H_max
+  lanes for *every* ordered pair, so one hot (p, q) cell inflates the
+  whole collective.  The ragged
   exchange instead walks the k−1 ring distances with one ``ppermute``
   each — hop s moves every device's (p → (p+s) mod k) lanes at once,
   padded only to that distance's max population H_s (the layout's
@@ -62,7 +71,8 @@ stacks**: its m = k/D partitions, (m, …), where D is the size of the mesh
 axis and device d holds partitions d·m … d·m + m − 1.  ``combine`` is
 ``"sum"`` (pagerank) or ``"min"`` (label propagation).  The stacked
 forms model the collective with a transpose (all_to_all) / broadcast
-(all_gather), so tests and host benchmarks run the identical math.
+(all_gather) — the halo wire's one-device routes need neither — so
+tests and host benchmarks run the identical math.
 
 Only ``DenseExchange`` and ``HaloExchange`` route several partitions per
 device (one collective over the D devices; lanes between partitions on
@@ -74,8 +84,8 @@ one partition, unstacked.
 
 **Multi-lane (fused multi-program) operations.**  N homogeneous GAS
 programs over the same layout can share one exchange per phase: values
-grow a leading program axis ((N, L_max) per device), lanes become
-(k, N, H_max), and ONE collective ships every program's mirror traffic —
+grow a program axis ((N, L_max) per device), each lane carries N
+values, and ONE collective ships every program's mirror traffic —
 the ``*_multi`` halves below (``init_state_multi`` /
 ``reduce_to_masters_multi`` / ``broadcast_from_masters_multi`` /
 ``reduce_stacked_multi`` / ``broadcast_stacked_multi``).  For the exact
@@ -147,10 +157,55 @@ def _unpack(new_master, recv, dev):
     return jnp.where(dev["is_master"], new_master, scattered)
 
 
-def _transposed(send):
-    """The stacked form's all_to_all: (k, k, H_max) lanes by (source,
-    destination) → by (destination, source)."""
-    return jnp.swapaxes(send, 0, 1)
+# ------------------------------------------------ the halo wire's routes
+#
+# A device's values are an (m, L_max, *programs) stack: its m partitions'
+# slots, with one value per fused program in the trailing axes.  The
+# routes index the flat (m·(L_max+1), *programs) stack in which each
+# partition's slots are followed by a pad slot: pad lanes read the
+# combine identity there and land there, where nothing reads.
+
+
+def _flat_stack(values, combine: str):
+    """(m, L_max, *p) values → the flat (m·(L_max+1), *p) stack, each
+    partition's pad slot holding the combine identity."""
+    m, _, *rest = values.shape
+    pad = jnp.full((m, 1, *rest), _pad_value(combine, values.dtype),
+                   values.dtype)
+    return jnp.concatenate([values, pad], axis=1).reshape(-1, *rest)
+
+
+def _route_reduce(partials, dev, combine: str, route):
+    """Mirror partials to their masters along the routes: one gather of
+    the send lanes, ``route`` (the collective, or nothing on one
+    device), one segment combine into the flat master stack, and the
+    merge with the local partial."""
+    m, l_max, *rest = partials.shape
+    lanes = route(_flat_stack(partials, combine)[dev["route_send"]
+                                                 .reshape(-1)])
+    agg = _segment_combine(lanes, dev["route_recv"].reshape(-1),
+                           m * (l_max + 1), combine)
+    return _merge(partials,
+                  agg.reshape(m, l_max + 1, *rest)[:, :l_max], combine)
+
+
+def _route_broadcast(masters, dev, combine: str, route):
+    """Master values back to their mirrors along the same routes: one
+    gather of the receive lanes, ``route``, one scatter into the mirror
+    slots; master slots keep their value."""
+    m, l_max, *rest = masters.shape
+    lanes = route(_flat_stack(masters, combine)[dev["route_recv"]
+                                                .reshape(-1)])
+    flat = jnp.zeros((m * (l_max + 1), *rest), masters.dtype).at[
+        dev["route_send"].reshape(-1)].set(lanes)
+    is_master = dev["is_master"].reshape(m, l_max, *(1 for _ in rest))
+    return jnp.where(is_master, masters,
+                     flat.reshape(m, l_max + 1, *rest)[:, :l_max])
+
+
+def _on_chip(lanes):
+    """The stacked form's route: every lane stays on the one device."""
+    return lanes
 
 
 def _one_partition(wire: str, values, dev, state):
@@ -340,14 +395,15 @@ class DenseExchange:
 
 @dataclass(frozen=True)
 class HaloExchange:
-    """Mirror-routed all_to_all sync over the layout's halo tables.
+    """Mirror-routed sync over the layout's halo routes.
 
-    Reduce: pack mirror values into (k, H_max) destination lanes, one
-    all_to_all, scatter-combine received lanes into master slots, merge
-    with the local partial (a master's own contribution never leaves the
-    device).  Broadcast runs the same route backwards: masters pack
-    ``halo_recv`` lanes, mirrors scatter via ``halo_send``; master slots
-    keep their local value.
+    Reduce: gather the device's send lanes, one all_to_all over the D
+    devices, combine the received lanes into master slots with one
+    segment op, merge with the local partial (a master's own
+    contribution never leaves the device).  Broadcast runs the same
+    route backwards: masters gather the receive lanes, mirrors take them
+    with one scatter; master slots keep their local value.  The stacked
+    form is the one-device route, with no collective.
     """
     axis: str | None = None
     name = "halo"
@@ -355,104 +411,59 @@ class HaloExchange:
     def init_state(self, dev, dtype, combine: str = "sum"):
         return ()
 
-    # -- per-device halves (inside shard_map over ``axis``): the device's
-    # m partitions pack (m, k, H_max) lanes, one all_to_all over the D
-    # devices routes them (``_routed``), and each local partition
-    # combines or scatters the lanes it received, as in the stacked form --
+    # -- per-device halves (inside shard_map over ``axis``), over the
+    # device's (m, L_max) local stacks --
     def reduce_to_masters(self, partials, dev, combine: str = "sum",
                           state=()):
-        return self._reduce(partials, dev, combine, self._routed), state
+        return _route_reduce(partials, dev, combine, self._routed), state
 
     def broadcast_from_masters(self, new_masters, dev, combine: str = "sum",
                                state=()):
-        return self._broadcast(new_masters, dev, combine,
-                               self._routed), state
+        return _route_broadcast(new_masters, dev, combine,
+                                self._routed), state
 
-    def _routed(self, send):
-        """(m, k, H_max) lanes by (local source, destination partition) →
-        (m, k, H_max) by (local destination, source partition).  Lanes go
-        out in one (D, m, m, H_max) block per destination device; the
-        device's own block, the lanes between partitions on the same
-        chip, never leaves it."""
-        m, k, h = send.shape
-        blocks = jnp.swapaxes(send.reshape(m, k // m, m, h), 0, 1)
-        got = jax.lax.all_to_all(blocks, self.axis, 0, 0)  # (D, m, m, H)
-        return got.transpose(2, 0, 1, 3).reshape(m, k, h)
+    def _routed(self, lanes):
+        """(D·H_dev, …) lanes in blocks by destination device → in
+        blocks by source device: one all_to_all; the device's own block
+        never leaves it."""
+        return jax.lax.all_to_all(lanes, self.axis, 0, 0, tiled=True)
 
-    # -- stacked halves: all_to_all over k virtual devices == transpose --
+    # -- stacked halves: (k, L_max) stacks on one device --
     def reduce_stacked(self, partials, dev, combine: str = "sum", state=()):
-        return self._reduce(partials, dev, combine, _transposed), state
+        return _route_reduce(partials, dev, combine, _on_chip), state
 
     def broadcast_stacked(self, masters, dev, combine: str = "sum",
                           state=()):
-        return self._broadcast(masters, dev, combine, _transposed), state
+        return _route_broadcast(masters, dev, combine, _on_chip), state
 
-    @staticmethod
-    def _reduce(partials, dev, combine: str, route):
-        l_max = partials.shape[1]
-        send = jax.vmap(
-            lambda v, idx: _pack(v, idx, combine)
-        )(partials, dev["halo_send"])                       # (·, k, H_max)
-        recv = route(send)
-
-        def one(recv_q, slots_q, partial_q):
-            agg = _segment_combine(recv_q.reshape(-1),
-                                   slots_q.reshape(-1),
-                                   l_max + 1, combine)[:l_max]
-            return _merge(partial_q, agg, combine)
-
-        return jax.vmap(one)(recv, dev["halo_recv"], partials)
-
-    @staticmethod
-    def _broadcast(masters, dev, combine: str, route):
-        send = jax.vmap(
-            lambda v, idx: _pack(v, idx, combine)
-        )(masters, dev["halo_recv"])                        # (·, k, H_max)
-        recv = route(send)
-        return jax.vmap(
-            lambda m, r, d: _unpack(m, r, d)
-        )(masters, recv, dev)
-
-    # -- multi-lane halves (fused programs; values carry a leading N) --
+    # -- multi-lane halves (fused programs): each lane carries the N
+    # programs' values, so one collective ships them all.  The
+    # per-device halves hold one partition, unstacked: (N, L_max) --
     def init_state_multi(self, dev, dtype, combine: str, n: int):
         return ()
 
     def reduce_to_masters_multi(self, partials, dev, combine: str = "sum",
                                 state=()):
-        l_max = partials.shape[1]
-        send = _pack_multi(partials, dev["halo_send"], combine)
-        recv = jax.lax.all_to_all(send, self.axis, 0, 0)    # (k, N, H_max)
-        agg = _segment_combine_multi(recv, dev["halo_recv"], l_max + 1,
-                                     combine)
-        return _merge(partials, agg, combine), state
+        total = _route_reduce(partials.T[None], dev, combine, self._routed)
+        return total[0].T, state
 
     def broadcast_from_masters_multi(self, new_masters, dev,
                                      combine: str = "sum", state=()):
-        send = _pack_multi(new_masters, dev["halo_recv"], combine)
-        recv = jax.lax.all_to_all(send, self.axis, 0, 0)    # (k, N, H_max)
-        return _unpack_multi(new_masters, recv, dev), state
+        values = _route_broadcast(new_masters.T[None], dev, combine,
+                                  self._routed)
+        return values[0].T, state
 
     def reduce_stacked_multi(self, partials, dev, combine: str = "sum",
                              state=()):
-        l_max = partials.shape[2]
-        send = jax.vmap(
-            lambda v, idx: _pack_multi(v, idx, combine)
-        )(partials, dev["halo_send"])                   # (k, k, N, H_max)
-        recv = jnp.swapaxes(send, 0, 1)
-        agg = jax.vmap(
-            lambda r, s: _segment_combine_multi(r, s, l_max + 1, combine)
-        )(recv, dev["halo_recv"])
-        return _merge(partials, agg, combine), state
+        total = _route_reduce(jnp.swapaxes(partials, 1, 2), dev, combine,
+                              _on_chip)
+        return jnp.swapaxes(total, 1, 2), state
 
     def broadcast_stacked_multi(self, masters, dev, combine: str = "sum",
                                 state=()):
-        send = jax.vmap(
-            lambda v, idx: _pack_multi(v, idx, combine)
-        )(masters, dev["halo_recv"])                    # (k, k, N, H_max)
-        recv = jnp.swapaxes(send, 0, 1)
-        return jax.vmap(
-            lambda m, r, d: _unpack_multi(m, r, d)
-        )(masters, recv, dev), state
+        values = _route_broadcast(jnp.swapaxes(masters, 1, 2), dev, combine,
+                                  _on_chip)
+        return jnp.swapaxes(values, 1, 2), state
 
     def bytes_per_iter(self, layout, value_bytes: int = 4) -> int:
         return layout.comm_bytes("halo", value_bytes=value_bytes)
@@ -506,8 +517,9 @@ def _ef_encode(lanes, sref, sres):
 class QuantizedHaloExchange:
     """Halo routing with an int8 delta-coded payload (error feedback).
 
-    Same static lane tables as ``HaloExchange``; the wire payload per
-    phase is (k, H_max) int8 codes + (k,) fp32 per-lane-group scales —
+    Rides the per-pair lane tables (``halo_send`` / ``halo_recv``); the
+    wire payload per phase is (k, H_max) int8 codes + (k,) fp32
+    per-lane-group scales —
     ~4× fewer bytes than the fp32 halo lanes.  Each endpoint pair keeps a
     reconstruction reference per lane (``sref`` on the sender, ``rref``
     on the receiver) advanced in lockstep by the dequantized delta, and
@@ -517,8 +529,8 @@ class QuantizedHaloExchange:
 
     ``combine="min"`` / integer payloads (CC labels) are exact in int32
     already — quantizing would corrupt the min lattice — so those
-    programs get the plain halo wire format (``init_state`` returns the
-    empty state and every op delegates).
+    programs get the plain halo wire and its routes (``init_state``
+    returns the empty state and every op delegates).
     """
     axis: str | None = None
     name = "quantized"

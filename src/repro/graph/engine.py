@@ -12,10 +12,10 @@ mirror-sync phases go through the pluggable exchange layer
 - ``exchange="dense"``: two all_gathers of (k, L_max) values — simple, but
   bytes scale with k²·L_max regardless of partition quality (the seed wire
   format).
-- ``exchange="halo"``: two all_to_alls over the layout's static mirror
-  routing tables — bytes scale with the mirror count (RF−1)·|V|, the
-  quantity the partitioner optimizes, so Fig. 8's mechanism shows up on
-  the wire.
+- ``exchange="halo"``: two all_to_alls over the layout's static halo
+  routes, each real mirror lane once, padded per device pair — bytes
+  scale with the mirror count (RF−1)·|V|, the quantity the partitioner
+  optimizes, so Fig. 8's mechanism shows up on the wire.
 - ``exchange="quantized"``: halo routing with int8 delta-coded lanes +
   per-lane-group scales and an error-feedback residual threaded through
   the iteration carry — ~4× fewer payload bytes for fp32 programs, exact
@@ -620,9 +620,20 @@ def _warm_tables(layout: PartitionLayout, dtype, init_values):
 
 # ----------------------------------------------------------- simulated driver
 
-def _stack_dev(layout: PartitionLayout, exchange: str | None = None):
-    return jax.tree_util.tree_map(jnp.asarray,
-                                  layout.device_arrays(exchange))
+def _stack_dev(layout: PartitionLayout, exchange: str | None = None,
+               parts_per_device: int | None = None):
+    return jax.tree_util.tree_map(
+        jnp.asarray, layout.device_arrays(exchange, parts_per_device))
+
+
+def _route_lanes(layout: PartitionLayout, exchange: str, parts: int) -> dict:
+    """On the halo wire, ``gas.run``'s ``halo_lanes`` (the lanes one
+    device packs a phase, pads included) and ``mirror_lanes`` (the real
+    lanes among them, on the fullest device)."""
+    if exchange != "halo":
+        return {}
+    routes = layout.halo_routes(parts)
+    return {"halo_lanes": routes.lanes, "mirror_lanes": routes.mirror_lanes}
 
 
 @partial(jax.jit,
@@ -682,7 +693,8 @@ def simulate_gas(program: GASProgram, layout: PartitionLayout,
             warm = (None if init_values is None
                     else _warm_tables(layout, program.dtype, init_values))
         with obs.span("gas.run", sorted_lanes=_sorted_lanes(
-                (program,), layout, layout.k)):
+                (program,), layout, layout.k),
+                **_route_lanes(layout, exchange, layout.k)):
             out = _sim_gas(program, dev, iters, ex, tol, overlap, warm)
             values, iters_run = (out, iters) if tol is None else out
             vals, iters_run = np.asarray(values), int(iters_run)
@@ -732,8 +744,10 @@ def _parts_per_device(layout: PartitionLayout, mesh: Mesh,
 def _mesh_dev(layout: PartitionLayout, exchange: str, mesh: Mesh,
               axis: str):
     """The layout's tables, each device holding its k/D partitions."""
-    return jax.device_put(layout.device_arrays(exchange),
-                          NamedSharding(mesh, P(axis)))
+    return jax.device_put(
+        layout.device_arrays(exchange,
+                             _parts_per_device(layout, mesh, axis)),
+        NamedSharding(mesh, P(axis)))
 
 
 def shard_map_gas(program: GASProgram, layout: PartitionLayout, mesh: Mesh,
@@ -780,8 +794,9 @@ def _mesh_run(program, layout: PartitionLayout, exchange: str, dev, warm,
     ``ici_bytes`` (what one chip sends over the interconnect per
     iteration, both phases, as padded on the wire: the layout's
     ``comm_bytes(parts_per_device=…)``), ``sorted_lanes`` (as in
-    ``_sorted_lanes``) and ``traced`` (traces of the loop in this
-    call)."""
+    ``_sorted_lanes``), on the halo wire ``halo_lanes`` and
+    ``mirror_lanes`` (``_route_lanes``), and ``traced`` (traces of the
+    loop in this call)."""
     parts = _parts_per_device(layout, mesh, axis)
     fused = isinstance(program, FusedGAS)
     programs = program.programs if fused else (program,)
@@ -792,8 +807,8 @@ def _mesh_run(program, layout: PartitionLayout, exchange: str, dev, warm,
         parts_per_device=parts)
     with obs.span("gas.run", devices=mesh.shape[axis],
                   parts_per_device=parts, ici_bytes=ici,
-                  sorted_lanes=_sorted_lanes(programs, layout, parts)
-                  ) as gas_run:
+                  sorted_lanes=_sorted_lanes(programs, layout, parts),
+                  **_route_lanes(layout, exchange, parts)) as gas_run:
         traced = mesh_traces()
         out = _mesh_gas(program, dev, iters, ex, mesh, axis, tol, overlap,
                         warm)
@@ -1041,7 +1056,8 @@ def simulate_gas_many(programs, layout: PartitionLayout, iters: int = 30,
             warm = (None if init_values is None
                     else _warm_tables_many(layout, fused, init_values))
         with obs.span("gas.run", sorted_lanes=_sorted_lanes(
-                fused.programs, layout, layout.k)):
+                fused.programs, layout, layout.k),
+                **_route_lanes(layout, exchange, layout.k)):
             out = _sim_gas_many(fused, dev, iters, ex, tol, overlap, warm)
             values, iters_run = (out, iters) if tol is None else out
             vals, iters_run = np.asarray(values), int(iters_run)
@@ -1132,7 +1148,8 @@ def gas_step_for_dryrun(program, layout: PartitionLayout,
     only) — the dry-run gates that its wire bytes and collective-permute
     count match the phase-ordered step exactly."""
     _check_overlap(exchange, overlap)
-    dev = _stack_dev(layout, exchange)
+    dev = _stack_dev(layout, exchange,
+                     _parts_per_device(layout, mesh, axis))
     ex = get_exchange(exchange, layout, axis=axis)
     spec = P(axis)
     if isinstance(program, GASProgram):

@@ -15,12 +15,24 @@ Two wire formats are materialized for the exchange layer
 - the **dense** tables (``red_index`` / ``owner`` / ``own_slot``) that back
   the padded all_gather path — bytes ∝ k²·L_max no matter how good the
   partition is; and
-- the **halo routing tables**: for every ordered device pair (p, q) the
-  static send list of p's mirror slots owned by q and the matching recv
-  list of q's master slots, padded per-pair to ``H_max`` so they jit.
-  The mirror-only backend moves 2·k·(k−1)·H_max values per iteration —
-  within per-pair padding of the ideal 2·mirrors volume, so partition
-  quality shows up on the wire.
+- the **halo routes**: the layout keeps its mirror list (``halo_cnt`` and
+  ``mirror_slots``: for each mirror, its partition and slot and its
+  owner's partition and slot), and ``halo_routes(m)`` turns it, in
+  O(k² + mirrors) host time, into the routes of a mesh with m
+  partitions a device, D = k/m devices.  Each device sends one block of
+  lanes to each device, padded only to the fullest *device pair*
+  (``H_dev``), and indexes the flat (m·(L_max+1),) stack of its
+  partitions' values; pad lanes read and land in the pad column.  A
+  device packs D·H_dev lanes a phase and puts (D−1)·H_dev of them on the
+  wire.  On one chip (m = k, D = 1) the routes are one flat list of the
+  mirrors_total real lanes, rounded up; at m = 1 the device pair is the
+  partition pair and the routes hold the per-pair tables below.
+
+The quantized and ragged wires keep the **per-pair tables**: for every
+ordered partition pair (p, q) the static send list of p's mirror slots
+owned by q and the matching recv list of q's master slots, padded per
+pair to ``H_max`` (``halo_send`` / ``halo_recv`` / ``halo_cnt``): they
+need per-destination rows for their scales and per-distance hops.
 
 All tables are padded to static shapes so the engine jits/shard_maps:
 
@@ -37,6 +49,13 @@ All tables are padded to static shapes so the engine jits/shard_maps:
                                   halo_send[p, q] (lanes are packed at the
                                   front of each pair row, so the first
                                   halo_cnt[p, q] entries are valid)
+  route_send        (k, D·H_dev/m) a device's m rows, joined, are its
+                                  (D, H_dev) send lanes by destination
+                                  device: indices into its flat value
+                                  stack (pad: L_max, partition 0's pad)
+  route_recv        (k, D·H_dev/m) likewise its (D, H_dev) receive lanes
+                                  by source device: indices into its flat
+                                  master stack
 
 ``halo_cnt`` is what makes the **ragged** exchanges possible: the padded
 halo wire ships H_max = max over all pairs for *every* pair, so one hot
@@ -49,14 +68,90 @@ replication factors (the common case on web graphs) make Σ_s H_s ≪
 """
 from __future__ import annotations
 
+import math
 import os
 import warnings
 from concurrent.futures import ThreadPoolExecutor
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import numpy as np
 
 from .. import obs
+
+
+@dataclass(frozen=True)
+class HaloRoutes:
+    """The halo wire's routes for ``parts_per_device`` = m partitions a
+    device, D = k/m devices (``PartitionLayout.halo_routes``).
+
+    ``send`` and ``recv`` are (k, D·h_dev/m): a device's m rows, joined,
+    are its D blocks of ``h_dev`` lanes, one per destination device
+    (``send``: indices into its flat (m·(L_max+1),) value stack) or per
+    source device (``recv``: where each received lane lands in its flat
+    master stack).  Pad lanes hold L_max, partition 0's pad column."""
+    parts_per_device: int
+    h_dev: int               # lanes per ordered device pair, padded
+    send: np.ndarray         # (k, D·h_dev/m) int32
+    recv: np.ndarray         # (k, D·h_dev/m) int32
+    mirror_lanes: int        # real lanes of the fullest device
+
+    @property
+    def devices(self) -> int:
+        return self.send.shape[0] // self.parts_per_device
+
+    @property
+    def lanes(self) -> int:
+        """Lanes one device packs (and receives) a phase, pads included."""
+        return self.devices * self.h_dev
+
+
+def _halo_routes(halo_cnt: np.ndarray, mirror_slots: np.ndarray, m: int,
+                 l_max: int, pad_multiple: int) -> HaloRoutes:
+    """``HaloRoutes`` from the layout's mirror list, O(k² + mirrors).
+
+    The list runs in (partition p, owner q) order, so each (p, q) pair is
+    one run of ``halo_cnt[p, q]`` lanes, and it stays one run of lanes in
+    the block of its device pair, after the runs of that block that come
+    before it.  ``h_dev`` is the fullest device pair rounded up to
+    ``pad_multiple``, and further, where m > pad_multiple, until a
+    device's D·h_dev lanes split evenly over its m rows."""
+    k = halo_cnt.shape[0]
+    if m < 1 or k % m:
+        raise ValueError(f"{m} partitions a device do not divide k = {k}")
+    n_dev = k // m
+    # (p, q) runs grouped by device pair: [src dev, dst dev, p mod m, q mod m]
+    runs = halo_cnt.astype(np.int64).reshape(n_dev, m, n_dev, m).transpose(
+        0, 2, 1, 3).reshape(n_dev, n_dev, m * m)
+    step = math.lcm(pad_multiple, m // math.gcd(m, n_dev))
+    h_dev = _pad_to(int(runs.sum(axis=2).max(initial=0)), step)
+    # each run's first lane inside its block, back in (p, q) order
+    in_block = (np.cumsum(runs, axis=2) - runs).reshape(
+        n_dev, n_dev, m, m).transpose(0, 2, 1, 3).reshape(k, k)
+    # each run's first cell in the (D, D, h_dev) send table (blocks by
+    # [source, owner] device) and the receive table ([owner, source])
+    dev = np.arange(k) // m
+    send_at = (dev[:, None] * n_dev + dev[None, :]) * h_dev + in_block
+    recv_at = (dev[None, :] * n_dev + dev[:, None]) * h_dev + in_block
+    # a lane's cell: its run's first cell, plus the lane's place in the
+    # list less the place where its run starts there
+    cnt = halo_cnt.reshape(-1)
+    list_at = (np.cumsum(cnt) - cnt).reshape(k, k)
+    stack_at = ((np.arange(k) % m) * (l_max + 1)).astype(np.int32)
+    lane = np.arange(mirror_slots.shape[1])
+
+    def table(at, offset, slots):
+        """One route table: each lane's slot, plus its partition's offset
+        in the flat stack, at its cell; pads hold L_max."""
+        out = np.full(n_dev * n_dev * h_dev, l_max, dtype=np.int32)
+        out[lane + np.repeat((at - list_at).reshape(-1), cnt)] = (
+            slots + np.repeat(offset, cnt))
+        return out.reshape(k, -1)
+
+    send, recv = _host_map(lambda args: table(*args), [
+        (send_at, np.repeat(stack_at, k), mirror_slots[0]),
+        (recv_at, np.tile(stack_at, k), mirror_slots[1])])
+    return HaloRoutes(parts_per_device=m, h_dev=h_dev, send=send, recv=recv,
+                      mirror_lanes=int(runs.sum(axis=(1, 2)).max(initial=0)))
 
 
 @dataclass
@@ -84,15 +179,26 @@ class PartitionLayout:
     #                          master aggregate depends on mirror lanes);
     #                          interior = vert_mask & ~frontier
     mirrors_total: int       # Σ_v (|P(v)| − 1)
+    mirror_slots: np.ndarray  # (2, mirrors_total) int32, the mirror
+    #                          list: each mirror's slot and its master's
+    #                          slot, in (partition p, owner q, slot) order
+    #                          — halo_cnt[p, q] lanes for each (p, q)
+    pad_multiple: int = 8
+    _routes: dict = field(default_factory=dict, init=False, repr=False,
+                          compare=False)
 
     # per-device tables every backend needs, and each wire format's own
     COMMON_TABLES = ("edge_src", "edge_dst", "edge_mask", "vert_gid",
                      "vert_mask", "is_master", "out_deg")
+    ROUTE_TABLES = ("route_send", "route_recv")
     EXCHANGE_TABLES = {"dense": ("owner", "own_slot", "red_index"),
-                       "halo": ("halo_send", "halo_recv"),
-                       # quantized rides the same routing tables; only the
-                       # payload encoding differs (int8 codes + scales)
-                       "quantized": ("halo_send", "halo_recv"),
+                       "halo": ROUTE_TABLES,
+                       # quantized rides the per-pair tables; only the
+                       # payload encoding differs (int8 codes + scales).
+                       # Its exact payloads (min/int programs) take the
+                       # halo wire's routes
+                       "quantized": ("halo_send", "halo_recv")
+                       + ROUTE_TABLES,
                        # the ragged exchanges slice prefixes of the same
                        # tables per ppermute distance (lanes are packed at
                        # the front of each pair row); the static schedule
@@ -104,11 +210,14 @@ class PartitionLayout:
                        "ragged_quantized": ("halo_send", "halo_recv",
                                             "frontier")}
 
-    def device_arrays(self, exchange: str | None = None) -> dict:
+    def device_arrays(self, exchange: str | None = None,
+                      parts_per_device: int | None = None) -> dict:
         """The pytree of arrays each device needs (leading k axis).
         ``exchange`` restricts the wire-format tables to one backend so the
         other format's tables (red_index is the largest, k²·L_max) never
-        ship to devices; None includes both."""
+        ship to devices; None includes all.  The halo routes are those of
+        ``parts_per_device`` partitions a device (default k: the stacked
+        form, one device)."""
         if exchange is not None and exchange not in self.EXCHANGE_TABLES:
             raise ValueError(
                 f"unknown exchange {exchange!r}; expected one of "
@@ -116,7 +225,20 @@ class PartitionLayout:
         keys = self.COMMON_TABLES + (
             tuple(t for ts in self.EXCHANGE_TABLES.values() for t in ts)
             if exchange is None else self.EXCHANGE_TABLES[exchange])
-        return {f: getattr(self, f) for f in dict.fromkeys(keys)}
+        routes = (self.halo_routes(parts_per_device)
+                  if set(keys) & set(self.ROUTE_TABLES) else None)
+        return {f: (getattr(routes, f[len("route_"):])
+                    if f in self.ROUTE_TABLES else getattr(self, f))
+                for f in dict.fromkeys(keys)}
+
+    def halo_routes(self, parts_per_device: int | None = None) -> HaloRoutes:
+        """The halo wire's routes for m = ``parts_per_device`` partitions
+        a device (default k), built from the mirror list once per m."""
+        m = self.k if parts_per_device is None else int(parts_per_device)
+        if m not in self._routes:
+            self._routes[m] = _halo_routes(self.halo_cnt, self.mirror_slots,
+                                           m, self.l_max, self.pad_multiple)
+        return self._routes[m]
 
     def interior_frontier_stats(self) -> dict:
         """Interior/frontier split of the local vertex tables — the
@@ -174,10 +296,11 @@ class PartitionLayout:
           a mesh of k/m devices, m partitions on each, sends to the other
           chips over the interconnect; lanes between partitions on the
           same chip never leave it.  The halo and dense wires hold any m
-          dividing k (halo: its m partitions' H_max-padded lanes to the
-          k − m partitions elsewhere; dense: its (m, L_max) block to the
-          k/m − 1 other chips); the other wires route one partition a
-          chip, where each chip sends 1/k of the whole wire.
+          dividing k (halo: its routes' (D − 1)·H_dev lanes to the
+          D − 1 other chips, H_dev padded per device pair — at m = 1 the
+          per-pair H_max; dense: its (m, L_max) block to the k/m − 1
+          other chips); the other wires route one partition a chip,
+          where each chip sends 1/k of the whole wire.
         """
         if parts_per_device is not None:
             return self._bytes_per_chip(
@@ -225,7 +348,9 @@ class PartitionLayout:
             raise ValueError(f"{m} partitions a device do not divide "
                              f"k = {self.k}")
         if exchange == "halo" or (exchange == "quantized" and not lossy):
-            return programs * 2 * m * (self.k - m) * self.h_max * value_bytes
+            routes = self.halo_routes(m)
+            return (programs * 2 * (routes.devices - 1) * routes.h_dev
+                    * value_bytes)
         if exchange in ("dense", "dense_gather"):
             return programs * 2 * (self.k - m) * self.l_max * value_bytes
         if exchange not in ("quantized", "ragged", "ragged_quantized"):
@@ -513,6 +638,9 @@ def _build_layout(src, dst, assign, num_vertices: int, k: int,
     # max pair population H_max — every mirror is routed exactly once
     halo_send = np.empty((k, k, h_max), dtype=np.int32)
     halo_recv = np.empty((k, k, h_max), dtype=np.int32)
+    # the mirror list: partition p's mirrors start where p − 1's end
+    mirror_at = np.concatenate([[0], np.cumsum(halo_cnt.sum(axis=1))])
+    mirror_slots = np.empty((2, int(mirror_at[-1])), dtype=np.int32)
 
     def fill(p):
         """Row p of every table, and partition p's columns of red_index
@@ -552,6 +680,8 @@ def _build_layout(src, dst, assign, num_vertices: int, k: int,
         halo_recv[:, p].fill(l_max)
         halo_send[p, to, lane] = mirror
         halo_recv[to, p, lane] = slots[mirror]
+        mirror_slots[0, mirror_at[p]:mirror_at[p + 1]] = mirror
+        mirror_slots[1, mirror_at[p]:mirror_at[p + 1]] = slots[mirror]
 
     _host_map(fill, range(k))
     mirrors_total = int(np.maximum(replic - 1, 0).sum())
@@ -563,7 +693,8 @@ def _build_layout(src, dst, assign, num_vertices: int, k: int,
         is_master=is_master, owner=owner, own_slot=own_slot,
         red_index=red_index, out_deg=out_deg, halo_send=halo_send,
         halo_recv=halo_recv, halo_cnt=halo_cnt, frontier=frontier,
-        mirrors_total=mirrors_total)
+        mirrors_total=mirrors_total, mirror_slots=mirror_slots,
+        pad_multiple=pad_multiple)
 
 
 def build_layout_reference(src: np.ndarray, dst: np.ndarray,
@@ -667,11 +798,14 @@ def build_layout_reference(src: np.ndarray, dst: np.ndarray,
     halo_send = np.full((k, k, h_max), l_max, dtype=np.int32)
     halo_recv = np.full((k, k, h_max), l_max, dtype=np.int32)
     halo_cnt = np.zeros((k, k), dtype=np.int32)
-    for (p, o), lanes in pair_lanes.items():
+    mirror_rows = []
+    for (p, o), lanes in sorted(pair_lanes.items()):
         halo_cnt[p, o] = len(lanes)
         for h, (sl, osl) in enumerate(lanes):
             halo_send[p, o, h] = sl
             halo_recv[o, p, h] = osl
+        mirror_rows.extend(lanes)
+    mirror_slots = np.array(mirror_rows, dtype=np.int32).reshape(-1, 2).T
 
     replic = np.zeros(num_vertices, dtype=np.int64)
     for p in range(k):
@@ -690,4 +824,5 @@ def build_layout_reference(src: np.ndarray, dst: np.ndarray,
         is_master=is_master, owner=owner, own_slot=own_slot,
         red_index=red_index, out_deg=out_deg, halo_send=halo_send,
         halo_recv=halo_recv, halo_cnt=halo_cnt, frontier=frontier,
-        mirrors_total=mirrors_total)
+        mirrors_total=mirrors_total, mirror_slots=mirror_slots,
+        pad_multiple=pad_multiple)

@@ -319,7 +319,8 @@ def test_mesh_gas_compiles_once_and_refuses_what_it_cannot_route(
     lay = sess.partition_layout
     assert attrs["traced"] == 0
     assert attrs["devices"] == 8 and attrs["parts_per_device"] == 2
-    assert attrs["ici_bytes"] == 2 * 2 * lay.h_max * (16 - 2) * 4
+    pairs = lay.halo_cnt.reshape(8, 2, 8, 2).sum(axis=(1, 3))
+    assert attrs["ici_bytes"] == 2 * (8 - 1) * (-(-pairs.max() // 8) * 8) * 4
     assert attrs["ici_bytes"] == lay.comm_bytes("halo", parts_per_device=2)
 
     for exchange, program in (("quantized", "pagerank"),

@@ -169,8 +169,9 @@ def _loop_body_scatters(text: str) -> int:
 @pytest.mark.parametrize("name", PROGRAM_NAMES)
 def test_no_scatter_in_the_local_phase(name):
     """Stacked iteration on the halo wire: the only scatters in the
-    compiled step are the exchange's own (``_segment_combine`` and
-    ``_unpack``), and the loop holds them — except degree's, whose
+    compiled step are the exchange's own (the segment combine of
+    ``_route_reduce`` and the scatter of ``_route_broadcast``), and the
+    loop holds them — except degree's, whose
     iteration reads no loop state, so XLA hoists all of it (exchange
     included) out of the loop."""
     src, dst, n, assign = random_graph_and_assign(0, 4)

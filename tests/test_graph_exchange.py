@@ -135,10 +135,19 @@ def test_comm_model_halo_between_ideal_and_dense():
     assert lay.comm_bytes("halo") < lay.comm_bytes("dense")
 
 
+def _device_pair_lanes(lay, m: int) -> int:
+    """The halo wire's lanes per ordered device pair at m partitions a
+    device: the fullest pair's mirrors, padded to a multiple of 8."""
+    d = lay.k // m
+    pairs = lay.halo_cnt.reshape(d, m, d, m).sum(axis=(1, 3))
+    return -(-int(pairs.max()) // 8) * 8
+
+
 @pytest.mark.parametrize("exchange,m,expect", [
     ("halo", 1, lambda lay: lay.comm_bytes("halo") // 8),
-    ("halo", 2, lambda lay: 2 * 2 * (8 - 2) * lay.h_max * 4),
-    ("quantized", 4, lambda lay: 2 * 4 * (8 - 4) * lay.h_max * 4),
+    ("halo", 2, lambda lay: 2 * (4 - 1) * _device_pair_lanes(lay, 2) * 4),
+    ("quantized", 4,
+     lambda lay: 2 * (2 - 1) * _device_pair_lanes(lay, 4) * 4),
     ("dense", 2, lambda lay: 2 * (8 - 2) * lay.l_max * 4),
     ("dense", 8, lambda lay: 0),
     ("ragged", 1, lambda lay: lay.comm_bytes("ragged") // 8),
@@ -150,10 +159,11 @@ def test_comm_model_halo_between_ideal_and_dense():
 def test_comm_model_per_chip(exchange, m, expect):
     """``comm_bytes(parts_per_device=m)``: what one chip sends to the
     others.  The halo and dense wires hold any m dividing k and keep the
-    lanes between a chip's own partitions off the wire; the other wires
-    route one partition a chip (1/k of the whole wire) and refuse more.
-    The quantized wire's exact payloads (``lossy=False``) ride the halo
-    wire at any m."""
+    lanes between a chip's own partitions off the wire (halo: one block
+    of lanes to each of the D − 1 other chips, padded to the fullest
+    device pair); the other wires route one partition a chip (1/k of the
+    whole wire) and refuse more.  The quantized wire's exact payloads
+    (``lossy=False``) ride the halo wire at any m."""
     src, dst, n, assign = _random_graph_and_assign(2, 8)
     lay = build_layout(src, dst, assign, n, 8)
     lossy = exchange != "quantized"
